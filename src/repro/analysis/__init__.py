@@ -14,8 +14,8 @@
   detector, the precision-flow pass (promoted from shardcheck), and
   the measured f64 error-budget probe, for every backend x dtype.
 * :mod:`repro.analysis.lint` — AST invariants for bug classes this repo
-  has already shipped (dropped kwargs, stray env reads, shard_map
-  imports bypassing the compat shim, bare un-annotated GEMMs).
+  has already shipped (dropped kwargs, stray env reads, bare
+  un-annotated GEMMs).
 
 Run all five: ``python -m repro.analysis --suite all``.
 

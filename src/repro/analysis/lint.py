@@ -15,13 +15,7 @@ class it prevents:
     ``os.environ[...]`` / ``os.environ.get`` / ``os.getenv`` read
     anywhere but ``core/compat.py``, the plan cache (``plan/cache.py``),
     and the calibration store (``plan/calibrate.py``).  Env reads are
-    version/deployment surface; one module owning them is what lets the
-    jax-matrix CI leg work.
-
-``shard-map-import-outside-compat``
-    ``shard_map`` imported from jax anywhere but ``core/compat.py`` —
-    the shim owns the moved-module / renamed-kwarg differences; a direct
-    import silently bypasses them on one side of the version matrix.
+    deployment surface; a few modules owning them keeps it auditable.
 
 ``deprecated-acc-bytes-env``
     Any read of the deprecated ``REPRO_MEC_ACC_BYTES`` override outside
@@ -62,7 +56,6 @@ LINT_BASELINE_VERSION = 1
 RULES = (
     "accepted-kwarg-not-forwarded",
     "raw-environ-read-outside-compat",
-    "shard-map-import-outside-compat",
     "deprecated-acc-bytes-env",
     "no-bare-dot-precision",
 )
@@ -77,11 +70,10 @@ _DOT_PRECISION_DIRS = ("src/repro/core/", "src/repro/kernels/",
                        "src/repro/serving/", "src/repro/plan/")
 _DOT_CALLEES = ("dot", "einsum", "dot_general")
 
-# Files allowed to read the environment raw: the version-compat shim and
-# the plan cache + calibration store (whose directory/file overrides ARE
-# their public configuration).
+# Files allowed to read the environment raw: the startup environment
+# module and the plan cache + calibration store (whose directory/file
+# overrides ARE their public configuration).
 _ENVIRON_ALLOWED = ("core/compat.py", "plan/cache.py", "plan/calibrate.py")
-_SHARD_MAP_ALLOWED = ("core/compat.py",)
 _ACC_BYTES_ENV = "REPRO_MEC_ACC_BYTES"
 
 # Directories scanned relative to the repo root; tests are out of scope
@@ -237,34 +229,6 @@ def _check_environ_reads(tree: ast.AST, path: str,
     return out
 
 
-def _check_shard_map_imports(tree: ast.AST, path: str,
-                             lines: Sequence[str]) -> List[Finding]:
-    rule = "shard-map-import-outside-compat"
-    if any(path.endswith(a) for a in _SHARD_MAP_ALLOWED):
-        return []
-    out: List[Finding] = []
-    for node in ast.walk(tree):
-        detail = None
-        if isinstance(node, ast.ImportFrom):
-            mod = node.module or ""
-            if mod.startswith("jax") and (
-                    "shard_map" in mod
-                    or any(a.name == "shard_map" for a in node.names)):
-                detail = f"from {mod} import " + \
-                    ", ".join(a.name for a in node.names)
-        elif isinstance(node, ast.Import):
-            for a in node.names:
-                if a.name.startswith("jax") and "shard_map" in a.name:
-                    detail = f"import {a.name}"
-        if detail and not _suppressed(lines, node.lineno, rule):
-            out.append(Finding(
-                rule=rule, path=path, symbol=detail, lineno=node.lineno,
-                message=f"{detail}: import shard_map from "
-                        f"repro.core.compat (the shim owns the "
-                        f"moved-module and renamed-kwarg differences)"))
-    return out
-
-
 def _check_bare_dot_precision(tree: ast.AST, path: str,
                               lines: Sequence[str]) -> List[Finding]:
     rule = "no-bare-dot-precision"
@@ -319,7 +283,6 @@ def lint_file(path: pathlib.Path, rel: str) -> List[Finding]:
     out: List[Finding] = []
     out += _check_unused_params(tree, rel, lines)
     out += _check_environ_reads(tree, rel, lines)
-    out += _check_shard_map_imports(tree, rel, lines)
     out += _check_bare_dot_precision(tree, rel, lines)
     return out
 

@@ -5,7 +5,7 @@ For every plan in the committed decision baseline
 the public ``conv2d(plan=...)`` executor against
 ``jax.ShapeDtypeStruct`` operands (no real arrays — cv4 alone would be
 100+ MB), pull the compiled executable's peak temporary-buffer bytes via
-the version-shimmed :func:`repro.core.compat.memory_analysis`, and gate
+``Compiled.memory_analysis()``, and gate
 the measurement against the analytic model
 (``repro.core.memory.algorithm_overhead`` x dtype size) within a
 per-algorithm tolerance band.
@@ -43,7 +43,6 @@ import pathlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.core import memory
-from repro.core.compat import memory_analysis
 from repro.core.convspec import ConvSpec
 
 # Per-algorithm measured-vs-predicted gates, keyed by the *base* model
@@ -94,9 +93,9 @@ def audit_plan(scenario: str, plan) -> Tuple[Dict, List[str]]:
     predicted_bytes = predicted_elems * dtype_bytes
 
     compiled = lower_plan(plan)
-    stats = memory_analysis(compiled)
-    measured = None if stats is None else stats.get("temp_bytes")
-    source = None if stats is None else stats.get("source")
+    stats = compiled.memory_analysis()
+    measured = None if stats is None else int(stats.temp_size_in_bytes)
+    source = None if stats is None else "memory_analysis"
 
     is_pallas = plan.algorithm in ("mec_lowered", "mec_fused", "mec_fused2")
     policy = "recorded" if (is_pallas and not pallas_gated()) else "gated"
@@ -141,9 +140,9 @@ def audit_plan(scenario: str, plan) -> Tuple[Dict, List[str]]:
         "predicted_overhead_bytes": predicted_bytes,
         "measured_temp_bytes": measured,
         "measured_argument_bytes": None if stats is None
-        else stats.get("argument_bytes"),
+        else int(stats.argument_size_in_bytes),
         "measured_output_bytes": None if stats is None
-        else stats.get("output_bytes"),
+        else int(stats.output_size_in_bytes),
         "ratio": ratio,
         "slack_bytes": slack,
         "tolerance": dict(tol),

@@ -15,6 +15,9 @@ TPU before anything is timed or cached:
                               fused2 ``h+1`` halo view.
 ``grid-not-covering``         the output grid leaves part of the (padded)
                               output unwritten.
+``unaligned-block-start``     the fused kernel splits a row into several
+                              output-column blocks whose dynamic starts
+                              Mosaic cannot prove sublane-aligned.
 ``vmem-budget-overrun``       the double-buffered per-step working set
                               (blocks + in-kernel scratch) exceeds the
                               device VMEM (``repro.kernels.ops.vmem_bytes``).
@@ -305,35 +308,39 @@ def check_geometry(spec, algorithm: str, w_blk: Optional[int],
 
 def _check_fused_v1(spec, w_blk: int, db: int, viol: List[Violation],
                     add) -> None:
-    i_n, i_h, i_w, i_c = spec.i_n, spec.i_h, spec.i_w, spec.i_c
+    i_n, i_h, i_c = spec.i_n, spec.i_h, spec.i_c
     k_h, k_w, k_c = spec.k_h, spec.k_w, spec.k_c
     s_h, s_w = spec.s_h, spec.s_w
     o_h, o_w = spec.o_h, spec.o_w
-    kwic = k_w * i_c
     f_wblk = min(w_blk, o_w)
     o_w_p = _ceil_to(o_w, f_wblk)
-    need_w = max(i_w, s_w * (o_w_p - 1) + k_w)
-    in_pad = (i_n, i_h, need_w, i_c)
-    grid = (i_n, o_h, o_w_p // f_wblk, k_h)
-    in_blk = (1, 1, need_w, i_c)
-    k_blk = (1, kwic, k_c)
+    n_wblk = o_w_p // f_wblk
+    # width folded by s_w into channels: k_q unit-stride taps, plus the
+    # window halo past a block (8-aligned when blocks start dynamically)
+    k_q = -(-k_w // s_w)
+    halo = k_q - 1 if n_wblk == 1 else _ceil_to(k_q - 1, 8)
+    i_w2, c2 = o_w_p + halo, s_w * i_c
+    in_pad = (i_n, i_h, i_w2, c2)
+    grid = (i_n, o_h, n_wblk, k_h)
+    in_blk = (1, 1, i_w2, c2)
+    k_blk = (1, k_q, c2, k_c)
     o_blk = (1, 1, f_wblk, k_c)
     out_shape = (i_n, o_h, o_w_p, k_c)
     # input row h*s_h + r — the fused shifted-window walk
     _index_bounds("input", "mec_fused", in_blk, in_pad,
                   lambda n, h, w, r: (n, h * s_h + r, 0, 0), grid, viol)
-    _index_bounds("kernel", "mec_fused", k_blk, (k_h, kwic, k_c),
-                  lambda n, h, w, r: (r, 0, 0), grid, viol)
+    _index_bounds("kernel", "mec_fused", k_blk, (k_h, k_q, c2, k_c),
+                  lambda n, h, w, r: (r, 0, 0, 0), grid, viol)
     _index_bounds("output", "mec_fused", o_blk, out_shape,
                   lambda n, h, w, r: (n, h, w, 0), grid, viol)
     _coverage("mec_fused", o_blk, out_shape,
               (grid[0], grid[1], grid[2], 1), viol)
-    max_col = (grid[2] - 1) * s_w * f_wblk + (k_w - 1) + s_w * (f_wblk - 1)
-    if max_col >= need_w:
+    if n_wblk > 1 and f_wblk % 8:
         viol.append(Violation(
-            "block-index-out-of-bounds", "mec_fused",
-            f"in-kernel column {max_col} over-runs padded width {need_w}"))
-    scratch = f_wblk * kwic * db + f_wblk * k_c * _F32
+            "unaligned-block-start", "mec_fused",
+            f"w_blk={f_wblk} splits o_w={o_w} into {n_wblk} blocks whose "
+            "dynamic starts are not sublane (8) aligned"))
+    scratch = (f_wblk + halo) * c2 * db + f_wblk * k_c * _F32
     add("mec_fused", grid,
         {"input": (in_blk, db), "kernel": (k_blk, db),
          "output": (o_blk, _F32)},

@@ -165,34 +165,6 @@ def trim_reshard(spec, parts, sizes,
     return None, slab
 
 
-def replica_combine_bytes(spec, parts, sizes, dtype_bytes: int) -> float:
-    """Per-device bytes of the gradient-combine all-reduce GSPMD may add
-    when the deployment mesh is *larger* than the partition (free axes
-    replicate the cell ``replicated_ways``-fold — the production-mesh
-    dry-run, not the exact-size host meshes).
-
-    GSPMD is free to shard the backward computation over the unused
-    axes and combine the partial gradients with one all-reduce.  A
-    gradient whose VJP already carries a modeled psum merges into that
-    op (same operand bytes, wider replica groups — no new traffic); the
-    one gradient *without* a modeled psum pays its local shard bytes:
-    the input gradient when the partition has no channel component
-    (its cotangent arrives via the permute transpose), the kernel
-    gradient for the pure-channel partition (computed locally per k_c
-    shard).  At most one term is ever non-zero.
-    """
-    n = dict(zip(parts, sizes))
-    if "channel" not in parts:
-        x_loc = (-(-spec.i_n // n.get("batch", 1))) * \
-            (spec.i_h // max(1, n.get("spatial", 1))) * spec.i_w * spec.i_c
-        return float(x_loc * dtype_bytes)
-    if parts == ("channel",):
-        k_loc = spec.k_h * spec.k_w * spec.i_c * \
-            (-(-spec.k_c // n["channel"]))
-        return float(k_loc * dtype_bytes)
-    return 0.0
-
-
 def expected_collectives(spec, partition, n_dev, dtype_bytes: int,
                          direction: str, *, replicated_ways: int = 1
                          ) -> Tuple[Dict[str, float], Dict[str, float],
@@ -204,17 +176,17 @@ def expected_collectives(spec, partition, n_dev, dtype_bytes: int,
     Eq.-level terms the bench ``dist`` suite gates — so the contract
     can never drift from the costmodel.  ``optional`` is traffic GSPMD
     may add or elide at its discretion (the output-trim rebalance
-    permute; with ``replicated_ways > 1``, the free-axis gradient
-    combine of :func:`replica_combine_bytes`); an observed total
-    matches if it equals the required bytes alone or required+optional.
+    permute); an observed total matches if it equals the required bytes
+    alone or required+optional.
     A non-None ``unmodeled_reason`` means this direction's reshard
     lowering cannot be priced and must be recorded as unverified —
     never as a pass.  ``direction='fwd'`` is the forward program alone;
     ``'grad'`` is ``value_and_grad`` of the probe loss (forward halo +
     transposed halo cotangent on the permute, every backward psum on
-    the all-reduce).  ``replicated_ways`` is how many copies of the
-    cell the deployment mesh's unused axes carry (1 on an exact-size
-    mesh).
+    the all-reduce, including the free-axis gradient combine of
+    ``costmodel.replica_combine_bytes``).  ``replicated_ways`` is how
+    many copies of the cell the mesh's unused axes carry (1 on an
+    exact-size mesh).
     """
     if direction not in DIRECTIONS:
         raise ValueError(f"unknown direction {direction!r}; expected one "
@@ -229,7 +201,8 @@ def expected_collectives(spec, partition, n_dev, dtype_bytes: int,
                          f"component(s) but n_dev {n_dev!r} has "
                          f"{len(sizes)}")
     entry = conv_partition_costs(
-        spec, sizes if len(parts) > 1 else sizes[0], dtype_bytes)[
+        spec, sizes if len(parts) > 1 else sizes[0], dtype_bytes,
+        replicated_ways=replicated_ways)[
             parts if len(parts) > 1 else parts[0]]
     halo = float(entry["halo_bytes_per_device"])
     psum = float(entry["comm_bytes_bwd_per_device"]) - halo
@@ -247,9 +220,6 @@ def expected_collectives(spec, partition, n_dev, dtype_bytes: int,
     optional["collective-permute"] = mult * trim
     if direction == "grad":
         required["all-reduce"] = psum
-        if replicated_ways > 1:
-            optional["all-reduce"] = replica_combine_bytes(
-                spec, parts, sizes, dtype_bytes)
     return required, optional, unmodeled
 
 
@@ -488,6 +458,7 @@ def check_sharding(spec, partition, n_dev=None, *, dtype: str = "float32",
         mesh = make_host_mesh(shape=sizes, axes=tuple(axes) if axes
                               else None)
         axes = tuple(mesh.axis_names)
+    replicated_ways = int(mesh.devices.size) // n_total
 
     precision_value = None
     if precision is not None:
@@ -500,7 +471,8 @@ def check_sharding(spec, partition, n_dev=None, *, dtype: str = "float32",
     verified = []
     for direction in directions:
         required, optional, unmodeled = expected_collectives(
-            spec, parts, sizes, dtype_bytes, direction)
+            spec, parts, sizes, dtype_bytes, direction,
+            replicated_ways=replicated_ways)
         if unmodeled is not None:
             record["directions"][direction] = {"unmodeled": unmodeled}
             unmodeled_reasons.append(f"{direction}: {unmodeled}")

@@ -12,7 +12,7 @@ across runs, machines, and jax versions.  This package owns that:
 * :mod:`repro.bench.harness` — warmup/steady-state timing of
   pre-compiled calls, analytic memory overhead (``repro.core.memory``),
   HLO-derived flops/bytes (``repro.launch.hlo_analysis`` via
-  ``repro.core.compat.cost_analysis``), and costmodel cross-validation.
+  ``Compiled.cost_analysis``), and costmodel cross-validation.
 * :mod:`repro.bench.report` — the ``BENCH_<suite>.json`` schema,
   environment fingerprint, validation, and legacy-CSV rendering.
 * :mod:`repro.bench.check` — baseline comparison with per-metric

@@ -47,6 +47,8 @@ def main(argv=None) -> int:
                     help="cross-validate costmodel predictions against "
                          "measurements (adds a 'crosscheck' section)")
     args = ap.parse_args(argv)
+    from repro.core.compat import enable_compile_cache
+    enable_compile_cache()
 
     interpret = {"auto": None, "true": True, "false": False}[args.interpret]
     if args.suite == "autotune":

@@ -1,176 +1,39 @@
-"""Version-compat shims for the pinned jax (0.4.37).
+"""Where a process of this repo meets its environment.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to the top-level
-``jax`` namespace in later releases, and two kwargs were renamed along
-the way:
+The repo targets one installation (jax 0.9.0) and calls its APIs
+directly.  What is left here is the environment the
+program reads at startup, in the one module the lint allows to read it
+(``raw-environ-read-outside-compat``):
 
-* ``check_rep``  -> ``check_vma``
-* ``auto={axes left automatic}`` -> ``axis_names={axes made manual}``
-  (complementary sets over the mesh axes)
-
-Every module in this package imports ``shard_map`` from here and uses
-the *new* spellings; the shim rewrites them for old builds so one compat
-file covers the whole repo.
-
-``abstract_mesh`` papers over the ``AbstractMesh`` constructor change
-(new: ``AbstractMesh(axis_sizes, axis_names)``; old 0.4.x:
-``AbstractMesh(((name, size), ...))``).
-
-``cost_analysis`` papers over the ``Compiled.cost_analysis()`` return
-change: 0.4.x returns a one-element list of dicts (or an empty list on
-backends without an HLO cost model), newer jax returns the dict itself.
-
-``memory_analysis`` papers over the buffer-assignment accessor: newer
-jax exposes ``Compiled.memory_analysis()`` (a ``CompiledMemoryStats``
-with ``temp_size_in_bytes`` etc.; some versions wrap it in a list);
-builds without it fall back to parsing ``allocation N: size B`` lines
-from the buffer-assignment dump when one is reachable.  Returns ``None``
-when neither source exists, so callers (``repro.analysis.memaudit``)
-can record "unavailable" instead of crashing.
+``enable_compile_cache``  places JAX's persistent compilation cache.  An
+                          entry point calls it once at startup, never a
+                          library module at import.
 """
 from __future__ import annotations
 
-import functools
-import inspect
-import re
-from typing import Optional, Sequence
+import os
+import pathlib
 
-from jax.sharding import AbstractMesh as _AbstractMesh
+#: The checkout root (``src/repro/core/compat.py`` -> three levels up).
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
-try:  # jax >= 0.6-ish exports it at top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # the pinned 0.4.x line
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-if "check_vma" in inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:
-    @functools.wraps(_shard_map)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if "axis_names" in kwargs:
-            manual = frozenset(kwargs.pop("axis_names"))
-            mesh = kwargs.get("mesh") or (args[1] if len(args) > 1 else None)
-            if mesh is None:
-                raise TypeError("shard_map compat: axis_names requires mesh")
-            kwargs["auto"] = frozenset(mesh.axis_names) - manual
-        return _shard_map(*args, **kwargs)
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def cost_analysis(compiled) -> dict:
-    """Properties dict of ``compiled.cost_analysis()`` across jax versions.
+def enable_compile_cache() -> str:
+    """Put JAX's persistent compilation cache where the environment says,
+    else at ``<checkout>/.jax_cache``; return the directory.
 
-    Returns ``{}`` when the backend provides no cost model, so callers can
-    always ``.get("flops", 0.0)`` without version branches.
-    """
-    try:
-        cost = compiled.cost_analysis()
-    except Exception:          # some backends raise instead of returning []
-        return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
-
-
-# CompiledMemoryStats attribute -> the normalized key memaudit reads.
-_MEMORY_STAT_FIELDS = {
-    "temp_size_in_bytes": "temp_bytes",
-    "argument_size_in_bytes": "argument_bytes",
-    "output_size_in_bytes": "output_bytes",
-    "alias_size_in_bytes": "alias_bytes",
-    "generated_code_size_in_bytes": "generated_code_bytes",
-}
-
-# e.g. "allocation 3: 12.3KiB, size 23232, thread-local: ..." — only the
-# decimal byte size is load-bearing; classification flags follow on the
-# same line.
-_ALLOCATION_RE = re.compile(r"^\s*allocation\s+\d+:.*?\bsize\s+(\d+)\b(.*)$",
-                            re.IGNORECASE)
+    With ``$JAX_COMPILATION_CACHE_DIR`` set, JAX reads it itself and this
+    sets nothing.  The fallback is a fixed path because the cache key
+    includes it: a temp, pid or time path would never hit."""
+    env = os.environ.get(COMPILE_CACHE_ENV)
+    if env:
+        return env
+    import jax
+    path = CHECKOUT / ".jax_cache"
+    jax.config.update("jax_compilation_cache_dir", str(path))
+    return str(path)
 
 
-def parse_allocation_lines(text: str) -> dict:
-    """Peak buffer bytes from a buffer-assignment dump's ``allocation:``
-    lines.  Classification mirrors XLA's: ``parameter`` allocations are
-    arguments, ``maybe-live-out`` are outputs, ``constant`` is code-side,
-    everything else is temporary scratch — the quantity Eqs. 2-4 bound.
-    """
-    out = {"temp_bytes": 0, "argument_bytes": 0, "output_bytes": 0,
-           "alias_bytes": 0, "generated_code_bytes": 0}
-    for line in text.splitlines():
-        m = _ALLOCATION_RE.match(line)
-        if not m:
-            continue
-        size, flags = int(m.group(1)), m.group(2)
-        if "parameter" in flags:
-            out["argument_bytes"] += size
-        elif "maybe-live-out" in flags:
-            out["output_bytes"] += size
-        elif "constant" in flags:
-            out["generated_code_bytes"] += size
-        else:
-            out["temp_bytes"] += size
-    return out
-
-
-def _buffer_assignment_text(compiled) -> Optional[str]:
-    """Best-effort buffer-assignment dump of a compiled executable."""
-    for attr in ("buffer_assignment_text", "buffer_assignment"):
-        fn = getattr(compiled, attr, None)
-        if callable(fn):
-            try:
-                text = fn()
-            except Exception:
-                continue
-            if isinstance(text, str) and "allocation" in text:
-                return text
-    try:  # runtime executable's memory-annotated HLO dump, where offered
-        text = compiled.runtime_executable().hlo_modules()[0].to_string()
-    except Exception:
-        return None
-    return text if isinstance(text, str) and "allocation" in text else None
-
-
-def memory_analysis(compiled) -> Optional[dict]:
-    """Normalized buffer-assignment byte counts of a compiled executable.
-
-    Returns ``{"temp_bytes", "argument_bytes", "output_bytes",
-    "alias_bytes", "generated_code_bytes", "source"}`` — ``temp_bytes``
-    is XLA's peak temporary-allocation total, the measured side of the
-    paper's Eq. 2-4 overhead claims.  ``None`` when this build exposes
-    neither ``Compiled.memory_analysis()`` nor a parseable
-    buffer-assignment dump.
-    """
-    stats = None
-    fn = getattr(compiled, "memory_analysis", None)
-    if callable(fn):
-        try:
-            stats = fn()
-        except Exception:
-            stats = None
-    if isinstance(stats, (list, tuple)):
-        stats = stats[0] if stats else None
-    if stats is not None and hasattr(stats, "temp_size_in_bytes"):
-        out = {key: int(getattr(stats, attr, 0))
-               for attr, key in _MEMORY_STAT_FIELDS.items()}
-        out["source"] = "memory_analysis"
-        return out
-    text = _buffer_assignment_text(compiled)
-    if text is None:
-        return None
-    out = parse_allocation_lines(text)
-    out["source"] = "buffer_assignment"
-    return out
-
-
-def abstract_mesh(axis_sizes: Sequence[int],
-                  axis_names: Sequence[str]) -> _AbstractMesh:
-    """AbstractMesh across the constructor-signature change."""
-    try:
-        return _AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:  # 0.4.x: one tuple of (name, size) pairs
-        return _AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
-__all__ = ["abstract_mesh", "cost_analysis", "memory_analysis",
-           "parse_allocation_lines", "shard_map"]
+__all__ = ["CHECKOUT", "COMPILE_CACHE_ENV", "enable_compile_cache"]

@@ -134,29 +134,28 @@ def mec_gemm_pallas(low: jnp.ndarray, kernel_mat: jnp.ndarray,
 # Fused kernel: lowering in VMEM, no L in HBM (beyond-paper)
 # ---------------------------------------------------------------------------
 
-def _fused_kernel(i_ref, k_ref, o_ref, *, k_w: int, s_w: int, w_blk: int,
-                  precision):
-    # i_ref: (1, 1, i_w, i_c) — one input row (h*s_h + r) in VMEM
-    # k_ref: (1, kwic, k_c); o_ref: (1, 1, w_blk, k_c)
+def _fused_kernel(x_ref, k_ref, o_ref, *, k_q: int, w_blk: int, halo: int,
+                  n_wblk: int, precision):
+    # x_ref: (1, 1, i_w2, s_w*i_c) — input row h*s_h + r, width folded by
+    #        s_w into channels, so every window below has unit stride
+    # k_ref: (1, k_q, s_w*i_c, k_c); o_ref: (1, 1, w_blk, k_c)
     r = pl.program_id(3)
-    w = pl.program_id(2)
 
     @pl.when(r == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = i_ref[0, 0]                     # (i_w, i_c)
-    i_c = x.shape[1]
-    base = w * (s_w * w_blk)            # input col of first window in block
-    span = s_w * (w_blk - 1) + 1
-    cols = []
-    for j in range(k_w):
-        seg = lax.dynamic_slice(x, (base + j, 0), (span, i_c))
-        cols.append(seg[::s_w])         # (w_blk, i_c)
-    strip = jnp.stack(cols, axis=1).reshape(w_blk, k_w * i_c)
-    acc = jnp.dot(strip, k_ref[0], precision=precision,
-                  preferred_element_type=jnp.float32)
-    o_ref[0, 0] += acc.astype(o_ref.dtype)
+    # The block's columns plus a halo, loaded from a block-aligned start;
+    # the k_q windows are then static slices of that value.
+    base = 0 if n_wblk == 1 else \
+        pl.multiple_of(pl.program_id(2) * w_blk, w_blk)
+    row = x_ref[0, 0, pl.ds(base, w_blk + halo), :]
+    acc = None
+    for q in range(k_q):
+        part = jnp.dot(row[q:q + w_blk], k_ref[0, q], precision=precision,
+                       preferred_element_type=jnp.float32)
+        acc = part if acc is None else acc + part
+    o_ref[0, 0] += acc
 
 
 # ---------------------------------------------------------------------------
@@ -265,6 +264,13 @@ def mec_conv_fused_pallas(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
 
     inp: (n, i_h, i_w, i_c) pre-padded; kernel: (k_h, k_w, i_c, k_c).
     Returns (n, o_h, o_w, k_c) in inp.dtype (f32 accumulation).
+
+    The width stride is folded out here (space-to-depth): input column
+    ``m*s_w + p`` becomes column ``m``, channel block ``p``, and the
+    kernel's k_w taps regroup into ``k_q = ceil(k_w/s_w)`` unit-stride
+    taps over ``s_w*i_c`` channels (taps past k_w are zero).  Window
+    ``q`` of output column ``ow`` is then folded column ``ow + q``, so
+    the kernel body reads only contiguous, unit-stride slices.
     """
     s_h, s_w = (stride, stride) if isinstance(stride, int) else stride
     i_n, i_h, i_w, i_c = inp.shape
@@ -272,26 +278,37 @@ def mec_conv_fused_pallas(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
     o_h = (i_h - k_h) // s_h + 1
     o_w = (i_w - k_w) // s_w + 1
     w_blk = min(w_blk, o_w)
-    pad_w = (-o_w) % w_blk
-    o_w_p = o_w + pad_w
-    # Pad input width so the last window block is in-bounds.
-    need_w = s_w * (o_w_p - 1) + k_w
-    if need_w > i_w:
-        inp = jnp.pad(inp, ((0, 0), (0, 0), (0, need_w - i_w), (0, 0)))
-    kernel_mat = kernel.reshape(k_h, k_w * i_c, k_c)
-    grid = (i_n, o_h, o_w_p // w_blk, k_h)
+    o_w_p = o_w + (-o_w) % w_blk
+    n_wblk = o_w_p // w_blk
+    k_q = -(-k_w // s_w)
+    # Window halo past a block; a whole multiple of 8 rows when blocks
+    # start at a dynamic offset, which Mosaic loads only sublane-aligned.
+    halo = k_q - 1 if n_wblk == 1 else -(-(k_q - 1) // 8) * 8
+    i_w2 = o_w_p + halo                  # folded columns any block reads
+    need_w = s_w * i_w2
+    # Columns past need_w feed no output; missing ones only meet zero taps
+    # or padded output columns.
+    inp = inp[:, :, :need_w, :]
+    if need_w > inp.shape[2]:
+        inp = jnp.pad(inp, ((0, 0), (0, 0), (0, need_w - inp.shape[2]),
+                            (0, 0)))
+    x2 = inp.reshape(i_n, i_h, i_w2, s_w * i_c)
+    k2 = jnp.pad(kernel, ((0, 0), (0, k_q * s_w - k_w), (0, 0), (0, 0)))
+    k2 = k2.reshape(k_h, k_q, s_w * i_c, k_c)
+    grid = (i_n, o_h, n_wblk, k_h)
     out = pl.pallas_call(
-        functools.partial(_fused_kernel, k_w=k_w, s_w=s_w, w_blk=w_blk,
-                          precision=precision),
+        functools.partial(_fused_kernel, k_q=k_q, w_blk=w_blk, halo=halo,
+                          n_wblk=n_wblk, precision=precision),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, inp.shape[2], i_c),
+            pl.BlockSpec((1, 1, i_w2, s_w * i_c),
                          lambda n, h, w, r, s_h=s_h: (n, h * s_h + r, 0, 0)),
-            pl.BlockSpec((1, k_w * i_c, k_c), lambda n, h, w, r: (r, 0, 0)),
+            pl.BlockSpec((1, k_q, s_w * i_c, k_c),
+                         lambda n, h, w, r: (r, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, w_blk, k_c),
                                lambda n, h, w, r: (n, h, w, 0)),
         out_shape=jax.ShapeDtypeStruct((i_n, o_h, o_w_p, k_c), jnp.float32),
         interpret=interpret,
-    )(inp, kernel_mat)
+    )(x2, k2)
     return out[:, :, :o_w, :].astype(inp.dtype)

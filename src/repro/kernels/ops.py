@@ -1,10 +1,12 @@
 """Public jit'd entry points for the MEC Pallas kernels.
 
-``interpret`` defaults to True when the backend has no TPU (this container
-is CPU-only; on a real TPU pod pass interpret=False or rely on the
-auto-detection).  Block sizes are chosen for v5e VMEM (~16 MiB/core):
-the fused kernel's working set is
-``i_w*i_c + k_w*i_c*k_c + w_blk*k_c`` floats per step.
+``interpret`` defaults to True when the backend has no TPU, so the CPU
+tests run the kernels in the Pallas interpreter.  On a TPU backend the
+kernels always compile through Mosaic: asking for interpret mode there is
+an error, never a silent slowdown.  Only ``mode="fused"`` has a Mosaic
+lowering (:data:`MOSAIC_MODES`); the other modes raise
+``NotImplementedError`` before lowering.  Block sizes are chosen for v5e
+VMEM (~16 MiB/core).
 """
 from __future__ import annotations
 
@@ -25,8 +27,7 @@ ACC_BYTES_ENV = "REPRO_MEC_ACC_BYTES"
 
 # Per-core VMEM by device kind (substring match against
 # jax.Device.device_kind).  v2-v5 generations all carry ~16 MiB/core;
-# Trillium doubles it.  Unknown kinds (and CPU/GPU interpret runs) fall
-# back to the v5e figure.
+# Trillium doubles it.  An unknown TPU kind is an error.
 _VMEM_BYTES_BY_KIND = (
     ("v6", 32 << 20),
     ("v5", 16 << 20),
@@ -34,38 +35,67 @@ _VMEM_BYTES_BY_KIND = (
     ("v3", 16 << 20),
     ("v2", 16 << 20),
 )
-_DEFAULT_VMEM = 16 << 20
+# Off-TPU (Pallas interpreter) runs size blocks as for a v5e chip, so the
+# CPU tests exercise the block sizes the chip path would pick.
+INTERPRET_VMEM = 16 << 20
 # The f32 accumulator gets 1/8 of VMEM; the rest holds the input strip,
 # kernel block, and Mosaic's double buffering.
 _ACC_FRACTION = 8
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# Kernel modes with a Mosaic (TPU) lowering.  ``fused2`` and ``lowered``
+# index loaded values with ``lax.dynamic_slice`` and use strided or
+# (8,128)-misaligned blocks, none of which Mosaic lowers; they run only
+# in the Pallas interpreter.
+MOSAIC_MODES = ("fused",)
+
+
+def resolve_interpret(interpret, mode: str = "fused") -> bool:
+    """The interpret flag a kernel call runs with.
+
+    None follows the backend: interpret everywhere but TPU.  On a TPU
+    backend ``interpret=True`` raises.  A mode without a Mosaic lowering
+    raises ``NotImplementedError`` whenever the call would lower for the
+    chip, before any tracing."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        interpret = not on_tpu
+    elif interpret and on_tpu:
+        raise ValueError(
+            "interpret=True on a TPU backend would run the Pallas kernel "
+            "in the interpreter; drop the flag to compile it for the chip")
+    if not interpret and mode not in MOSAIC_MODES:
+        raise NotImplementedError(
+            f"mec_{mode} has no Mosaic lowering (it slices loaded values "
+            "with lax.dynamic_slice and uses strided or misaligned "
+            f"blocks); on TPU use one of {['mec_' + m for m in MOSAIC_MODES]}"
+            " or an XLA algorithm")
+    return interpret
 
 
 def vmem_bytes() -> int:
-    """Per-core VMEM of the queried device kind (v5e figure when the
-    kind is unknown or the query fails — CPU/GPU interpret runs).  The
-    static checker (``repro.analysis.pallas_check``) sizes whole-kernel
-    working sets against this; :func:`accumulator_budget` carves the
-    accumulator's fraction out of it."""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return _DEFAULT_VMEM
+    """Per-core VMEM of the local device kind.  Off TPU (interpreter
+    runs) this is :data:`INTERPRET_VMEM`; an unknown TPU kind raises.
+    The static checker (``repro.analysis.pallas_check``) sizes
+    whole-kernel working sets against this; :func:`accumulator_budget`
+    carves the accumulator's fraction out of it."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return INTERPRET_VMEM
+    kind = dev.device_kind.lower()
     for tag, vmem in _VMEM_BYTES_BY_KIND:
         if tag in kind:
             return vmem
-    return _DEFAULT_VMEM
+    raise ValueError(f"no VMEM size known for TPU kind {dev.device_kind!r}; "
+                     "add it to repro.kernels.ops._VMEM_BYTES_BY_KIND")
 
 
 def accumulator_budget(*, _warn_env: bool = True) -> int:
     """VMEM bytes the f32 output accumulator may fill.
 
     Resolution order: the REPRO_MEC_ACC_BYTES env override, else
-    VMEM/8 for the queried device kind, else the ~2 MiB v5e heuristic —
-    so non-v5e targets tune block sizes without editing source.
+    VMEM/8 of the local device (:func:`vmem_bytes`; ~2 MiB on v5e and
+    in interpreter runs).
 
     The env override is deprecated outside the planner: tuned block
     sizes belong in a :class:`repro.plan.ConvPlan` (``plan.w_blk``,
@@ -128,8 +158,9 @@ def mec_conv2d_tpu(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
     path) it falls back to :func:`pick_w_blk` — device-queried VMEM with
     the deprecated REPRO_MEC_ACC_BYTES env override.
     """
-    if interpret is None:
-        interpret = _default_interpret()
+    if mode not in ("fused", "fused2", "lowered"):
+        raise ValueError(f"unknown mode {mode!r}")
+    interpret = resolve_interpret(interpret, mode)
     s_h, s_w = (stride, stride) if isinstance(stride, int) else stride
     i_n, i_h, i_w, i_c = inp.shape
     k_h, k_w, _, k_c = kernel.shape
@@ -146,18 +177,15 @@ def mec_conv2d_tpu(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
         return mec_conv_fused2_pallas(inp, kernel, (s_h, s_w), w_blk=w_blk,
                                       interpret=interpret,
                                       precision=precision)
-    if mode == "lowered":
-        low = mec_lower_pallas(inp, k_w, s_w, interpret=interpret)
-        kernel_mat = kernel.reshape(k_h, k_w * i_c, k_c)
-        out = mec_gemm_pallas(low, kernel_mat, k_h, s_h, w_blk=w_blk,
-                              interpret=interpret, precision=precision)
-        return out.astype(inp.dtype)
-    raise ValueError(f"unknown mode {mode!r}")
+    low = mec_lower_pallas(inp, k_w, s_w, interpret=interpret)   # lowered
+    kernel_mat = kernel.reshape(k_h, k_w * i_c, k_c)
+    out = mec_gemm_pallas(low, kernel_mat, k_h, s_h, w_blk=w_blk,
+                          interpret=interpret, precision=precision)
+    return out.astype(inp.dtype)
 
 
 def mec_conv1d_tpu(x: jnp.ndarray, kernel: jnp.ndarray,
                    interpret=None) -> jnp.ndarray:
     """Fused causal depthwise conv1d (Mamba2 / xLSTM blocks)."""
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     return mec_conv1d_pallas(x, kernel, interpret=interpret)

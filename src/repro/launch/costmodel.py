@@ -306,8 +306,34 @@ def _halo_rows(spec) -> int:
     return spatial_halo_rows(spec.k_h, spec.s_h)
 
 
+def replica_combine_bytes(spec, parts, sizes, dtype_bytes: int) -> float:
+    """Per-device bytes of the all-reduce a partitioned backward pays for
+    mesh axes the partition leaves free (the cell is replicated over
+    them).
+
+    ``sharded_conv2d``'s shard_map transpose sums every input cotangent
+    over the mesh axes its input spec does not name.  A gradient whose
+    VJP already carries a modeled psum merges into that op (same operand
+    bytes, wider replica groups — no new traffic); the one gradient
+    *without* a modeled psum pays its local shard bytes: the input
+    gradient when the partition has no channel component, the kernel
+    gradient for the pure-channel partition.  At most one term is ever
+    non-zero.
+    """
+    n = dict(zip(parts, sizes))
+    if "channel" not in parts:
+        x_loc = (-(-spec.i_n // n.get("batch", 1))) * \
+            (spec.i_h // max(1, n.get("spatial", 1))) * spec.i_w * spec.i_c
+        return float(x_loc * dtype_bytes)
+    if tuple(parts) == ("channel",):
+        k_loc = spec.k_h * spec.k_w * spec.i_c * \
+            (-(-spec.k_c // n["channel"]))
+        return float(k_loc * dtype_bytes)
+    return 0.0
+
+
 def conv_partition_costs(spec, n_dev, dtype_bytes: int = 4,
-                         calibration=None) -> Dict:
+                         calibration=None, replicated_ways: int = 1) -> Dict:
     """Per-partition per-device cost terms for an ``n_dev``-way split.
 
     ``n_dev`` as an int evaluates the three 1-D modes (keys ``"batch"``/
@@ -329,7 +355,10 @@ def conv_partition_costs(spec, n_dev, dtype_bytes: int = 4,
       channel psums the input cotangent.  Composites sum their
       components' terms, each psum operand taken at the size the *other*
       component leaves local (e.g. batch x channel psums a ``k_c/n1``
-      kernel shard and an ``i_n/n0`` input shard);
+      kernel shard and an ``i_n/n0`` input shard).  On a mesh with
+      ``replicated_ways > 1`` copies of the cell (axes the partition
+      leaves free) the backward also pays
+      ``replica_combine_bytes_per_device`` (:func:`replica_combine_bytes`);
     * ``flops_per_device``.
 
     A ``repro.plan.calibrate.Calibration`` scales the two per-device
@@ -381,6 +410,9 @@ def conv_partition_costs(spec, n_dev, dtype_bytes: int = 4,
             # the (possibly batch/spatially-sharded) local input.
             bwd += i_n_loc * ceil_div(spec.i_h, max(n_s, 1)) \
                 * spec.i_w * spec.i_c * dtype_bytes
+        replica = replica_combine_bytes(spec, parts, sizes, dtype_bytes) \
+            if replicated_ways > 1 else 0.0
+        bwd += replica
         n_total = math.prod(max(n, 1) for n in sizes)
         return {
             "viable": bool(min(sizes) > 0
@@ -397,6 +429,7 @@ def conv_partition_costs(spec, n_dev, dtype_bytes: int = 4,
             "halo_bytes_per_device": float(halo_bytes),
             "comm_bytes_fwd_per_device": float(fwd),
             "comm_bytes_bwd_per_device": float(bwd),
+            "replica_combine_bytes_per_device": float(replica),
             "flops_per_device": float(memory.conv_flops(spec) / n_total),
         }
 
@@ -482,13 +515,35 @@ def pick_conv_partition(spec, axis_sizes: Dict,
     return best
 
 
+def tpu_fused_ineligibility(spec, dtype="float32") -> str | None:
+    """Why the fused Pallas kernel cannot take ``spec`` on a TPU, or None.
+
+    The kernel's Mosaic lowering needs its geometry to pass the static
+    Pallas checker at the planner's own ``w_blk``: blocks in bounds,
+    sublane-aligned block starts, and a working set inside the device's
+    VMEM.  A geometry it refuses runs on XLA's direct conv instead
+    (:func:`pick_conv2d_algorithm`); ``ConvPlan.explain`` prints the
+    reason."""
+    if spec.k_h == 1 and spec.k_w == 1:
+        return "1x1 kernel: the lowering is a no-op, XLA's conv wins"
+    from repro.analysis.pallas_check import check_geometry
+    from repro.kernels.ops import pick_w_blk
+    verdict = check_geometry(spec, "mec_fused",
+                             pick_w_blk(spec.o_w, spec.k_c, _warn_env=False),
+                             str(dtype))
+    if verdict.ok:
+        return None
+    return "; ".join(v.render() for v in verdict.violations)
+
+
 def pick_conv2d_algorithm(spec, backend: str | None = None,
-                          calibration="ambient") -> str:
+                          calibration="ambient", dtype="float32") -> str:
     """Dispatch rule for conv2d(algorithm='auto') — DESIGN.md §1, §10.
 
     * 1x1 kernels: lowering is a no-op, direct wins outright.
-    * TPU backend: the fused Pallas kernel (no L in HBM at all) is the
-      whole point of this codebase — always.
+    * TPU backend: the fused Pallas kernel (no L in HBM at all) wherever
+      :func:`tpu_fused_ineligibility` admits the geometry, else XLA's
+      direct conv.
     * elsewhere (CPU/GPU via XLA): MEC whenever its compact L actually
       saves memory over im2col (k_h > s_h row overlap, Eq. 4), else
       direct — never im2col/fft/winograd, which only trade memory away
@@ -513,7 +568,8 @@ def pick_conv2d_algorithm(spec, backend: str | None = None,
     if spec.k_h == 1 and spec.k_w == 1:
         return "direct"
     if backend == "tpu":
-        return "mec_fused"
+        return "direct" if tpu_fused_ineligibility(spec, dtype) else \
+            "mec_fused"
     from repro.plan.calibrate import resolve_calibration
     calib = resolve_calibration(calibration, backend)
     costs = conv2d_algorithm_costs(spec, calibration=calib)

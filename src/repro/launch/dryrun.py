@@ -27,7 +27,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.archs import ARCHS
 from repro.configs.shapes import SHAPES, cell_applicable, input_specs
-from repro.core.compat import cost_analysis
 from repro.core.convspec import ConvSpec
 from repro.launch.costmodel import conv_partition_costs
 from repro.launch.hlo_analysis import collective_bytes, roofline_terms
@@ -159,7 +158,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: pathlib.Path,
     t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)     # per-device (partitioned module)
+    cost = compiled.cost_analysis() or {}     # per-device (partitioned module)
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
 
@@ -256,7 +255,7 @@ def run_conv_cell(name: str, multi_pod: bool, out_dir: pathlib.Path,
     t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     coll = collective_bytes(compiled.as_text())
     analytic = conv_partition_costs(spec, n_dev)[
         parts if len(parts) > 1 else parts[0]]
@@ -266,8 +265,8 @@ def run_conv_cell(name: str, multi_pod: bool, out_dir: pathlib.Path,
     from repro.analysis.shardcheck import (expected_collectives,
                                            verify_collectives)
     # The production mesh is larger than the partition: the unused axes
-    # replicate the cell, and GSPMD may shard the backward over them
-    # (expected_collectives prices that combine as optional traffic).
+    # replicate the cell, and the backward sums one gradient over them
+    # (costmodel.replica_combine_bytes, priced by expected_collectives).
     replicated = int(mesh.devices.size) // math.prod(n_axes)
     required, optional, unmodeled = expected_collectives(
         spec, parts, n_axes, 4, "grad", replicated_ways=replicated)

@@ -86,7 +86,10 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
             continue
         types = _TYPE_RE.findall(result_types)
         if variant == "-start" and len(types) > 1:
-            # (operand, result) tuple: keep the result element(s)
+            # (operand, result) tuple: keep the result element(s).  TPU
+            # appends two u32[] sync scalars to a collective-permute-start
+            # tuple; they are not data.
+            types = [t for t in types if t != ("u32", "")]
             types = types[len(types) // 2:]
         total = sum(_shape_bytes(t, d) for t, d in types)
         g = _group_size(line)
@@ -103,12 +106,10 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
 def hlo_flops_bytes(compiled) -> Dict[str, float]:
     """HLO-derived {flops, bytes_accessed} of a compiled executable.
 
-    Uses the version-normalized ``repro.core.compat.cost_analysis``; both
-    fields are 0.0 on backends without a cost model.  NOTE the while-body
+    Both fields are 0.0 on backends without a cost model.  NOTE the while-body
     caveat in ``repro.launch.costmodel``: scan bodies are counted once.
     """
-    from repro.core.compat import cost_analysis
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes_accessed": float(cost.get("bytes accessed", 0.0))}
 

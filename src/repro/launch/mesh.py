@@ -11,7 +11,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -20,7 +20,10 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        # Auto axes: the sharding rules and shard_map code here are
+        # GSPMD-style (jax.make_mesh's default is Explicit).
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(shape))
     if len(devices) > n:
         # dry-run host platform exposes 512 devices; single-pod uses 256
         return Mesh(np.asarray(devices[:n]).reshape(shape), axes)

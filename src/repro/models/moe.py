@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map
+from jax import shard_map
 from repro.models.layers import init_linear, init_swiglu, swiglu
 from repro.parallel.axes import current_rules
 

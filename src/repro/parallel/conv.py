@@ -54,7 +54,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
+from jax import shard_map
 from repro.core.conv_api import ALGORITHMS, apply_padding, conv2d
 from repro.core.convspec import ConvSpec, normalize_stride, spec_of
 from repro.core.mec import SOLUTIONS

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(block_fn: Callable, stacked_params, x: jnp.ndarray,

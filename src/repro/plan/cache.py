@@ -40,10 +40,7 @@ _DEFAULT_MAX_ENTRIES = 4096
 def environment_fingerprint() -> str:
     """Short stable hash of everything that invalidates cached plans."""
     import jax
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = "unknown"
+    kind = jax.devices()[0].device_kind
     raw = (f"plan{PLAN_VERSION}|jax{jax.__version__}|"
            f"{jax.default_backend()}|{kind}")
     return hashlib.sha256(raw.encode()).hexdigest()[:16]

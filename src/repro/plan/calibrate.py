@@ -126,11 +126,7 @@ def _features(spec: ConvSpec, algorithm: str) -> Tuple[float, float]:
 
 def _current_env() -> Tuple[str, str]:
     import jax
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = "unknown"
-    return jax.default_backend(), kind
+    return jax.default_backend(), jax.devices()[0].device_kind
 
 
 @dataclasses.dataclass

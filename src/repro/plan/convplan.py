@@ -241,6 +241,11 @@ class ConvPlan:
         if self.algorithm in _PALLAS_ALGOS:
             lines.append("  (Pallas kernel: lowering stays in VMEM; "
                          "HBM overhead is the direct conv's)")
+        elif self.backend == "tpu":
+            from repro.launch.costmodel import tpu_fused_ineligibility
+            why = tpu_fused_ineligibility(s, self.dtype)
+            if why:
+                lines.append(f"  mec_fused not taken on tpu: {why}")
         if self.partition is None:
             lines.append("  partition: none (single device)")
         else:
@@ -263,7 +268,7 @@ class ConvPlan:
                     f"(halo {entry['halo_bytes_per_device']:.3e}); "
                     f"per-device L overhead "
                     f"{entry['per_device_overhead_elems']:.3e} elems")
-            except Exception:  # no live mesh to size the axes from
+            except (ValueError, KeyError):  # no live mesh to size axes
                 lines.append("    (no live mesh: per-device comm bytes "
                              "need the axis sizes)")
         return "\n".join(lines)
@@ -399,12 +404,21 @@ def pick_measured(times: Dict[str, float], analytic: str,
     return best
 
 
-def eligible_candidates(spec: ConvSpec) -> Tuple[str, ...]:
-    """conv2d algorithm names the measured policy may time on a spec."""
+def eligible_candidates(spec: ConvSpec,
+                        backend: Optional[str] = None) -> Tuple[str, ...]:
+    """conv2d algorithm names the measured policy may time on a spec.
+    On a TPU backend only the Pallas kernels with a Mosaic lowering
+    (``repro.kernels.ops.MOSAIC_MODES``) are candidates."""
+    import jax
+    from repro.kernels.ops import MOSAIC_MODES
+    on_tpu = (backend or jax.default_backend()) == "tpu"
     algs = []
     for alg in _SINGLE_DEVICE_ALGOS:
         if alg == "winograd" and \
                 (spec.k_h, spec.k_w, spec.s_h, spec.s_w) != (3, 3, 1, 1):
+            continue
+        if on_tpu and alg in _PALLAS_ALGOS and \
+                alg[len("mec_"):] not in MOSAIC_MODES:
             continue
         algs.append(alg)
     return tuple(algs)
@@ -598,7 +612,7 @@ def tune_measured(spec: ConvSpec, dtype: str = "float32",
         interpret=interpret, precision=precision_name, record=record)
     from repro.launch.costmodel import pick_conv2d_algorithm
     analytic = pick_conv2d_algorithm(spec, backend,
-                                     calibration=calibration)
+                                     calibration=calibration, dtype=dtype)
     if not mc.times:
         raise ValueError(
             f"measured planning has no timeable candidate for "
@@ -759,7 +773,7 @@ def plan_conv2d(spec: ConvSpec, *, dtype="float32", mode: str = "analytic",
 
     from repro.launch.costmodel import pick_conv2d_algorithm
     algorithm = pick_conv2d_algorithm(spec, backend,
-                                      calibration=calibration)
+                                      calibration=calibration, dtype=dtype)
     solution = pick_solution(spec) if algorithm == "mec" else "auto"
     plan = ConvPlan(spec=spec, dtype=dtype, algorithm=algorithm,
                     solution=solution,

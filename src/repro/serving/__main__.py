@@ -11,9 +11,8 @@
 the persistent plan cache, and prints the resolved
 :class:`~repro.plan.ConvPlan` table per class
 (:meth:`ConvPlan.explain`) plus the warning / plan-cache-I/O counters —
-exactly what the serve report will carry at runtime.  Exit status is
-non-zero when any class failed to warm (the service would still run,
-degraded; deploy gates can choose to care).
+exactly what the serve report will carry at runtime.  A class that
+fails to plan or compile raises, so the exit status is non-zero.
 """
 from __future__ import annotations
 
@@ -70,6 +69,8 @@ def main(argv=None) -> int:
     ap.add_argument("--d-model", type=int, default=64,
                     help="whisper frontend: model width")
     args = ap.parse_args(argv)
+    from repro.core.compat import enable_compile_cache
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -89,13 +90,10 @@ def main(argv=None) -> int:
         svc.warm()
         services, labels = [svc], [f"conv {args.kernel}"]
 
-    rc = 0
     for label, svc in zip(labels, services):
         print(f"== {label} ==")
         print(svc.warmup.render())
-        if len(svc.warmup.plans) < len(svc.classes):
-            rc = 1
-    return rc
+    return 0
 
 
 if __name__ == "__main__":

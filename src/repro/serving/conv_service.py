@@ -14,11 +14,11 @@ cashes it in under live traffic:
   ``cache_key()`` — per class, not per request shape.
 * :meth:`ConvService.warm` — at startup, resolve the plan for every
   class through the persistent plan cache (``plan_conv2d(mode=
-  "cached")``) and AOT-compile the class executor.  Warmup is strictly
-  best-effort: an unreadable/corrupt/read-only ``$REPRO_PLAN_CACHE_DIR``
-  degrades to analytic planning with a warning *counter* (surfaced in
-  the serve report), never a crash — the same stance the plan cache
-  itself takes on reads.
+  "cached")``) and compile the class executor.  An unreadable/corrupt/
+  read-only ``$REPRO_PLAN_CACHE_DIR`` degrades to analytic planning with
+  a warning *counter* (surfaced in the serve report), never a crash — the
+  same stance the plan cache itself takes on reads.  A class executor
+  that fails to compile or run raises.
 * :meth:`ConvService.execute` — bucket, zero-pad into the class, run the
   frozen plan through the compiled executor, slice the request's true
   output back out.  A class the service was never warmed for resolves
@@ -230,10 +230,11 @@ class ConvService:
 
     def warm(self) -> WarmupReport:
         """Resolve every class's ConvPlan through the plan cache and
-        AOT-compile the class executors.  Best-effort: a class whose
-        cached resolution fails falls back to an analytic plan; a class
-        that cannot be planned at all is recorded as a warning and
-        served lazily — warmup never raises for cache trouble."""
+        compile the class executors.  Cache trouble degrades: a class
+        whose cached resolution fails is planned analytically, with a
+        warning (:meth:`_resolve_plan`).  A class that cannot be planned
+        analytically, or whose executor fails to compile or run, raises:
+        warmup never reports success for a class it could not serve."""
         from repro.plan.cache import global_plan_cache
         t0 = time.perf_counter()
         cache = global_plan_cache()
@@ -241,13 +242,8 @@ class ConvService:
         for cls in self.classes:
             if cls in self._compiled:
                 continue
-            try:
-                plan = self._resolve_plan(cls)
-                self._compiled[cls] = self._compile(cls, plan)
-            except Exception as e:  # degraded, not down (DESIGN.md §9)
-                self.warmup.warnings.append(
-                    f"class {cls.tag()}: {type(e).__name__}: {e}")
-                continue
+            plan = self._resolve_plan(cls)
+            self._compiled[cls] = self._compile(cls, plan)
             self._plans[cls] = plan
             self.warmup.plans[cls] = plan
         self.warmup.plan_cache_io_errors = cache.io_errors - io_before
@@ -257,15 +253,18 @@ class ConvService:
     def _resolve_plan(self, cls: ShapeClass):
         from repro.plan import plan_conv2d
         spec = self.class_spec(cls)
+        if self.plan_mode == "analytic":
+            return plan_conv2d(spec, dtype=self.dtype, mode="analytic",
+                               partition="none")
         try:
             return plan_conv2d(spec, dtype=self.dtype, mode=self.plan_mode,
                                partition="none")
-        except Exception as e:
-            if self.plan_mode == "analytic":
-                raise
+        except (OSError, ValueError, TypeError, KeyError) as e:
             # The cached policy's failure modes (a poisoned cache object,
             # a cache dir that is actually a file, ...) must not take the
             # service down — replan analytically and count the warning.
+            # The analytic replan below is not guarded: if it fails too,
+            # the class cannot be planned and that error propagates.
             self.warmup.warnings.append(
                 f"class {cls.tag()}: {self.plan_mode!r} planning failed "
                 f"({type(e).__name__}: {e}); fell back to analytic")
@@ -335,7 +334,8 @@ def fit_prefix(frames: jnp.ndarray, prefix_len: int) -> jnp.ndarray:
 
 def whisper_frontend_service(key, n_mels: int, d_model: int,
                              classes: Sequence[Tuple[int, int, int]],
-                             plan_mode: str = "cached"):
+                             plan_mode: str = "cached",
+                             dtype="float32"):
     """The whisper mel frontend (examples/whisper_frontend.py) as two
     warm ConvServices over time-bucketed shape classes.
 
@@ -345,11 +345,14 @@ def whisper_frontend_service(key, n_mels: int, d_model: int,
     is class-servable); layer 2 is the whisper-conventional stride-2
     (1, 1) pad.  Returns ``(frontend, [service1, service2])`` where
     ``frontend(mel)`` maps (B, T, n_mels) -> (B, ceil(T/2), d_model)
-    through the warmed plans.
+    through the warmed plans.  ``dtype`` is the weights' (and so the
+    requests') dtype.
     """
     k1, k2 = jax.random.split(key)
-    w1 = jax.random.normal(k1, (3, 1, n_mels, d_model)) * n_mels ** -0.5
-    w2 = jax.random.normal(k2, (3, 1, d_model, d_model)) * d_model ** -0.5
+    w1 = (jax.random.normal(k1, (3, 1, n_mels, d_model))
+          * n_mels ** -0.5).astype(dtype)
+    w2 = (jax.random.normal(k2, (3, 1, d_model, d_model))
+          * d_model ** -0.5).astype(dtype)
     svc1 = ConvService(w1, stride=(1, 1), padding=((1, 1), (0, 0)),
                        classes=classes, plan_mode=plan_mode)
     svc2 = ConvService(w2, stride=(2, 1), padding=((1, 1), (0, 0)),

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map
+from jax import shard_map
 from repro.models import serve
 from repro.models.lm import LM
 from repro.optim import adamw

@@ -125,26 +125,14 @@ def test_measure_candidates_skips_rejected_pallas(monkeypatch):
 # memaudit
 # ---------------------------------------------------------------------------
 
-def _require_memory_stats():
-    """Gate for jax builds whose AOT API exposes no memory stats (the
-    auditor degrades to recorded-only there; nothing to assert)."""
-    import jax
-    from repro.core.compat import memory_analysis
-    compiled = jax.jit(lambda x: x + 1).lower(
-        jax.ShapeDtypeStruct((8,), "float32")).compile()
-    if memory_analysis(compiled) is None:
-        pytest.skip("no compiled memory stats on this jax build")
-
-
 def test_memaudit_single_cell_passes():
     from repro.analysis.memaudit import audit_plan
-    _require_memory_stats()
     plan = ConvPlan(spec=SMALL, dtype="float32", algorithm="mec",
                     solution="A")
     rec, failures = audit_plan("unit/small", plan)
     assert failures == []
     assert rec["verdict"] == "pass"
-    assert rec["source"] in ("memory_analysis", "buffer_assignment")
+    assert rec["source"] == "memory_analysis"
     assert rec["predicted_overhead_bytes"] == \
         SMALL.i_n * SMALL.o_w * SMALL.i_h * SMALL.k_w * SMALL.i_c * 4
     assert rec["measured_temp_bytes"] >= rec["predicted_overhead_bytes"]
@@ -153,7 +141,6 @@ def test_memaudit_single_cell_passes():
 def test_memaudit_im2col_exact():
     """im2col is the calibration cell: XLA materializes exactly the
     Toeplitz matrix, ratio 1.000."""
-    _require_memory_stats()
     from repro.analysis.memaudit import audit_plan
     plan = ConvPlan(spec=SMALL, dtype="float32", algorithm="im2col")
     rec, failures = audit_plan("unit/im2col", plan)
@@ -162,7 +149,6 @@ def test_memaudit_im2col_exact():
 
 
 def test_memaudit_report_schema_and_crosscheck():
-    _require_memory_stats()
     from repro.analysis.memaudit import run_audit
     from repro.bench.report import validate_report
     plans = {"unit/small": ConvPlan(spec=SMALL, dtype="float32",
@@ -183,7 +169,6 @@ def test_memaudit_detects_model_drift():
     """If the implementation's footprint leaves the model's band, the
     auditor fails — simulated by shrinking the prediction (equivalent to
     an Eq. 3 regression)."""
-    _require_memory_stats()
     from repro.analysis import memaudit
     plan = ConvPlan(spec=SMALL, dtype="float32", algorithm="mec",
                     solution="A")
@@ -276,16 +261,6 @@ def test_lint_deprecated_acc_bytes_env(tmp_path):
         v = os.environ.get("REPRO_MEC_ACC_BYTES")
         """, rel="src/repro/core/compat.py")   # allowed file: env rule off
     assert [f.rule for f in findings] == ["deprecated-acc-bytes-env"]
-
-
-def test_lint_shard_map_import_outside_compat(tmp_path):
-    findings = _lint_src(tmp_path, """
-        from jax.experimental.shard_map import shard_map
-        """)
-    assert [f.rule for f in findings] == ["shard-map-import-outside-compat"]
-    assert _lint_src(tmp_path, """
-        from repro.core.compat import shard_map
-        """) == []
 
 
 def test_lint_bare_dot_precision_flagged_in_numeric_core(tmp_path):

@@ -215,8 +215,10 @@ def test_warmup_report_renders_plan_table():
     assert "0 plan-cache I/O error(s)" in report.summary()
 
 
-def test_warmup_report_cli(capsys):
+def test_warmup_report_cli(capsys, tmp_path, monkeypatch):
     from repro.serving.__main__ import main
+    # the CLI places JAX's compile cache; keep it out of the checkout
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     rc = main(["--warmup-report", "--kernel", "3x3x2x4", "--stride", "2",
                "--padding", "1", "--shape-classes", "1x8x8",
                "--plan-mode", "analytic"])

@@ -22,8 +22,8 @@ from repro.parallel.axes import default_rules
 
 def _fake_mesh(shape=(2, 4), axes=("data", "model")):
     """An abstract mesh for spec computation only (no devices needed)."""
-    from repro.core.compat import abstract_mesh
-    return abstract_mesh(shape, axes)
+    from jax.sharding import AbstractMesh
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def test_param_rules_respect_divisibility():
@@ -161,11 +161,6 @@ def test_ep_moe_multidevice_subprocess():
     assert abs(res["aux_l"] - res["aux_e"]) / res["aux_l"] < 0.25, res
 
 
-@pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="partial-manual shard_map (auto= over the model axis) aborts the "
-           "XLA SPMD partitioner on jax 0.4.x (fatal "
-           "'Check failed: sharding.IsManualSubgroup()'); needs jax >= 0.5")
 def test_compressed_training_dp_tp_mesh_subprocess():
     """int8-EF gradient reduction composes with tensor parallelism via
     partial-manual shard_map (manual over DP, auto over model)."""

@@ -71,6 +71,22 @@ def test_collective_bytes_async_pair_counted_once():
     assert out["all-gather"] == 8 * 128 * 4 // 4
 
 
+def test_collective_bytes_tpu_permute_start_sync_scalars():
+    """A TPU collective-permute-start tuple carries two u32[] sync
+    scalars after (operand, result); only the result is data."""
+    hlo = "\n".join([
+        "  %collective-permute-start = (bf16[32,5,224,64]{2,3,1,0:T(8,128)"
+        "(2,1)S(1)}, bf16[32,5,224,64]{2,3,1,0:T(8,128)(2,1)S(1)}, "
+        "u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%slice.6), "
+        "channel_id=1, source_target_pairs={{1,0},{2,1},{3,2}}",
+        "  %collective-permute-done = bf16[32,5,224,64]{2,3,1,0} "
+        "collective-permute-done(%collective-permute-start)",
+    ])
+    out = collective_bytes(hlo)
+    assert out["count"] == 1
+    assert out["collective-permute"] == 32 * 5 * 224 * 64 * 2
+
+
 def test_collective_bytes_permute_and_all_to_all():
     hlo = "\n".join([
         "  %cp = bf16[4,256]{1,0} collective-permute(bf16[4,256] %p), "

@@ -168,3 +168,41 @@ def test_pick_w_blk_never_exceeds_explicit_budget():
     assert ops.pick_w_blk(16, 64, target_bytes=8) == 1
     # the implicit device budget keeps its 8-column sublane floor
     assert ops.pick_w_blk(1000, 1 << 20) == 8
+
+
+class _FakeTPU:
+    platform = "tpu"
+
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def test_tpu_backend_refuses_interpret_and_unlowerable_modes(monkeypatch):
+    """On a TPU backend the kernels compile through Mosaic or fail loudly:
+    interpret=True is an error, and a mode without a Mosaic lowering
+    raises before any tracing."""
+    import jax
+    from repro.kernels import ops
+    assert ops.resolve_interpret(None) is True          # CPU: interpreter
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_interpret(None, "fused") is False
+    with pytest.raises(ValueError, match="interpret=True"):
+        ops.resolve_interpret(True, "fused")
+    for mode in ("fused2", "lowered"):
+        with pytest.raises(NotImplementedError, match="Mosaic"):
+            ops.resolve_interpret(None, mode)
+    inp, ker = _rand((1, 6, 6, 2), 0, jnp.float32), \
+        _rand((3, 3, 2, 4), 1, jnp.float32)
+    with pytest.raises(NotImplementedError):
+        mec_conv2d_tpu(inp, ker, mode="fused2")
+
+
+def test_vmem_bytes_unknown_tpu_kind_raises(monkeypatch):
+    import jax
+    from repro.kernels import ops
+    assert ops.vmem_bytes() == ops.INTERPRET_VMEM      # CPU run
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeTPU("TPU v5 lite")])
+    assert ops.vmem_bytes() == 16 << 20
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeTPU("TPU v99")])
+    with pytest.raises(ValueError, match="TPU v99"):
+        ops.vmem_bytes()

@@ -94,7 +94,7 @@ def test_int8_a2a_is_differentiable_and_accurate():
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
+    from jax import shard_map
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("model",))
     x = jax.random.normal(jax.random.key(0), (8, 4, 16))
 

@@ -380,6 +380,31 @@ def test_measured_mode_picks_a_timed_winner(fresh_cache):
     assert "winograd" in eligible_candidates(spec)
 
 
+def test_tpu_candidates_and_pick_follow_the_mosaic_rule():
+    """On TPU only kernels with a Mosaic lowering are candidates, and a
+    geometry the fused kernel cannot take is routed to direct by an
+    explicit predicate that explain() reports."""
+    from repro.launch.costmodel import (pick_conv2d_algorithm,
+                                        tpu_fused_ineligibility)
+    spec = ConvSpec(1, 10, 10, 2, 3, 3, 4, 1, 1)
+    tpu = eligible_candidates(spec, backend="tpu")
+    assert "mec_fused" in tpu
+    assert not {"mec_fused2", "mec_lowered"} & set(tpu)
+    assert {"mec_fused2", "mec_lowered"} <= set(
+        eligible_candidates(spec, backend="cpu"))
+    assert tpu_fused_ineligibility(spec) is None
+    assert pick_conv2d_algorithm(spec, "tpu") == "mec_fused"
+    # o_w = 1000 at k_c = 2048: a 256-column block over 4 blocks would
+    # overrun VMEM, so the checker refuses it
+    wide = ConvSpec(1, 3, 1002, 512, 3, 3, 2048, 1, 1)
+    why = tpu_fused_ineligibility(wide)
+    assert why
+    assert pick_conv2d_algorithm(wide, "tpu") == "direct"
+    plan = plan_conv2d(wide, backend="tpu")
+    assert plan.algorithm == "direct"
+    assert "mec_fused not taken on tpu" in plan.explain()
+
+
 # -------------------------------------------------------------- partitions
 
 def test_plan_records_partition_and_executor_consumes_it(fresh_cache):

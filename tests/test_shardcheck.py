@@ -95,10 +95,10 @@ def test_expected_collectives_match_costmodel():
 
 
 def test_expected_collectives_replica_combine_on_oversized_mesh():
-    """Production-mesh dry-runs: unused mesh axes replicate the cell and
-    GSPMD may shard the backward over them, combining the one gradient
-    that has no modeled psum with an extra (optional) all-reduce."""
-    from repro.analysis.shardcheck import replica_combine_bytes
+    """Mesh axes the partition leaves free replicate the cell; the
+    shard_map transpose sums the one gradient that has no modeled psum
+    over them, and the costmodel prices that all-reduce as required."""
+    from repro.launch.costmodel import replica_combine_bytes
     # spatial: the input gradient pays its local shard bytes
     assert replica_combine_bytes(SPEC, ("spatial",), (2,), 4) == \
         SPEC.i_n * (SPEC.i_h // 2) * SPEC.i_w * SPEC.i_c * 4
@@ -108,17 +108,21 @@ def test_expected_collectives_replica_combine_on_oversized_mesh():
     # any channel composite: both gradients merge into modeled psums
     assert replica_combine_bytes(SPEC, ("batch", "channel"), (2, 2), 4) \
         == 0.0
-    # exact-size mesh (replicated_ways=1): no optional all-reduce at all
-    _, opt, _ = expected_collectives(SPEC, "spatial", 2, 4, "grad")
+    # exact-size mesh (replicated_ways=1): the modeled psum alone
+    req, opt, _ = expected_collectives(SPEC, "spatial", 2, 4, "grad")
+    entry = _costs(SPEC, 2)["spatial"]
+    assert req["all-reduce"] == entry["comm_bytes_bwd_per_device"] - \
+        entry["halo_bytes_per_device"]
     assert opt["all-reduce"] == 0.0
-    _, opt, _ = expected_collectives(SPEC, "spatial", 2, 4, "grad",
-                                     replicated_ways=16)
-    assert opt["all-reduce"] == \
+    req16, opt16, _ = expected_collectives(SPEC, "spatial", 2, 4, "grad",
+                                           replicated_ways=16)
+    assert req16["all-reduce"] == req["all-reduce"] + \
         replica_combine_bytes(SPEC, ("spatial",), (2,), 4)
+    assert opt16["all-reduce"] == 0.0
     # fwd never combines gradients
-    _, opt, _ = expected_collectives(SPEC, "spatial", 2, 4, "fwd",
+    req, _, _ = expected_collectives(SPEC, "spatial", 2, 4, "fwd",
                                      replicated_ways=16)
-    assert opt["all-reduce"] == 0.0
+    assert req["all-reduce"] == 0.0
 
 
 def test_expected_collectives_rejects_bad_inputs():

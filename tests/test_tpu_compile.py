@@ -1,0 +1,78 @@
+"""AOT compiles for a TPU v5e that is described, not attached.
+
+The TPU compiler refuses what the Pallas interpreter accepts (unaligned
+blocks, dynamic slices of loaded values, strided slices), so the main-path
+kernel and the XLA MEC training program are compiled here at paper
+Table-2 widths, batch 32, as the chip would compile them.  The topology
+is described inside a fixture, never at import: only one process may
+hold the TPU library, and every xdist worker imports this file.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.bench.scenarios import layer_spec
+from repro.core.conv_api import conv2d
+
+LAYERS = ("cv9", "cv11", "cv12")
+DTYPES = ("bfloat16", "float32")
+BATCH = 32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # A described chip's executables are written to a compilation cache
+    # but cannot be read back without the chip: keep the cache off here.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _operands(name, dtype, sharding):
+    s = layer_spec(name, batch=BATCH)
+    x = jax.ShapeDtypeStruct((s.i_n, s.i_h, s.i_w, s.i_c), jnp.dtype(dtype),
+                             sharding=sharding)
+    k = jax.ShapeDtypeStruct((s.k_h, s.k_w, s.i_c, s.k_c), jnp.dtype(dtype),
+                             sharding=sharding)
+    return s, x, k
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("name", LAYERS)
+def test_mec_fused_compiles_for_v5e(one_chip, name, dtype):
+    s, x, k = _operands(name, dtype, one_chip)
+    compiled = jax.jit(lambda a, b: conv2d(
+        a, b, stride=(s.s_h, s.s_w), algorithm="mec_fused",
+        interpret=False)).lower(x, k).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("name", LAYERS)
+def test_xla_mec_fwd_grad_compiles_for_v5e(one_chip, name, dtype):
+    s, x, k = _operands(name, dtype, one_chip)
+
+    def loss(a, b):
+        y = conv2d(a, b, stride=(s.s_h, s.s_w), algorithm="mec")
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        x, k).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 0
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes + \
+        mem.output_size_in_bytes < 16 << 30       # fits one v5e chip
+    assert "tpu_custom_call" not in compiled.as_text()
