@@ -60,6 +60,17 @@ ALGORITHMS = ("auto", "direct", "im2col", "fft", "winograd") + MEC_ALGORITHMS
 
 Padding = Union[str, int, Tuple]
 
+# The names the program gives its device work, as they appear in each
+# op's name stack (HLO ``op_name``) and so in a profiler trace: the
+# ``jax.named_scope``s of the whole conv, its padding pass, the kernel
+# wrapper's stride fold and output crop/cast, the two halves of the MEC
+# backward and the optimizer step (``repro.optim.adamw.update``), then the
+# ``pallas_call`` names of the kernels (``repro.kernels``).
+TRACE_SCOPES = ("conv2d", "conv2d_pad", "mec_fold", "conv2d_out",
+                "mec_input_grad", "mec_weight_grad", "adamw_update",
+                "mec_fused", "mec_fused2", "mec_lower", "mec_gemm",
+                "mec_conv1d")
+
 
 def apply_padding(inp: jnp.ndarray, k_h: int, k_w: int, s_h: int, s_w: int,
                   padding: Padding) -> jnp.ndarray:
@@ -72,7 +83,8 @@ def apply_padding(inp: jnp.ndarray, k_h: int, k_w: int, s_h: int, s_w: int,
         if mode == "VALID":
             return inp
         if mode == "SAME":
-            return pad_same(inp, k_h, k_w, s_h, s_w)
+            with jax.named_scope("conv2d_pad"):
+                return pad_same(inp, k_h, k_w, s_h, s_w)
         raise ValueError(f"unknown padding {padding!r}")
     if isinstance(padding, int):
         padding = ((padding, padding), (padding, padding))
@@ -86,7 +98,8 @@ def apply_padding(inp: jnp.ndarray, k_h: int, k_w: int, s_h: int, s_w: int,
         raise ValueError(
             f"padding must be non-negative, got {(p_h, p_w)}; negative "
             "pads (cropping) are not a convolution padding")
-    return jnp.pad(inp, ((0, 0), p_h, p_w, (0, 0)))
+    with jax.named_scope("conv2d_pad"):
+        return jnp.pad(inp, ((0, 0), p_h, p_w, (0, 0)))
 
 
 # ---------------------------------------------------------------------------
@@ -167,10 +180,12 @@ def _mec_bwd(s_h, s_w, _variant, _solution, _interpret, precision, _w_blk,
     # w_blk shape the forward lowering only — the VJP math is identical
     # for every MEC execution path.
     inp, kernel = res
-    d_inp = _mec_input_grad(g, kernel, s_h, s_w, inp.shape[1], inp.shape[2],
-                            precision)
-    d_ker = _mec_weight_grad(inp, g, s_h, s_w, kernel.shape[0],
-                             kernel.shape[1], precision)
+    with jax.named_scope("mec_input_grad"):
+        d_inp = _mec_input_grad(g, kernel, s_h, s_w, inp.shape[1],
+                                inp.shape[2], precision)
+    with jax.named_scope("mec_weight_grad"):
+        d_ker = _mec_weight_grad(inp, g, s_h, s_w, kernel.shape[0],
+                                 kernel.shape[1], precision)
     return d_inp.astype(inp.dtype), d_ker.astype(kernel.dtype)
 
 
@@ -242,42 +257,43 @@ def conv2d(inp: jnp.ndarray, kernel: jnp.ndarray, *, stride=1,
     same model code runs on a laptop and a pod.  partition_axis names the
     mesh axis explicitly (a tuple, paired positionally, for composites).
     """
-    if plan is not None:
-        return _execute_plan(inp, kernel, plan, stride=stride,
-                             padding=padding, interpret=interpret)
+    with jax.named_scope("conv2d"):
+        if plan is not None:
+            return _execute_plan(inp, kernel, plan, stride=stride,
+                                 padding=padding, interpret=interpret)
 
-    if partition != "none":
-        # Lazy import: parallel sits above core; call-time routing keeps
-        # core import-clean (mirrors the plan/costmodel imports below).
-        from repro.parallel.axes import current_rules
-        if partition is not None or current_rules() is not None:
-            from repro.parallel.conv import sharded_conv2d
-            return sharded_conv2d(
-                inp, kernel, stride=stride, padding=padding,
-                algorithm=algorithm, solution=solution,
-                partition=partition or "auto", axis=partition_axis,
-                interpret=interpret, precision=precision)
+        if partition != "none":
+            # Lazy import: parallel sits above core; call-time routing keeps
+            # core import-clean (mirrors the plan/costmodel imports below).
+            from repro.parallel.axes import current_rules
+            if partition is not None or current_rules() is not None:
+                from repro.parallel.conv import sharded_conv2d
+                return sharded_conv2d(
+                    inp, kernel, stride=stride, padding=padding,
+                    algorithm=algorithm, solution=solution,
+                    partition=partition or "auto", axis=partition_axis,
+                    interpret=interpret, precision=precision)
 
-    s_h, s_w = normalize_stride(stride)
-    k_h, k_w = kernel.shape[0], kernel.shape[1]
-    x = apply_padding(inp, k_h, k_w, s_h, s_w, padding)
-    spec = spec_of(x, kernel, (s_h, s_w))
+        s_h, s_w = normalize_stride(stride)
+        k_h, k_w = kernel.shape[0], kernel.shape[1]
+        x = apply_padding(inp, k_h, k_w, s_h, s_w, padding)
+        spec = spec_of(x, kernel, (s_h, s_w))
 
-    algorithm = algorithm.lower()
-    if algorithm not in ALGORITHMS:
-        raise ValueError(
-            f"unknown algorithm {algorithm!r}; expected one of {ALGORITHMS}")
-    w_blk = None
-    if algorithm == "auto":
-        # Bare kwargs resolve through the plan cache (DESIGN.md §7):
-        # process LRU -> on-disk JSON -> the analytic costmodel pick the
-        # pre-planner dispatch made.  Lazy import: plan sits above core.
-        from repro.plan import resolve_cached_plan
-        cached = resolve_cached_plan(spec, dtype=x.dtype)
-        algorithm = cached.algorithm
-        w_blk = cached.w_blk
-    return _dispatch(x, kernel, spec, s_h, s_w, algorithm, solution,
-                     interpret, precision, w_blk)
+        algorithm = algorithm.lower()
+        if algorithm not in ALGORITHMS:
+            raise ValueError(f"unknown algorithm {algorithm!r}; expected "
+                             f"one of {ALGORITHMS}")
+        w_blk = None
+        if algorithm == "auto":
+            # Bare kwargs resolve through the plan cache (DESIGN.md §7):
+            # process LRU -> on-disk JSON -> the analytic costmodel pick the
+            # pre-planner dispatch made.  Lazy import: plan sits above core.
+            from repro.plan import resolve_cached_plan
+            cached = resolve_cached_plan(spec, dtype=x.dtype)
+            algorithm = cached.algorithm
+            w_blk = cached.w_blk
+        return _dispatch(x, kernel, spec, s_h, s_w, algorithm, solution,
+                         interpret, precision, w_blk)
 
 
 def _execute_plan(inp: jnp.ndarray, kernel: jnp.ndarray, plan: "ConvPlan",

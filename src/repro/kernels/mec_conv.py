@@ -65,6 +65,7 @@ def mec_lower_pallas(inp: jnp.ndarray, k_w: int, s_w: int,
     grid = (i_n, i_h_p // h_blk)
     out = pl.pallas_call(
         functools.partial(_lower_kernel, k_w=k_w, s_w=s_w, o_w=o_w),
+        name="mec_lower",
         grid=grid,
         in_specs=[pl.BlockSpec((1, h_blk, i_w, i_c), lambda n, h: (n, h, 0, 0))],
         out_specs=pl.BlockSpec((1, o_w, h_blk, k_w * i_c),
@@ -116,6 +117,7 @@ def mec_gemm_pallas(low: jnp.ndarray, kernel_mat: jnp.ndarray,
     grid = (i_n, o_h, o_w_p // w_blk, k_h)
     out = pl.pallas_call(
         functools.partial(_gemm_kernel, precision=precision),
+        name="mec_gemm",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, w_blk, 1, kwic),
@@ -127,7 +129,8 @@ def mec_gemm_pallas(low: jnp.ndarray, kernel_mat: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((i_n, o_h, o_w_p, k_c), jnp.float32),
         interpret=interpret,
     )(low, kernel_mat)
-    return out[:, :, :o_w, :]
+    with jax.named_scope("conv2d_out"):
+        return out[:, :, :o_w, :]
 
 
 # ---------------------------------------------------------------------------
@@ -227,14 +230,16 @@ def mec_conv_fused2_pallas(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
     # one extra zero block so the h+1 halo view is always in bounds
     need_h = (n_hblocks + 1) * rows_blk
     need_w = s_w * (o_w_p - 1) + k_w
-    inp = jnp.pad(inp, ((0, 0), (0, max(0, need_h - i_h)),
-                        (0, max(0, need_w - i_w)), (0, 0)))
-    kernel_mat = kernel.reshape(k_h, k_w * i_c, k_c)
+    with jax.named_scope("mec_fold"):
+        inp = jnp.pad(inp, ((0, 0), (0, max(0, need_h - i_h)),
+                            (0, max(0, need_w - i_w)), (0, 0)))
+        kernel_mat = kernel.reshape(k_h, k_w * i_c, k_c)
     grid = (i_n, n_hblocks, o_w_p // w_blk, k_h)
     out = pl.pallas_call(
         functools.partial(_fused2_kernel, k_w=k_w, s_w=s_w, s_h=s_h,
                           w_blk=w_blk, oh_blk=oh_blk, halo=halo,
                           precision=precision),
+        name="mec_fused2",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, rows_blk, inp.shape[2], i_c),
@@ -250,7 +255,8 @@ def mec_conv_fused2_pallas(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
         out_shape=jax.ShapeDtypeStruct((i_n, o_h_p, o_w_p, k_c), jnp.float32),
         interpret=interpret,
     )(inp, inp, kernel_mat)
-    return out[:, :o_h, :o_w, :].astype(inp.dtype)
+    with jax.named_scope("conv2d_out"):
+        return out[:, :o_h, :o_w, :].astype(inp.dtype)
 
 
 @functools.partial(jax.jit,
@@ -288,17 +294,19 @@ def mec_conv_fused_pallas(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
     need_w = s_w * i_w2
     # Columns past need_w feed no output; missing ones only meet zero taps
     # or padded output columns.
-    inp = inp[:, :, :need_w, :]
-    if need_w > inp.shape[2]:
-        inp = jnp.pad(inp, ((0, 0), (0, 0), (0, need_w - inp.shape[2]),
-                            (0, 0)))
-    x2 = inp.reshape(i_n, i_h, i_w2, s_w * i_c)
-    k2 = jnp.pad(kernel, ((0, 0), (0, k_q * s_w - k_w), (0, 0), (0, 0)))
-    k2 = k2.reshape(k_h, k_q, s_w * i_c, k_c)
+    with jax.named_scope("mec_fold"):
+        inp = inp[:, :, :need_w, :]
+        if need_w > inp.shape[2]:
+            inp = jnp.pad(inp, ((0, 0), (0, 0),
+                                (0, need_w - inp.shape[2]), (0, 0)))
+        x2 = inp.reshape(i_n, i_h, i_w2, s_w * i_c)
+        k2 = jnp.pad(kernel, ((0, 0), (0, k_q * s_w - k_w), (0, 0), (0, 0)))
+        k2 = k2.reshape(k_h, k_q, s_w * i_c, k_c)
     grid = (i_n, o_h, n_wblk, k_h)
     out = pl.pallas_call(
         functools.partial(_fused_kernel, k_q=k_q, w_blk=w_blk, halo=halo,
                           n_wblk=n_wblk, precision=precision),
+        name="mec_fused",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, i_w2, s_w * i_c),
@@ -311,4 +319,5 @@ def mec_conv_fused_pallas(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
         out_shape=jax.ShapeDtypeStruct((i_n, o_h, o_w_p, k_c), jnp.float32),
         interpret=interpret,
     )(x2, k2)
-    return out[:, :, :o_w, :].astype(inp.dtype)
+    with jax.named_scope("conv2d_out"):
+        return out[:, :, :o_w, :].astype(inp.dtype)

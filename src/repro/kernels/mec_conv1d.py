@@ -49,6 +49,7 @@ def mec_conv1d_pallas(x: jnp.ndarray, kernel: jnp.ndarray,
     grid = (n, t_p // t_blk, c_p // c_blk)
     out = pl.pallas_call(
         functools.partial(_conv1d_kernel, k_w=k_w),
+        name="mec_conv1d",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, t_blk, c_blk), lambda n, i, cc: (n, i, cc)),

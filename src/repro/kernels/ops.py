@@ -181,7 +181,8 @@ def mec_conv2d_tpu(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
     kernel_mat = kernel.reshape(k_h, k_w * i_c, k_c)
     out = mec_gemm_pallas(low, kernel_mat, k_h, s_h, w_blk=w_blk,
                           interpret=interpret, precision=precision)
-    return out.astype(inp.dtype)
+    with jax.named_scope("conv2d_out"):
+        return out.astype(inp.dtype)
 
 
 def mec_conv1d_tpu(x: jnp.ndarray, kernel: jnp.ndarray,

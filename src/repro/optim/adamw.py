@@ -49,29 +49,30 @@ def global_norm(tree) -> jnp.ndarray:
 
 def update(cfg: AdamWConfig, grads, opt_state, params
            ) -> Tuple[Any, Dict[str, Any], Dict[str, jnp.ndarray]]:
-    step = opt_state["step"] + 1
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-9))
-    lr = schedule(cfg, step)
-    b1c = 1 - cfg.b1 ** step.astype(jnp.float32)
-    b2c = 1 - cfg.b2 ** step.astype(jnp.float32)
+    with jax.named_scope("adamw_update"):
+        step = opt_state["step"] + 1
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-9))
+        lr = schedule(cfg, step)
+        b1c = 1 - cfg.b1 ** step.astype(jnp.float32)
+        b2c = 1 - cfg.b2 ** step.astype(jnp.float32)
 
-    def upd(g, m, v, p):
-        g = g.astype(jnp.float32) * scale
-        m2 = cfg.b1 * m + (1 - cfg.b1) * g
-        v2 = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
-        mhat, vhat = m2 / b1c, v2 / b2c
-        delta = mhat / (jnp.sqrt(vhat) + cfg.eps)
-        if p.ndim >= 2:  # decay matrices only
-            delta = delta + cfg.weight_decay * p.astype(jnp.float32)
-        return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m2, v2
+        def upd(g, m, v, p):
+            g = g.astype(jnp.float32) * scale
+            m2 = cfg.b1 * m + (1 - cfg.b1) * g
+            v2 = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
+            mhat, vhat = m2 / b1c, v2 / b2c
+            delta = mhat / (jnp.sqrt(vhat) + cfg.eps)
+            if p.ndim >= 2:  # decay matrices only
+                delta = delta + cfg.weight_decay * p.astype(jnp.float32)
+            return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m2, v2
 
-    out = jax.tree.map(upd, grads, opt_state["m"], opt_state["v"], params)
-    new_params = jax.tree.map(lambda o: o[0], out,
-                              is_leaf=lambda x: isinstance(x, tuple))
-    new_m = jax.tree.map(lambda o: o[1], out,
-                         is_leaf=lambda x: isinstance(x, tuple))
-    new_v = jax.tree.map(lambda o: o[2], out,
-                         is_leaf=lambda x: isinstance(x, tuple))
-    return new_params, {"m": new_m, "v": new_v, "step": step}, \
-        {"grad_norm": gnorm, "lr": lr}
+        out = jax.tree.map(upd, grads, opt_state["m"], opt_state["v"], params)
+        new_params = jax.tree.map(lambda o: o[0], out,
+                                  is_leaf=lambda x: isinstance(x, tuple))
+        new_m = jax.tree.map(lambda o: o[1], out,
+                             is_leaf=lambda x: isinstance(x, tuple))
+        new_v = jax.tree.map(lambda o: o[2], out,
+                             is_leaf=lambda x: isinstance(x, tuple))
+        return new_params, {"m": new_m, "v": new_v, "step": step}, \
+            {"grad_norm": gnorm, "lr": lr}

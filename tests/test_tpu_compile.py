@@ -76,3 +76,20 @@ def test_xla_mec_fwd_grad_compiles_for_v5e(one_chip, name, dtype):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes + \
         mem.output_size_in_bytes < 16 << 30       # fits one v5e chip
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_mosaic_kernel_carries_program_names(one_chip):
+    """On the chip's compile the Mosaic kernel's ``op_name`` holds the
+    ``conv2d`` scope and the kernel's ``pallas_call`` name, which is what
+    a profiler trace reads as its owner."""
+    s, x, k = _operands("cv12", "bfloat16", one_chip)
+    text = jax.jit(lambda a, b: conv2d(
+        a, b, stride=(s.s_h, s.s_w), padding=1, algorithm="mec_fused",
+        interpret=False)).lower(x, k).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "op_name=" in line]
+    assert kernels
+    for line in kernels:
+        name = line.split('op_name="', 1)[1].split('"', 1)[0]
+        assert name.endswith("/conv2d/jit(mec_conv_fused_pallas)/mec_fused/"
+                             "pallas_call"), name
