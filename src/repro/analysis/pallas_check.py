@@ -11,22 +11,23 @@ TPU before anything is timed or cached:
                               precondition, checked without running it).
 ``block-index-out-of-bounds`` a BlockSpec index map addresses a block
                               past the (padded) array extent — e.g. the
-                              shifted-GEMM row ``h*s_h + r`` or the
-                              fused2 ``h+1`` halo view.
+                              shifted-GEMM row ``h*s_h + r``, the
+                              fused2 ``h+1`` halo view, or a fused
+                              element window past its over-run.
 ``grid-not-covering``         the output grid leaves part of the (padded)
                               output unwritten.
 ``unaligned-block-start``     the fused kernel splits a row into several
-                              output-column blocks whose dynamic starts
-                              Mosaic cannot prove sublane-aligned.
+                              output-column blocks whose starts are not
+                              whole sublane tiles of the dtype.
 ``vmem-budget-overrun``       the double-buffered per-step working set
                               (blocks + in-kernel scratch) exceeds the
                               device VMEM (``repro.kernels.ops.vmem_bytes``).
 ``accumulator-overrun``       the f32 accumulator block alone exceeds the
                               :func:`~repro.kernels.ops.accumulator_budget`
                               carve-out ``pick_w_blk`` sizes against
-                              (single-output-row kernels only; fused2's
-                              oh_blk-row accumulator is governed by the
-                              whole-set budget above).
+                              (mec_gemm's row and mec_fused's chunk;
+                              fused2's oh_blk-row accumulator is
+                              governed by the whole-set budget above).
 
 The index-map checks exploit that every map in ``mec_conv`` is monotone
 non-decreasing in each grid coordinate, so evaluating at the grid's max
@@ -245,14 +246,17 @@ def check_geometry(spec, algorithm: str, w_blk: Optional[int],
             scratch_bytes=0, acc_shape=(g_wblk, k_c))
 
     elif algorithm == "mec_fused":
-        _check_fused_v1(spec, w_blk, db, viol, add)
+        _check_fused_v1(spec, w_blk, db, viol, add,
+                        vmem_budget=vmem_budget, acc_budget=acc_budget)
 
     elif algorithm == "mec_fused2":
         halo = k_h - s_h
         oh_blk = min(8, o_h)
         if halo < 0 or halo > s_h * 8:
             # the executor falls back to v1 on these geometries
-            _check_fused_v1(spec, w_blk, db, viol, add)
+            _check_fused_v1(spec, w_blk, db, viol, add,
+                            vmem_budget=vmem_budget,
+                            acc_budget=acc_budget)
         else:
             f_wblk = min(w_blk, o_w)
             pad_h = (-o_h) % oh_blk
@@ -307,44 +311,52 @@ def check_geometry(spec, algorithm: str, w_blk: Optional[int],
 
 
 def _check_fused_v1(spec, w_blk: int, db: int, viol: List[Violation],
-                    add) -> None:
-    i_n, i_h, i_c = spec.i_n, spec.i_h, spec.i_c
-    k_h, k_w, k_c = spec.k_h, spec.k_w, spec.k_c
-    s_h, s_w = spec.s_h, spec.s_w
-    o_h, o_w = spec.o_h, spec.o_w
-    f_wblk = min(w_blk, o_w)
-    o_w_p = _ceil_to(o_w, f_wblk)
-    n_wblk = o_w_p // f_wblk
-    # width folded by s_w into channels: k_q unit-stride taps, plus the
-    # window halo past a block (8-aligned when blocks start dynamically)
-    k_q = -(-k_w // s_w)
-    halo = k_q - 1 if n_wblk == 1 else _ceil_to(k_q - 1, 8)
-    i_w2, c2 = o_w_p + halo, s_w * i_c
-    in_pad = (i_n, i_h, i_w2, c2)
-    grid = (i_n, o_h, n_wblk, k_h)
-    in_blk = (1, 1, i_w2, c2)
-    k_blk = (1, k_q, c2, k_c)
-    o_blk = (1, 1, f_wblk, k_c)
-    out_shape = (i_n, o_h, o_w_p, k_c)
-    # input row h*s_h + r — the fused shifted-window walk
-    _index_bounds("input", "mec_fused", in_blk, in_pad,
-                  lambda n, h, w, r: (n, h * s_h + r, 0, 0), grid, viol)
-    _index_bounds("kernel", "mec_fused", k_blk, (k_h, k_q, c2, k_c),
-                  lambda n, h, w, r: (r, 0, 0, 0), grid, viol)
-    _index_bounds("output", "mec_fused", o_blk, out_shape,
-                  lambda n, h, w, r: (n, h, w, 0), grid, viol)
-    _coverage("mec_fused", o_blk, out_shape,
-              (grid[0], grid[1], grid[2], 1), viol)
-    if n_wblk > 1 and f_wblk % 8:
+                    add, *, vmem_budget: int, acc_budget: int) -> None:
+    from repro.kernels.mec_conv import fused_blocks
+    fb = fused_blocks(spec.i_n, spec.i_h, spec.i_w, spec.i_c, spec.k_h,
+                      spec.k_w, spec.k_c, spec.s_h, spec.s_w,
+                      min(w_blk, spec.o_w), db, acc_budget=acc_budget,
+                      vmem_budget=vmem_budget)
+    grid = fb.grid
+    n_c, n_b, n_h, n_w = grid
+    in_shape = (fb.i_n, fb.i_h2, fb.s_h, fb.i_w2, fb.c2)
+    in_blk = (fb.nb, fb.rows_in, fb.s_h, fb.cols_in, fb.c2)
+    # Input windows are placed by element and may run past the array by
+    # what the last block over-runs: never by a whole window.
+    starts = (fb.nb * (n_b - 1), fb.hb * (n_h - 1), 0,
+              fb.w_blk * (n_w - 1), 0)
+    over = (n_b * fb.nb - fb.i_n, n_h * fb.hb - fb.o_h, 0,
+            (n_w - 1) * fb.w_blk + fb.cols_in - fb.i_w2, 0)
+    for axis, (s, blk, ext, o) in enumerate(
+            zip(starts, in_blk, in_shape, over)):
+        if s + blk > ext + max(o, 0) or s >= ext:
+            viol.append(Violation(
+                "block-index-out-of-bounds", "mec_fused",
+                f"input axis {axis}: window {s}+{blk} over-runs extent "
+                f"{ext} (+{max(o, 0)} over-run)"))
+    k_blk = (fb.k_h, fb.k_q, fb.c2, fb.kc)
+    o_blk = (fb.nb, fb.hb, fb.w_blk, fb.kc)
+    # ragged last image and row blocks: Pallas masks their writes
+    out_pad = (n_b * fb.nb, n_h * fb.hb, n_w * fb.w_blk, fb.k_c)
+    _index_bounds("kernel", "mec_fused", k_blk,
+                  (fb.k_h, fb.k_q, fb.c2, fb.k_c),
+                  lambda c, b, h, w: (0, 0, 0, c), grid, viol)
+    _index_bounds("output", "mec_fused", o_blk, out_pad,
+                  lambda c, b, h, w: (b, h, w, c), grid, viol)
+    _coverage("mec_fused", o_blk, (fb.i_n, fb.o_h, fb.o_w, fb.k_c),
+              (n_b, n_h, n_w, n_c), viol)
+    tile = max(8, 32 // db)
+    if n_w > 1 and fb.w_blk % tile:
         viol.append(Violation(
             "unaligned-block-start", "mec_fused",
-            f"w_blk={f_wblk} splits o_w={o_w} into {n_wblk} blocks whose "
-            "dynamic starts are not sublane (8) aligned"))
-    scratch = (f_wblk + halo) * c2 * db + f_wblk * k_c * _F32
-    add("mec_fused", grid,
-        {"input": (in_blk, db), "kernel": (k_blk, db),
-         "output": (o_blk, _F32)},
-        scratch_bytes=scratch, acc_shape=(f_wblk, k_c))
+            f"w_blk={fb.w_blk} splits o_w={fb.o_w} into {n_w} blocks whose "
+            f"starts are not sublane ({tile}) aligned"))
+    blocks = {"input": (in_blk, db), "kernel": (k_blk, db),
+              "output": (o_blk, db)}
+    # the working set as the picker counts it, in Mosaic's tiles
+    add("mec_fused", grid, blocks,
+        scratch_bytes=fb.vmem_bytes - _blocks_bytes(blocks),
+        acc_shape=(fb.dot_rows, fb.kc))
 
 
 def check_plan(plan, *, vmem_budget: Optional[int] = None,

@@ -241,6 +241,16 @@ class ConvPlan:
         if self.algorithm in _PALLAS_ALGOS:
             lines.append("  (Pallas kernel: lowering stays in VMEM; "
                          "HBM overhead is the direct conv's)")
+            if self.algorithm == "mec_fused":
+                import numpy as np
+                from repro.kernels.mec_conv import fused_blocks
+                from repro.kernels.ops import pick_w_blk
+                w_blk = self.w_blk or pick_w_blk(s.o_w, s.k_c,
+                                                 _warn_env=False)
+                fb = fused_blocks(s.i_n, s.i_h, s.i_w, s.i_c, s.k_h,
+                                  s.k_w, s.k_c, s.s_h, s.s_w, w_blk,
+                                  np.dtype(self.dtype).itemsize)
+                lines.append(f"  mec_fused blocking: {fb.describe()}")
         elif self.backend == "tpu":
             from repro.launch.costmodel import tpu_fused_ineligibility
             why = tpu_fused_ineligibility(s, self.dtype)

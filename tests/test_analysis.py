@@ -92,6 +92,86 @@ def test_pallas_check_fused2_fallback_geometry():
     assert result.kernels[0].name == "mec_fused"
 
 
+# The resnet101_t3 stages as the benchmark runs them (3x3 inputs
+# pre-padded), batch 32: (spec, convs of the stage).
+RESNET_STAGES = {
+    "cv4": (ConvSpec(32, 224, 224, 64, 7, 7, 64, 2, 2), 1),
+    "cv9": (ConvSpec(32, 58, 58, 64, 3, 3, 64), 3),
+    "cv10": (ConvSpec(32, 30, 30, 128, 3, 3, 128), 4),
+    "cv11": (ConvSpec(32, 16, 16, 256, 3, 3, 256), 23),
+    "cv12": (ConvSpec(32, 9, 9, 512, 3, 3, 512), 3),
+}
+
+
+def _pallas_call_geometry(spec, dtype, w_blk):
+    """Grid and block shapes of the pallas_call the fused wrapper traces."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.mec_conv import mec_conv_fused_pallas
+    x = jax.ShapeDtypeStruct((spec.i_n, spec.i_h, spec.i_w, spec.i_c),
+                             jnp.dtype(dtype))
+    k = jax.ShapeDtypeStruct((spec.k_h, spec.k_w, spec.i_c, spec.k_c),
+                             jnp.dtype(dtype))
+    jaxpr = jax.make_jaxpr(lambda a, b: mec_conv_fused_pallas(
+        a, b, (spec.s_h, spec.s_w), w_blk=w_blk, interpret=True))(x, k)
+
+    def find(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", None)
+                inner = getattr(inner, "jaxpr", inner)
+                if inner is not None and hasattr(inner, "eqns"):
+                    found = find(inner)
+                    if found is not None:
+                        return found
+        return None
+
+    gm = find(jaxpr.jaxpr).params["grid_mapping"]
+    blocks = [tuple(getattr(d, "block_size", d) for d in bm.block_shape)
+              for bm in gm.block_mappings]
+    return tuple(gm.grid), dict(zip(("input", "kernel", "output"), blocks))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("stage", sorted(RESNET_STAGES))
+def test_fused_mirror_matches_pallas_call(stage, dtype):
+    """The checker's mec_fused mirror derives its grid and blocks from
+    the kernel's own blocking, and they are the pallas_call's."""
+    from repro.kernels.ops import pick_w_blk
+    spec, _ = RESNET_STAGES[stage]
+    w_blk = pick_w_blk(spec.o_w, spec.k_c, _warn_env=False)
+    result = check_geometry(spec, "mec_fused", w_blk, dtype)
+    assert result.ok, result.render()
+    (kernel,) = result.kernels
+    grid, blocks = _pallas_call_geometry(spec, dtype, w_blk)
+    assert kernel.grid == grid
+    assert kernel.blocks == blocks
+
+
+def test_fused_blocking_engages_on_every_stage():
+    """Many output rows per grid step: each tap's dot has M >= 256, cv4
+    takes >= 8 rows a step, cv11 and cv12 whole planes of several
+    images, and a forward's 34 convs take a few hundred steps (one row
+    a step took 84,224)."""
+    from repro.kernels.mec_conv import fused_blocks
+    from repro.kernels.ops import pick_w_blk
+    steps = 0
+    for name, (s, count) in RESNET_STAGES.items():
+        fb = fused_blocks(s.i_n, s.i_h, s.i_w, s.i_c, s.k_h, s.k_w, s.k_c,
+                          s.s_h, s.s_w,
+                          pick_w_blk(s.o_w, s.k_c, _warn_env=False), 2)
+        assert fb.dot_rows >= 256, (name, fb.describe())
+        assert fb.padded_flop_share < 0.6, (name, fb.describe())
+        steps += count * fb.steps
+        if name == "cv4":
+            assert fb.hb >= 8, fb.describe()
+        if name in ("cv11", "cv12"):
+            assert fb.hb == s.o_h and fb.nb > 1, fb.describe()
+    assert steps < 1000, steps
+
+
 def test_plan_conv2d_never_returns_rejected_pallas_plan(monkeypatch):
     """The planner wiring: a Pallas pick whose geometry fails the static
     check raises at plan time instead of faulting at execute time."""

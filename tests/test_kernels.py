@@ -84,6 +84,66 @@ def test_mec_fused_kernel_property(ih, iw, ic, kh, kw, kc, sh, sw):
                                rtol=2e-4, atol=2e-4)
 
 
+# (name, input (n, i_h, i_w, i_c), kernel (k_h, k_w, k_c), stride, w_blk,
+#  what the blocking must show) — w_blk None is the planner's pick_w_blk.
+FUSED_BLOCKING = [
+    # the resnet101_t3 stages (pre-padded 3x3 inputs) at batch 2
+    ("cv4", (2, 224, 224, 64), (7, 7, 64), 2, None, "rows"),
+    ("cv9", (2, 58, 58, 64), (3, 3, 64), 1, None, "plane"),
+    ("cv10", (2, 30, 30, 128), (3, 3, 128), 1, None, "images"),
+    ("cv11", (2, 16, 16, 256), (3, 3, 256), 1, None, "images"),
+    ("cv12", (2, 9, 9, 512), (3, 3, 512), 1, None, "images"),
+    # o_h = 21 in row blocks of 11: the last block runs past the rows
+    ("ragged_rows", (1, 23, 66, 2), (3, 3, 512), 1, None, "ragged_rows"),
+    # 11 images in blocks of 6: the last block runs past the batch
+    ("ragged_images", (11, 10, 18, 2), (3, 3, 512), 1, None,
+     "ragged_images"),
+    ("7x7_s2", (2, 29, 31, 8), (7, 7, 16), 2, None, "plane"),
+    # o_w = 38 in 16-column blocks
+    ("w_blk_lt_o_w", (2, 9, 40, 4), (3, 3, 8), 1, 16, "columns"),
+    # whisper's conv1d as (time, 1): the unit width is squeezed
+    ("conv1d", (2, 40, 1, 8), (3, 1, 16), 1, None, "squeeze"),
+    ("conv1d_s2", (2, 41, 1, 8), (3, 1, 16), (2, 1), None, "squeeze"),
+]
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("case", FUSED_BLOCKING, ids=[c[0] for c in
+                                                      FUSED_BLOCKING])
+def test_mec_fused_blocking_matches_direct(case, dtype):
+    """The fused kernel's row, image and column blocks (fused_blocks)
+    give direct's answer at the resnet101_t3 stage shapes and at every
+    edge of the blocking, in the output dtype."""
+    from repro.kernels.mec_conv import fused_blocks
+    from repro.kernels.ops import pick_w_blk
+    _, (n, ih, iw, ic), (kh, kw, kc), s, w_blk, shows = case
+    s_h, s_w = (s, s) if isinstance(s, int) else s
+    o_h, o_w = (ih - kh) // s_h + 1, (iw - kw) // s_w + 1
+    w_blk = w_blk or pick_w_blk(o_w, kc)
+    fb = fused_blocks(n, ih, iw, ic, kh, kw, kc, s_h, s_w, w_blk,
+                      jnp.dtype(dtype).itemsize)
+    n_c, n_b, n_h, n_w = fb.grid
+    assert {
+        "rows": fb.nb == 1 and 8 <= fb.hb < o_h,
+        "plane": fb.hb == o_h and n_h == 1,
+        "images": fb.nb == n and fb.hb == o_h,
+        "ragged_rows": n_h > 1 and o_h % fb.hb != 0,
+        "ragged_images": n_b > 1 and n % fb.nb != 0,
+        "columns": n_w > 1 and fb.w_blk == w_blk,
+        "squeeze": fb.squeeze and fb.o_w == o_h and fb.dot_rows >= o_h,
+    }[shows], fb.describe()
+    inp = _rand((n, ih, iw, ic), 11, dtype)
+    ker = _rand((kh, kw, ic, kc), 12, dtype) / np.sqrt(kh * kw * ic)
+    oracle = ref.conv2d_ref(inp.astype(jnp.float32),
+                            ker.astype(jnp.float32), (s_h, s_w))
+    out = mec_conv2d_tpu(inp, ker, (s_h, s_w), mode="fused",
+                         interpret=True, w_blk=w_blk)
+    assert out.shape == oracle.shape and out.dtype == dtype
+    tol = 2e-4 if dtype == jnp.float32 else 4e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(oracle), rtol=tol, atol=tol)
+
+
 def test_lowered_gemm_matches_fused():
     """The two kernel modes are numerically identical paths."""
     inp = _rand((2, 14, 14, 4), 7, jnp.float32)

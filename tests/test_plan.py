@@ -394,15 +394,36 @@ def test_tpu_candidates_and_pick_follow_the_mosaic_rule():
         eligible_candidates(spec, backend="cpu"))
     assert tpu_fused_ineligibility(spec) is None
     assert pick_conv2d_algorithm(spec, "tpu") == "mec_fused"
-    # o_w = 1000 at k_c = 2048: a 256-column block over 4 blocks would
-    # overrun VMEM, so the checker refuses it
-    wide = ConvSpec(1, 3, 1002, 512, 3, 3, 2048, 1, 1)
+    # 4096 input channels: even one 128-channel block of the 3x3
+    # kernel, double-buffered, overruns VMEM, so the checker refuses it
+    wide = ConvSpec(1, 3, 1002, 4096, 3, 3, 2048, 1, 1)
     why = tpu_fused_ineligibility(wide)
     assert why
     assert pick_conv2d_algorithm(wide, "tpu") == "direct"
     plan = plan_conv2d(wide, backend="tpu")
     assert plan.algorithm == "direct"
     assert "mec_fused not taken on tpu" in plan.explain()
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_tpu_plans_mec_fused_for_table2_and_resnet_stages(dtype):
+    """Every paper Table-2 layer and every resnet101_t3 stage, at batch
+    32, stays eligible for the fused kernel on TPU, and explain() prints
+    its blocking."""
+    from repro.bench.scenarios import CV_LAYERS, layer_spec
+    from repro.launch.costmodel import tpu_fused_ineligibility
+    specs = [layer_spec(name, batch=32) for name in CV_LAYERS] + [
+        ConvSpec(32, 224, 224, 64, 7, 7, 64, 2, 2),
+        ConvSpec(32, 58, 58, 64, 3, 3, 64),
+        ConvSpec(32, 30, 30, 128, 3, 3, 128),
+        ConvSpec(32, 16, 16, 256, 3, 3, 256),
+        ConvSpec(32, 9, 9, 512, 3, 3, 512)]
+    for spec in specs:
+        assert tpu_fused_ineligibility(spec, dtype) is None, spec
+    plan = plan_conv2d(specs[-1], backend="tpu", dtype=dtype)
+    assert plan.algorithm == "mec_fused"
+    assert "mec_fused blocking:" in plan.explain()
+    assert "image(s) x 7 row(s)" in plan.explain()
 
 
 # -------------------------------------------------------------- partitions

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from repro.bench.scenarios import layer_spec
 from repro.core.conv_api import conv2d
 
-LAYERS = ("cv9", "cv11", "cv12")
+LAYERS = ("cv4", "cv9", "cv10", "cv11", "cv12")
 DTYPES = ("bfloat16", "float32")
 BATCH = 32
 
