@@ -5,6 +5,7 @@
 started on and prints one JSON result line.  Everything a cell needs is
 found by name: ``configs/<config>.json`` (sizes) beside
 ``configs/<config>.py`` (the plain float32 reference),
-``traffic/<mix>.json`` (the load) and ``metrics/<metric>.py`` (one
-per-layer reader each).
+``systems/<system>.py`` (what the config's ``system`` runs, with its
+control, faults and test size), ``traffic/<mix>.json`` (the load) and
+``metrics/<metric>.py`` (one per-layer reader each).
 """

@@ -18,10 +18,6 @@ from typing import Dict, List, Optional
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 HERE = ROOT / "chipbench"
 
-# A config's ``system`` -> the module that builds and drives it.
-SYSTEMS = {"conv_chain": "chipbench.convnet",
-           "whisper_frontend": "chipbench.frontend"}
-
 
 class NoChip(RuntimeError):
     """JAX found no TPU, or fewer chips than the cell asks for."""
@@ -196,11 +192,12 @@ class Window:
         return self.t1 - self.t0
 
     def reduce(self):
-        """Reduce the trace (once), then delete it."""
-        from chipbench import trace
+        """Reduce the trace (once), with device time by the program's
+        scopes, then delete it."""
+        from chipbench import scopes
         if self.trace and self.summary is None:
             try:
-                self.summary = trace.load(self._dir)
+                self.summary = scopes.load(self._dir)
             finally:
                 shutil.rmtree(self._dir, ignore_errors=True)
         return self.summary
@@ -272,6 +269,11 @@ def result_line(cell: Cell, run: Run, device: Dict, setup_s: float,
         summary = run.window.reduce()
         metrics = read_per_layer(cell, run, device)
         device.update(busy_s=summary.busy_s, window_s=summary.window_s)
+        # The line keeps the ops and the gaps; device seconds by the
+        # program's scopes go to stderr.
+        breakdown = summary.breakdown()
+        run.notes.append("[scopes] " + ", ".join(
+            f"{p} {s!r} s" for p, s in breakdown.pop("scopes")))
     else:
         metrics = {}
         readings = dict(run.e2e, setup_s=setup_s,
@@ -282,7 +284,7 @@ def result_line(cell: Cell, run: Run, device: Dict, setup_s: float,
     line = {"correct": run.correct, "attempted": run.attempted,
             "failed": run.failed, "metrics": metrics, "device": device}
     if trace:
-        line["breakdown"] = run.window.reduce().breakdown()
+        line["breakdown"] = breakdown
     # A non-finite reading is written as text, so the line stays JSON.
     line["checks"] = {c.name: {"value": c.value if math.isfinite(c.value)
                                else str(c.value), "limit": c.limit}
@@ -294,10 +296,10 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
             started: float, require_tpu: bool = True):
     """Run a loaded cell once: set-up, the window, the check.  Returns
     the result line and the run; ``started`` is when set-up began."""
-    import importlib
+    from chipbench import systems
     window = Window(trace, seconds)
     device = device_info(cell.chips, require_tpu)
-    system = importlib.import_module(SYSTEMS[cell.config["system"]])
+    system = systems.load(cell.config["system"])
     window.mark("device")
     run = system.run(cell, seed, seconds, window)
     setup_s = window.t0 - started

@@ -7,10 +7,10 @@
 For each seed of ``--seeds``, a whole run of the cell (short window, the
 cell's own load) and its compared numbers: the lower readings.  For each
 of ``--control-seeds``, the precision control's numbers (``control.py``):
-the upper readings.  With ``--fault``, runs with that fault planted
-(``faults.py``).  One JSON line per reading, then a summary line: each
-number's largest program reading and smallest control or fault reading.
-The benchmark's own runs never run this.
+the upper readings.  With ``--fault``, runs with that fault of the
+cell's system planted (its ``FAULTS``).  One JSON line per reading, then
+a summary line: each number's largest program reading and smallest
+control or fault reading.  The benchmark's own runs never run this.
 """
 import json
 import sys

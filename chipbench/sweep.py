@@ -4,12 +4,13 @@ chip:
     python3 chipbench/sweep.py --workload <cell> --seconds 10 \
         --rates 40,60,80,100
 
-Builds the cell's service once, then offers each rate for ``--seconds``
-and prints one JSON line per rate: latency percentiles, and whether the
-backlog grew (the last fifth of requests waited over twice as long as the
-first fifth).  The knee is the highest rate whose backlog did not grow;
-a cell's mix offers a fixed share of it.  The benchmark's own runs never
-run this.
+Builds the cell's service once (its system's ``build``, ``make_inputs``
+and ``serve``, as ``systems/whisper_frontend.py`` has them), then offers
+each rate for ``--seconds`` and prints one JSON line per rate: latency
+percentiles, and whether the backlog grew (the last fifth of requests
+waited over twice as long as the first fifth).  The knee is the highest
+rate whose backlog did not grow; a cell's mix offers a fixed share of
+it.  The benchmark's own runs never run this.
 """
 import json
 import sys
@@ -29,16 +30,17 @@ def main(argv=None) -> int:
     runmod.setup_caches()
     import numpy as np
 
-    from chipbench import bench, frontend, traffic
+    from chipbench import bench, systems, traffic
     cell = bench.load_cell(args.workload)
+    system = systems.load(cell.config["system"])
     bench.device_info(cell.chips)
-    serve = frontend.build(cell, args.seed)
-    inputs = frontend.make_inputs(cell, args.seed)
+    serve = system.build(cell, args.seed)
+    inputs = system.make_inputs(cell, args.seed)
     for rate in (float(r) for r in args.rates.split(",")):
         mix = dict(cell.traffic, rate_per_s=rate)
         schedule = traffic.open_loop_schedule(mix, args.seed, args.seconds)
-        done = frontend.serve(serve, inputs, schedule, [],
-                              bench.Window(False, args.seconds))
+        done = system.serve(serve, inputs, schedule, [],
+                            bench.Window(False, args.seconds))
         lat = done.latency_s * 1e3
         fifth = max(1, len(lat) // 5)
         first, last = lat[:fifth].mean(), lat[-fifth:].mean()

@@ -1,7 +1,8 @@
 """The ``conv_chain`` system: a configuration's stages as chains of convs
 through the repo's layer entry points (``plan_conv2d_layer`` once per
 layer, then ``conv2d_layer(plan=)``, ReLU after each), driven as a closed
-loop of forward passes or of AdamW training steps.
+loop of forward passes (traffic ``work`` ``forward``) or of AdamW
+training steps (``train``).
 
 Weights and inputs are made on the device from the seed, in one jitted
 call each.  Plans come from the program's default policy, never pinned to
@@ -10,14 +11,17 @@ an algorithm.
 from __future__ import annotations
 
 import collections
+import copy
 import functools
+import sys
 import time
 from typing import Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
 
-from chipbench import bench, compare, yardstick
+from chipbench import bench, compare, faults, yardstick
+from chipbench.control import CONTROL
 
 F32 = jnp.float32
 
@@ -303,3 +307,99 @@ def run_train(cell, seed, seconds, window) -> bench.Run:
             f"[train] program losses {prog['losses']}, reference "
             f"{want['losses']}"],
         window=window)
+
+
+# ---------------------------------------------------------------- control
+
+def control(cell: bench.Cell, seed: int, seconds: float) -> Dict[str, float]:
+    """The reference in float8 against the reference in float32, on the
+    weights and batches a run with this seed checks."""
+    cfg, tr = cell.config, cell.traffic
+    stages, ref = cfg["stages"], bench.reference_of(cfg)
+    if tr["work"] == "forward":
+        params = make_params(bench.key(seed, 0), stages, cfg["dtype"])
+        xs = make_batches(bench.key(seed, 1), stages, tr["batch"],
+                          tr["batches_held"], cfg["dtype"])[0]
+        f = jax.jit(lambda p, x, r: ref.forward(p, x, stages, r),
+                    static_argnums=2)
+        want, got = f(params, xs, None), f(params, xs, CONTROL)
+        return {f"fwd.{st['layer']}": compare.rel_l2(g, w)
+                for st, g, w in zip(stages, got, want)}
+    if tr["work"] == "train":
+        params = make_params(bench.key(seed, 0), stages, F32)
+        batches = make_batches(
+            bench.key(seed, 1), stages, tr["batch"], tr["batches_held"],
+            cfg["dtype"], targets=True)[:tr["checked_steps"]]
+        want = ref.train(params, batches, tr["optimizer"], stages)
+        got = ref.train(params, batches, tr["optimizer"], stages, CONTROL)
+        return compare.train_checks(got, want)
+    raise ValueError(f"conv_chain has no work {tr['work']!r}")
+
+
+# ----------------------------------------------------------------- faults
+
+def _altered_forward(build):
+    """The first stage's output for the first image zeroed."""
+    def build_altered(stages, plans):
+        fwd = build(stages, plans)
+
+        @jax.jit
+        def altered(params, xs):
+            outs = fwd(params, xs)
+            return [outs[0].at[0].set(0)] + list(outs[1:])
+        return altered
+    return build_altered
+
+
+def _unchanged(_build):
+    def build_unchanged(stages, plans, opt_cfg):
+        loss = functools.partial(loss_fn, stages=stages, plans=plans)
+        return jax.jit(lambda p, o, xs, ts: (p, o, loss(p, xs, ts)))
+    return build_unchanged
+
+
+def _half(_build):
+    def build_half(stages, plans, opt_cfg):
+        h = plans[0].spec.i_n // 2
+        step = train_step_fn(
+            stages, plan_layers(stages, h, plans[0].dtype), opt_cfg)
+
+        def half(p, o, xs, ts):
+            return step(p, o, [x[:h] for x in xs], [t[:h] for t in ts])
+        return jax.jit(half, donate_argnums=(0, 1))
+    return build_half
+
+
+_THIS = sys.modules[__name__]
+FAULTS = {
+    "answer_altered": ("forward", functools.partial(
+        faults.swap, _THIS, "build_forward", _altered_forward)),
+    "state_unchanged": ("train", functools.partial(
+        faults.swap, _THIS, "build_train_step", _unchanged)),
+    "half_batch": ("train", functools.partial(
+        faults.swap, _THIS, "build_train_step", _half)),
+}
+
+
+# -------------------------------------------------------------- test size
+
+# Two stages at tiny widths: a strided 7x7 VALID conv and a chain of three
+# padded 3x3 convs.
+TINY_STAGES = [
+    {"layer": "cv4", "count": 1, "i_h": 20, "i_w": 20, "i_c": 8, "k_h": 7,
+     "k_w": 7, "o_c": 8, "stride": 2, "pad": 0},
+    {"layer": "cv9", "count": 3, "i_h": 8, "i_w": 8, "i_c": 8, "k_h": 3,
+     "k_w": 3, "o_c": 8, "stride": 1, "pad": 1},
+]
+
+
+def tiny(config: Dict, traffic: Dict):
+    """``TINY_STAGES`` at batch 4, with the configuration's limits for
+    those stages."""
+    cfg, tr = copy.deepcopy(config), dict(traffic)
+    cfg["stages"] = TINY_STAGES
+    keep = {f"fwd.{st['layer']}" for st in TINY_STAGES}
+    cfg["limits"]["forward"] = {k: v for k, v in
+                                cfg["limits"]["forward"].items() if k in keep}
+    tr.update(batch=4, batches_held=4)
+    return cfg, tr

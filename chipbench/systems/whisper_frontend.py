@@ -1,10 +1,17 @@
 """The ``whisper_frontend`` system: the whisper conv frontend served by
 ``repro.serving.conv_service.whisper_frontend_service``, each request
 through its two ``ConvService.execute`` calls, under an open loop of
-arrivals from the traffic generator."""
+arrivals from the traffic generator (traffic ``work`` ``serve``).
+
+Besides the contract of ``chipbench/systems``, ``build``, ``make_inputs``
+and ``serve`` drive an open loop at any rate (``chipbench/sweep.py``).
+"""
 from __future__ import annotations
 
+import copy
 import dataclasses
+import functools
+import sys
 import time
 from typing import Dict, List
 
@@ -12,7 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import bench, compare, traffic
+from chipbench import bench, compare, faults, traffic
+from chipbench.control import CONTROL
 
 SPIN_S = 0.0005     # the last stretch before a request is due is spun
 
@@ -134,3 +142,49 @@ def run(cell: bench.Cell, seed: int, seconds: float,
                f"[serve] checked {len(values)} requests, widest rel_l2 "
                f"{max(values):.4e}"],
         window=window)
+
+
+def control(cell: bench.Cell, seed: int, seconds: float) -> Dict[str, float]:
+    """The reference in float8 against the reference in float32, on the
+    requests a run with this seed checks."""
+    cfg, tr = cell.config, cell.traffic
+    if tr["work"] != "serve":
+        raise ValueError(f"whisper_frontend has no work {tr['work']!r}")
+    ref = bench.reference_of(cfg)
+    schedule = traffic.open_loop_schedule(tr, seed, seconds)
+    checked = traffic.checked_sample(schedule, tr["checked_requests"], seed)
+    inputs = make_inputs(cell, seed)
+    w1, w2 = ref.weights(bench.key(seed, 0), cfg)
+    f = jax.jit(ref.frontend, static_argnums=3)
+    worst = 0.0
+    for i in checked:
+        r = schedule[i]
+        mel = inputs[r.windows][r.slot]
+        worst = max(worst, compare.rel_l2(f(mel, w1, w2, CONTROL),
+                                          f(mel, w1, w2, None)))
+    return {"serve.rel_l2": worst}
+
+
+def _altered_service(build):
+    """Each request's last window zeroed."""
+    def build_altered(cell, seed):
+        serve = build(cell, seed)
+        return lambda mel: serve(mel).at[-1].set(0)
+    return build_altered
+
+
+FAULTS = {"answer_altered": ("serve", functools.partial(
+    faults.swap, sys.modules[__name__], "build", _altered_service))}
+
+TINY_FRAMES = 40
+
+
+def tiny(config: Dict, traffic: Dict):
+    """8 mel bins into 16 channels, 40-frame windows, at 20 requests a
+    second."""
+    cfg, tr = copy.deepcopy(config), dict(traffic)
+    cfg.update(num_mel_bins=8, d_model=16)
+    tr.update(frames_per_window=TINY_FRAMES, rate_per_s=20.0,
+              checked_requests=4, inputs_held=2,
+              classes=[[n, TINY_FRAMES, 1] for n in (1, 2, 4, 8)])
+    return cfg, tr

@@ -1,6 +1,5 @@
 """Fixtures of the chip benchmark's tests: its cells at tiny sizes, run on
 the CPU with the harness's look for a chip skipped."""
-import copy
 import pathlib
 import sys
 
@@ -10,23 +9,45 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-# Two stages of resnet101_t3 at tiny widths: a strided 7x7 VALID conv and
-# a chain of three padded 3x3 convs.
-TINY_STAGES = [
-    {"layer": "cv4", "count": 1, "i_h": 20, "i_w": 20, "i_c": 8, "k_h": 7,
-     "k_w": 7, "o_c": 8, "stride": 2, "pad": 0},
-    {"layer": "cv9", "count": 3, "i_h": 8, "i_w": 8, "i_c": 8, "k_h": 3,
-     "k_w": 3, "o_c": 8, "stride": 1, "pad": 1},
-]
-TINY_FRAMES = 40
-
-
 # A cell kept under chipbench/ for a later benchmark PR, not yet in
 # BENCHMARK.json: its files, chips and end-to-end metrics.
 KEPT = {"whisper_tiny_fe.serve_poisson": (1, [
     {"name": "serve_p95_ms", "unit": "ms"}, {"name": "peak_hbm_mb",
                                              "unit": "MB"},
     {"name": "setup_s", "unit": "s"}])}
+
+
+def names():
+    """The cells of BENCHMARK.json, then those of ``KEPT``."""
+    import json
+
+    from chipbench import bench
+    bench_json = json.loads((bench.ROOT / "BENCHMARK.json").read_text())
+    return [w["name"] for w in bench_json["workloads"]] + list(KEPT)
+
+
+def fault_cases():
+    """(cell, fault) for each fault of the cell's system that its
+    traffic's work can have."""
+    from chipbench import systems
+    cases = []
+    for name in names():
+        cell = load(name)
+        for fault, (work, _) in systems.load(
+                cell.config["system"]).FAULTS.items():
+            if work == cell.traffic["work"]:
+                cases.append((name, fault))
+    return cases
+
+
+def pytest_generate_tests(metafunc):
+    """A test that takes ``cell_name`` runs for every cell of ``names()``;
+    one that also takes ``fault``, for every case of ``fault_cases()``."""
+    args = metafunc.fixturenames
+    if "cell_name" in args and "fault" in args:
+        metafunc.parametrize("cell_name,fault", fault_cases())
+    elif "cell_name" in args:
+        metafunc.parametrize("cell_name", names())
 
 
 def load(name: str):
@@ -44,23 +65,12 @@ def load(name: str):
 
 
 def tiny(name: str):
-    """The cell ``name`` at tiny widths, with its configuration's own
-    limits."""
+    """The cell ``name`` at its system's test size, with its
+    configuration's own limits."""
+    from chipbench import systems
     cell = load(name)
-    cfg, tr = copy.deepcopy(cell.config), dict(cell.traffic)
-    if cfg["system"] == "conv_chain":
-        cfg["stages"] = TINY_STAGES
-        keep = {f"fwd.{st['layer']}" for st in TINY_STAGES}
-        cfg["limits"]["forward"] = {k: v for k, v in
-                                    cfg["limits"]["forward"].items()
-                                    if k in keep}
-        tr.update(batch=4, batches_held=4)
-    else:
-        cfg.update(num_mel_bins=8, d_model=16)
-        tr.update(frames_per_window=TINY_FRAMES, rate_per_s=20.0,
-                  checked_requests=4, inputs_held=2,
-                  classes=[[n, TINY_FRAMES, 1] for n in (1, 2, 4, 8)])
-    cell.config, cell.traffic = cfg, tr
+    system = systems.load(cell.config["system"])
+    cell.config, cell.traffic = system.tiny(cell.config, cell.traffic)
     return cell
 
 
