@@ -65,11 +65,13 @@ Padding = Union[str, int, Tuple]
 # ``jax.named_scope``s of the whole conv, its padding pass, the kernel
 # wrapper's stride fold and output crop/cast, the two halves of the MEC
 # backward and the optimizer step (``repro.optim.adamw.update``), then the
-# ``pallas_call`` names of the kernels (``repro.kernels``).
+# ``pallas_call`` names of the kernels (``repro.kernels``), then the
+# ResNet's (``repro.models``): every batch norm, every 1x1 conv call and
+# the head (pool, classifier and loss).
 TRACE_SCOPES = ("conv2d", "conv2d_pad", "mec_fold", "conv2d_out",
                 "mec_input_grad", "mec_weight_grad", "adamw_update",
                 "mec_fused", "mec_fused2", "mec_lower", "mec_gemm",
-                "mec_conv1d")
+                "mec_conv1d", "batch_norm", "pointwise", "head")
 
 
 def apply_padding(inp: jnp.ndarray, k_h: int, k_w: int, s_h: int, s_w: int,

@@ -32,6 +32,37 @@ def rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     return (x32 * lax.rsqrt(var + eps)).astype(x.dtype) * w
 
 
+# torchvision's batch norm: eps, and the share of the way the running
+# statistics move to a batch's.
+BN_EPS = 1e-5
+BN_MOMENTUM = 0.1
+
+
+def batch_norm(x: jnp.ndarray, p: dict, stats: dict):
+    """Training-mode batch norm over every axis but the last (channels),
+    in float32; returns the normalised ``x`` in its own dtype and the
+    running statistics as torchvision keeps them.
+
+    ``p`` holds ``scale`` and ``bias``, ``stats`` the running ``mean`` and
+    ``var``.  The batch's mean and biased variance normalise ``x``; the
+    running statistics move ``BN_MOMENTUM`` of the way to the batch's mean
+    and unbiased variance."""
+    with jax.named_scope("batch_norm"):
+        x32 = x.astype(jnp.float32)
+        axes = tuple(range(x.ndim - 1))
+        mean = jnp.mean(x32, axis=axes)
+        var = jnp.mean(jnp.square(x32 - mean), axis=axes)
+        n = x32.size // x32.shape[-1]
+        keep = 1 - BN_MOMENTUM
+        stats = {"mean": keep * stats["mean"] +
+                 BN_MOMENTUM * lax.stop_gradient(mean),
+                 "var": keep * stats["var"] +
+                 BN_MOMENTUM * lax.stop_gradient(var) * n / (n - 1)}
+        y = (x32 - mean) * (lax.rsqrt(var + BN_EPS) * p["scale"]) + \
+            p["bias"]
+        return y.astype(x.dtype), stats
+
+
 def linear(x: jnp.ndarray, p: dict) -> jnp.ndarray:
     y = jnp.einsum("...d,df->...f", x, p["w"].astype(x.dtype),
                    preferred_element_type=jnp.float32).astype(x.dtype)

@@ -386,7 +386,7 @@ def test_tpu_candidates_and_pick_follow_the_mosaic_rule():
     explicit predicate that explain() reports."""
     from repro.launch.costmodel import (pick_conv2d_algorithm,
                                         tpu_fused_ineligibility)
-    spec = ConvSpec(1, 10, 10, 2, 3, 3, 4, 1, 1)
+    spec = ConvSpec(1, 10, 10, 4, 3, 3, 4, 1, 1)
     tpu = eligible_candidates(spec, backend="tpu")
     assert "mec_fused" in tpu
     assert not {"mec_fused2", "mec_lowered"} & set(tpu)
@@ -407,12 +407,20 @@ def test_tpu_candidates_and_pick_follow_the_mosaic_rule():
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_tpu_plans_mec_fused_for_table2_and_resnet_stages(dtype):
-    """Every paper Table-2 layer and every resnet101_t3 stage, at batch
-    32, stays eligible for the fused kernel on TPU, and explain() prints
-    its blocking."""
+    """Every paper Table-2 layer of more than 3 input channels and every
+    resnet101_t3 stage, at batch 32, stays eligible for the fused kernel
+    on TPU, and explain() prints its blocking; the 3-channel layers go
+    to XLA's conv."""
     from repro.bench.scenarios import CV_LAYERS, layer_spec
-    from repro.launch.costmodel import tpu_fused_ineligibility
-    specs = [layer_spec(name, batch=32) for name in CV_LAYERS] + [
+    from repro.launch.costmodel import (FUSED_MIN_CHANNELS,
+                                        tpu_fused_ineligibility)
+    table2 = [layer_spec(name, batch=32) for name in CV_LAYERS]
+    for spec in table2:
+        if spec.i_c < FUSED_MIN_CHANNELS:
+            assert "input channel" in tpu_fused_ineligibility(spec, dtype)
+            assert plan_conv2d(spec, backend="tpu", dtype=dtype).algorithm \
+                == "direct"
+    specs = [s for s in table2 if s.i_c >= FUSED_MIN_CHANNELS] + [
         ConvSpec(32, 224, 224, 64, 7, 7, 64, 2, 2),
         ConvSpec(32, 58, 58, 64, 3, 3, 64),
         ConvSpec(32, 30, 30, 128, 3, 3, 128),
@@ -424,6 +432,27 @@ def test_tpu_plans_mec_fused_for_table2_and_resnet_stages(dtype):
     assert plan.algorithm == "mec_fused"
     assert "mec_fused blocking:" in plan.explain()
     assert "image(s) x 7 row(s)" in plan.explain()
+
+
+def test_tpu_sends_3_channel_inputs_to_direct():
+    """The ResNet stem (7x7/2 on 224 px of 3 channels) goes to XLA's conv
+    on TPU, with the channel rule as its reason; cv4, the same kernel on
+    64 channels, and a 4-channel input keep the fused kernel."""
+    from repro.launch.costmodel import (FUSED_MIN_CHANNELS,
+                                        pick_conv2d_algorithm,
+                                        tpu_fused_ineligibility)
+    assert FUSED_MIN_CHANNELS == 4
+    stem = ConvSpec(32, 230, 230, 3, 7, 7, 64, 2, 2)
+    assert pick_conv2d_algorithm(stem, "tpu", dtype="bfloat16") == "direct"
+    why = tpu_fused_ineligibility(stem, "bfloat16")
+    assert why.startswith("3 input channel(s), under 4")
+    assert why in plan_conv2d(stem, backend="tpu",
+                              dtype="bfloat16").explain()
+    cv4 = ConvSpec(32, 224, 224, 64, 7, 7, 64, 2, 2)
+    assert pick_conv2d_algorithm(cv4, "tpu", dtype="bfloat16") == \
+        "mec_fused"
+    four = ConvSpec(32, 230, 230, 4, 7, 7, 64, 2, 2)
+    assert tpu_fused_ineligibility(four, "bfloat16") is None
 
 
 # -------------------------------------------------------------- partitions

@@ -93,3 +93,41 @@ def test_mosaic_kernel_carries_program_names(one_chip):
         name = line.split('op_name="', 1)[1].split('"', 1)[0]
         assert name.endswith("/conv2d/jit(mec_conv_fused_pallas)/mec_fused/"
                              "pallas_call"), name
+
+
+# The geometries the whole ResNet-101 adds to ``LAYERS``, at batch 32:
+# (input h = w, input channels, kernel, output channels, stride, pad).
+RESNET = {
+    "stem": (224, 3, 7, 64, 2, 3),
+    "s2_3x3_56": (56, 128, 3, 128, 2, 1),
+    "s2_3x3_28": (28, 256, 3, 256, 2, 1),
+    "s2_3x3_14": (14, 512, 3, 512, 2, 1),
+    "1x1_56": (56, 64, 1, 256, 1, 0),
+    "1x1_s2_56": (56, 256, 1, 512, 2, 0),
+}
+
+
+@pytest.mark.parametrize("name", RESNET)
+def test_resnet_geometry_trains_on_v5e_pick(one_chip, name):
+    """Each geometry's forward and both gradients compile for the v5e on
+    the algorithm the planner picks there, in bfloat16."""
+    from repro.core.conv_api import conv2d_spec
+    from repro.launch.costmodel import pick_conv2d_algorithm
+    size, i_c, k, o_c, stride, pad = RESNET[name]
+    dt = jnp.bfloat16
+    x = jax.ShapeDtypeStruct((BATCH, size, size, i_c), dt, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((k, k, i_c, o_c), dt, sharding=one_chip)
+    padding = pad or "VALID"
+    pick = pick_conv2d_algorithm(
+        conv2d_spec(x, w, stride=stride, padding=padding), backend="tpu",
+        dtype="bfloat16")
+
+    def loss(a, b):
+        y = conv2d(a, b, stride=stride, padding=padding, algorithm=pick,
+                   interpret=False)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile() \
+        .as_text()
+    assert ("tpu_custom_call" in text) == (pick == "mec_fused"), pick
+    assert pick == ("direct" if k == 1 or i_c < 4 else "mec_fused"), pick

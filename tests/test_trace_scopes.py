@@ -4,7 +4,8 @@ where a profiler trace reads each device op's owner.
 
 Programs are compiled on the CPU, the Pallas kernels in interpret mode:
 a ``conv2d(plan=)`` forward on ``mec_fused``, a training step (``jax.grad``
-through the MEC custom VJP, then AdamW), and the other Pallas paths.
+through the MEC custom VJP, then AdamW), the other Pallas paths, and a
+tiny ResNet's training step (``repro.models.resnet``).
 """
 import re
 
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.core.conv_api import TRACE_SCOPES, conv2d, conv2d_spec
 from repro.kernels.ops import mec_conv1d_tpu
+from repro.models import resnet
 from repro.optim import adamw
 from repro.plan import ConvPlan
 
@@ -51,6 +53,16 @@ def _programs():
         ws, state, _ = adamw.update(adamw.AdamWConfig(), grads, state, ws)
         return ws, state
 
+    # One block per stage, so every stage after the first projects its
+    # shortcut.
+    net = {"image_size": 32, "in_channels": 3, "num_classes": 10,
+           "stem": {"width": 4, "kernel": 7, "stride": 2, "pad": 3,
+                    "pool": {"kernel": 3, "stride": 2, "pad": 1}},
+           "depths": [1, 1, 1, 1], "widths": [4, 8, 16, 32], "expansion": 4}
+    net_params, net_stats = resnet.init(jax.random.key(0), net)
+    net_step = resnet.train_step(resnet.plan(net, 2, BF16),
+                                 adamw.AdamWConfig())
+
     xs = jnp.ones((2, 9, 9, 4), BF16)
     k = jnp.ones((3, 3, 4, 4), BF16)
     return {
@@ -62,6 +74,10 @@ def _programs():
                                        algorithm="mec_fused2"), (xs, k)),
         "conv1d": (mec_conv1d_tpu, (jnp.ones((2, 16, 8)),
                                     jnp.ones((3, 8)))),
+        "resnet": (net_step, (net_params, net_stats,
+                              adamw.init(net_params),
+                              jnp.ones((2, 32, 32, 3), BF16),
+                              jnp.zeros((2,), jnp.int32))),
     }
 
 
@@ -80,6 +96,9 @@ COVERS = {
     "mec_lower": ("lowered", {"concatenate"}),
     "mec_gemm": ("lowered", {"dot_general"}),
     "mec_conv1d": ("conv1d", {"mul"}),
+    "batch_norm": ("resnet", {"rsqrt"}),
+    "pointwise": ("resnet", {"conv_general_dilated"}),
+    "head": ("resnet", {"dot_general"}),
 }
 # Scopes that lie inside ``conv2d`` wherever they appear.
 IN_CONV2D = ("conv2d_pad", "mec_fold", "conv2d_out", "mec_input_grad",
@@ -131,3 +150,14 @@ def test_training_scopes_are_apart(op_names):
         assert len(owned) <= 1, stack
         if "adamw_update" in stack:
             assert "conv2d" not in stack
+
+
+def test_resnet_scopes_are_apart(op_names):
+    """A 1x1 conv's convolutions, forward and backward, lie in
+    ``pointwise`` and then ``conv2d``; no batch norm or head op lies in a
+    conv."""
+    for stack in op_names["resnet"]:
+        if "pointwise" in stack and stack[-1] == "conv_general_dilated":
+            assert "conv2d" in stack[stack.index("pointwise"):], stack
+        if {"batch_norm", "head"} & set(stack):
+            assert "conv2d" not in stack and "pointwise" not in stack, stack
