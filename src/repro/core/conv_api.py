@@ -31,7 +31,12 @@ lowering is trainable end-to-end:
 
 * input gradient = a *transposed MEC conv*: the cotangent, stride-dilated
   and fully padded, is itself MEC-convolved with the spatially-flipped,
-  channel-transposed kernel;
+  channel-transposed kernel.  A stride-1 conv that ran forward on
+  ``mec_fused`` runs it on the same ``mec_fused`` kernel, in the
+  cotangent's dtype, where the transposed geometry passes the forward's
+  own TPU rule (:func:`fused_input_grad_refusal`); every other conv, and
+  a transposed geometry the rule refuses, runs the pure-JAX reference
+  in f32 (``_mec_input_grad``);
 * weight gradient reuses ``mec_lower``'s compact L — one small einsum per
   kernel row over shifted views of L, never an im2col-sized buffer.
 """
@@ -176,15 +181,61 @@ def _mec_weight_grad(inp: jnp.ndarray, g: jnp.ndarray, s_h: int, s_w: int,
     return jnp.stack(rows, axis=0)        # (k_h, k_w, i_c, k_c)
 
 
-def _mec_bwd(s_h, s_w, _variant, _solution, _interpret, precision, _w_blk,
+def input_grad_spec(spec: ConvSpec) -> ConvSpec:
+    """The stride-1 conv whose output is dL/dI of the stride-1 ``spec``:
+    the cotangent padded by (k_h - 1, k_w - 1) against the flipped kernel
+    with its channel axes swapped.  Its output is ``spec``'s input."""
+    return ConvSpec(spec.i_n, spec.o_h + 2 * (spec.k_h - 1),
+                    spec.o_w + 2 * (spec.k_w - 1), spec.k_c, spec.k_h,
+                    spec.k_w, spec.i_c)
+
+
+def fused_input_grad_refusal(spec: ConvSpec, dtype) -> Optional[str]:
+    """Why the input gradient of a ``mec_fused`` conv of ``spec`` runs on
+    the XLA path (``_mec_input_grad``) and not on the ``mec_fused``
+    kernel, or None where the kernel takes it: a stride-1 conv whose
+    transposed geometry (:func:`input_grad_spec`) passes the forward's
+    own rule, ``costmodel.tpu_fused_ineligibility``."""
+    if (spec.s_h, spec.s_w) != (1, 1):
+        return (f"stride {(spec.s_h, spec.s_w)}: the kernel takes the "
+                "input gradients of stride-1 convs only")
+    # Lazy import: launch sits above core.
+    from repro.launch.costmodel import tpu_fused_ineligibility
+    why = tpu_fused_ineligibility(input_grad_spec(spec), str(dtype))
+    return None if why is None else f"transposed geometry refused: {why}"
+
+
+def _mec_fused_input_grad(g: jnp.ndarray, kernel: jnp.ndarray, interpret,
+                          precision=None) -> jnp.ndarray:
+    """dL/dI of a stride-1 conv on the ``mec_fused`` kernel: the cotangent
+    padded by (k_h - 1, k_w - 1) in its own dtype, convolved with the
+    flipped kernel (HWIO -> HWOI) cast to that dtype.  Every tap sums in
+    the kernel's f32 accumulator, rounded once to the cotangent's dtype;
+    the output is the gradient of the whole (padded) input."""
+    from repro.kernels.ops import mec_conv2d_tpu, pick_w_blk
+    k_h, k_w, i_c, _ = kernel.shape
+    gp = jnp.pad(g, ((0, 0), (k_h - 1, k_h - 1), (k_w - 1, k_w - 1),
+                     (0, 0)))
+    k_t = jnp.transpose(kernel[::-1, ::-1], (0, 1, 3, 2)).astype(g.dtype)
+    w_blk = pick_w_blk(gp.shape[2] - k_w + 1, i_c, _warn_env=False)
+    return mec_conv2d_tpu(gp, k_t, (1, 1), mode="fused",
+                          interpret=interpret, precision=precision,
+                          w_blk=w_blk)
+
+
+def _mec_bwd(s_h, s_w, variant, _solution, interpret, precision, _w_blk,
              res, g):
-    # The nondiff args arrive positionally; variant/solution/interpret/
-    # w_blk shape the forward lowering only — the VJP math is identical
-    # for every MEC execution path.
+    # The nondiff args arrive positionally.  solution and w_blk shape the
+    # forward lowering only; the variant picks where the input gradient
+    # runs, the same mathematics either way.
     inp, kernel = res
     with jax.named_scope("mec_input_grad"):
-        d_inp = _mec_input_grad(g, kernel, s_h, s_w, inp.shape[1],
-                                inp.shape[2], precision)
+        if variant == "mec_fused" and fused_input_grad_refusal(
+                spec_of(inp, kernel, (s_h, s_w)), g.dtype) is None:
+            d_inp = _mec_fused_input_grad(g, kernel, interpret, precision)
+        else:
+            d_inp = _mec_input_grad(g, kernel, s_h, s_w, inp.shape[1],
+                                    inp.shape[2], precision)
     with jax.named_scope("mec_weight_grad"):
         d_ker = _mec_weight_grad(inp, g, s_h, s_w, kernel.shape[0],
                                  kernel.shape[1], precision)

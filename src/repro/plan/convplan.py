@@ -251,6 +251,14 @@ class ConvPlan:
                                   s.k_w, s.k_c, s.s_h, s.s_w, w_blk,
                                   np.dtype(self.dtype).itemsize)
                 lines.append(f"  mec_fused blocking: {fb.describe()}")
+                from repro.core.conv_api import (fused_input_grad_refusal,
+                                                 input_grad_spec)
+                why = fused_input_grad_refusal(s, self.dtype)
+                lines.append(
+                    "  input gradient: the mec_fused kernel on the "
+                    f"transposed conv {spec_key(input_grad_spec(s))}"
+                    if why is None else
+                    f"  input gradient: XLA (_mec_input_grad), {why}")
         elif self.backend == "tpu":
             from repro.launch.costmodel import tpu_fused_ineligibility
             why = tpu_fused_ineligibility(s, self.dtype)

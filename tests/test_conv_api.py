@@ -97,22 +97,83 @@ def test_auto_dispatch_consults_costmodel():
     assert pick_conv2d_algorithm(s3) in ALGORITHMS
 
 
-@pytest.mark.parametrize("stride", [1, 2])
-@pytest.mark.parametrize("algorithm", ["mec", "mec_fused", "mec_lowered"])
-def test_mec_grad_matches_direct(algorithm, stride):
-    inp = _rand((2, 9, 10, 3), 13)
-    ker = _rand((3, 3, 3, 4), 14)
+# (algorithm, stride, input channels, output channels, dtype): 3 -> 4
+# channels in f32 on every MEC path and stride, then stride-1 convs whose
+# channel counts differ, the cases whose input gradient mec_fused runs on
+# its own kernel with the kernel's channel axes swapped.
+GRAD_CASES = [pytest.param(alg, stride, 3, 4, "float32",
+                           id=f"{alg}-{stride}")
+              for stride in (1, 2)
+              for alg in ("mec", "mec_fused", "mec_lowered")] + [
+    pytest.param(alg, 1, i_c, k_c, dtype,
+                 id=f"{alg}-1-{i_c}to{k_c}-{dtype}")
+    for alg in ("mec", "mec_fused")
+    for i_c, k_c in ((8, 16), (16, 8))
+    for dtype in ("float32", "bfloat16")]
+# bf16: each gradient is rounded once to bf16 (2**-8 relative) from a
+# cotangent that is itself bf16; 5 ulps of relative L2 against the f32
+# direct conv's gradients of the same bf16 values.
+BF16_GRAD_REL_L2 = 5 * 2.0 ** -8
+
+
+@pytest.mark.parametrize("algorithm,stride,i_c,k_c,dtype", GRAD_CASES)
+def test_mec_grad_matches_direct(algorithm, stride, i_c, k_c, dtype):
+    inp = _rand((2, 9, 10, i_c), 13, dtype)
+    ker = _rand((3, 3, i_c, k_c), 14, dtype)
 
     def loss(alg):
-        return lambda i, k: jnp.sum(jnp.sin(
-            conv2d(i, k, stride=stride, padding="SAME", algorithm=alg)))
+        return lambda i, k: jnp.sum(jnp.sin(conv2d(
+            i, k, stride=stride, padding="SAME",
+            algorithm=alg).astype(jnp.float32)))
 
     gi, gk = jax.grad(loss(algorithm), argnums=(0, 1))(inp, ker)
-    ri, rk = jax.grad(loss("direct"), argnums=(0, 1))(inp, ker)
-    np.testing.assert_allclose(np.asarray(gi), np.asarray(ri),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(gk), np.asarray(rk),
-                               rtol=2e-4, atol=2e-4)
+    ri, rk = jax.grad(loss("direct"), argnums=(0, 1))(
+        inp.astype(jnp.float32), ker.astype(jnp.float32))
+    assert gi.dtype == gk.dtype == jnp.dtype(dtype)
+    for got, ref in ((gi, ri), (gk, rk)):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref)
+        if dtype == "float32":
+            np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
+        else:
+            rel = np.linalg.norm(got - ref) / np.linalg.norm(ref)
+            assert rel < BF16_GRAD_REL_L2, rel
+
+
+def _kernels_in_input_grad(fn, *args):
+    """The ``pallas_call``s named ``mec_fused`` that ``fn``'s jaxpr runs
+    inside the ``mec_input_grad`` scope."""
+    def walk(jaxpr, stack):
+        n = 0
+        for eqn in jaxpr.eqns:
+            path = f"{stack}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "pallas_call":
+                n += eqn.params["name"] == "mec_fused" and \
+                    "mec_input_grad" in path
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", None)
+                inner = getattr(inner, "jaxpr", inner)
+                if inner is not None and hasattr(inner, "eqns"):
+                    n += walk(inner, path)
+        return n
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr, "")
+
+
+@pytest.mark.parametrize("algorithm,stride,kernels", [
+    ("mec_fused", 1, 1), ("mec_fused", 2, 0), ("mec", 1, 0)])
+def test_input_grad_runs_on_mec_fused_for_stride_1(algorithm, stride,
+                                                    kernels):
+    """The input gradient of a stride-1 ``mec_fused`` conv is one
+    ``mec_fused`` kernel inside ``mec_input_grad``; a stride-2 conv and
+    the pure-JAX ``mec`` keep the XLA path."""
+    inp = _rand((2, 9, 10, 8), 15, jnp.bfloat16)
+    ker = _rand((3, 3, 8, 16), 16, jnp.bfloat16)
+
+    def loss(i, k):
+        return jnp.sum(conv2d(i, k, stride=stride, padding="SAME",
+                              algorithm=algorithm).astype(jnp.float32))
+
+    grad = jax.grad(loss, argnums=(0, 1))
+    assert _kernels_in_input_grad(grad, inp, ker) == kernels
 
 
 @pytest.mark.parametrize("algorithm", list(MEC_ALGORITHMS))

@@ -432,6 +432,15 @@ def test_tpu_plans_mec_fused_for_table2_and_resnet_stages(dtype):
     assert plan.algorithm == "mec_fused"
     assert "mec_fused blocking:" in plan.explain()
     assert "image(s) x 7 row(s)" in plan.explain()
+    # Every stride-1 stage runs its input gradient on the kernel too;
+    # cv4, stride 2, keeps the XLA path, and explain() says which.
+    from repro.core.conv_api import fused_input_grad_refusal
+    for spec in specs[-4:]:
+        assert fused_input_grad_refusal(spec, dtype) is None, spec
+    assert ("input gradient: the mec_fused kernel on the transposed conv "
+            "32x11x11x512-k3x3x512-s1x1") in plan.explain()
+    cv4 = plan_conv2d(specs[-5], backend="tpu", dtype=dtype).explain()
+    assert "input gradient: XLA (_mec_input_grad), stride (2, 2)" in cv4
 
 
 def test_tpu_sends_3_channel_inputs_to_direct():

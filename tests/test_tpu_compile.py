@@ -104,13 +104,18 @@ RESNET = {
     "s2_3x3_14": (14, 512, 3, 512, 2, 1),
     "1x1_56": (56, 64, 1, 256, 1, 0),
     "1x1_s2_56": (56, 256, 1, 512, 2, 0),
+    "s1_3x3_56": (56, 64, 3, 64, 1, 1),
+    "s1_3x3_28": (28, 128, 3, 128, 1, 1),
+    "s1_3x3_14": (14, 256, 3, 256, 1, 1),
+    "s1_3x3_7": (7, 512, 3, 512, 1, 1),
 }
 
 
 @pytest.mark.parametrize("name", RESNET)
 def test_resnet_geometry_trains_on_v5e_pick(one_chip, name):
     """Each geometry's forward and both gradients compile for the v5e on
-    the algorithm the planner picks there, in bfloat16."""
+    the algorithm the planner picks there, in bfloat16; a stride-1
+    ``mec_fused`` conv's input gradient is a Mosaic kernel of its own."""
     from repro.core.conv_api import conv2d_spec
     from repro.launch.costmodel import pick_conv2d_algorithm
     size, i_c, k, o_c, stride, pad = RESNET[name]
@@ -130,4 +135,7 @@ def test_resnet_geometry_trains_on_v5e_pick(one_chip, name):
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile() \
         .as_text()
     assert ("tpu_custom_call" in text) == (pick == "mec_fused"), pick
+    input_grad = [line for line in text.splitlines()
+                  if "tpu_custom_call" in line and "mec_input_grad" in line]
+    assert bool(input_grad) == (pick == "mec_fused" and stride == 1), name
     assert pick == ("direct" if k == 1 or i_c < 4 else "mec_fused"), pick
