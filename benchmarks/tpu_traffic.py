@@ -1,17 +1,16 @@
-"""TPU HBM-traffic model for the MEC Pallas kernels (DESIGN.md §2).
+"""TPU HBM-traffic model for the MEC Pallas kernel (DESIGN.md §2).
 
-No TPU is attached, so the kernel-level win is reported as modeled HBM
-bytes derived from the BlockSpecs (what the grid actually DMAs), per
-cv layer, f32:
+The kernel-level win is reported as modeled HBM bytes, per cv layer,
+f32:
 
-  im2col  : read I + write L_i2c + read L_i2c + write O
-  lowered : read I + write L_mec + read (o_h*k_h rows of L) + write O
-  fused   : read I * ceil(k_h/s_h) + write O          (no L at all)
+  im2col : read I + write L_i2c + read L_i2c + write O
+  mec    : read I + write L_mec + read (o_h*k_h rows of L) + write O
+  fused  : read I * (1 + halo/rows a step) + write O   (no L at all)
 
-The fused kernel is the beyond-paper variant; 'lowered' is the faithful
-MEC data flow.  Arithmetic intensity (FLOPs/HBM byte) against the v5e
-ridge point (197e12/819e9 = 241 FLOP/B) says whether the layer stays
-memory-bound.
+``fused`` is the ``mec_fused`` kernel, modeled at 8 output rows a grid
+step; ``mec`` is the paper's data flow, with L in HBM.  Arithmetic
+intensity (FLOPs/HBM byte) against the v5e ridge point (197e12/819e9 =
+241 FLOP/B) says whether the layer stays memory-bound.
 """
 from __future__ import annotations
 
@@ -31,16 +30,14 @@ def traffic(s):
     k_bytes = s.k_h * s.k_w * s.i_c * s.k_c * f32
     l_i2c = im2col_overhead(s) * f32
     l_mec = mec_overhead(s) * f32
-    refetch = -(-s.k_h // s.s_h)
     gemm_reads = s.i_n * s.o_h * s.k_h * s.o_w * s.k_w * s.i_c * f32
-    # fused v2: oh_blk output rows per grid step + (k_h - s_h)-row halo
+    # oh_blk output rows per grid step + (k_h - s_h)-row halo
     oh_blk = 8
     halo_factor = 1 + max(s.k_h - s.s_h, 0) / (oh_blk * s.s_h)
     return {
         "im2col": i_bytes + l_i2c + l_i2c + k_bytes + o_bytes,
-        "lowered": i_bytes + l_mec + gemm_reads + k_bytes + o_bytes,
-        "fused": i_bytes * refetch + k_bytes + o_bytes,
-        "fused2": i_bytes * halo_factor + k_bytes + o_bytes,
+        "mec": i_bytes + l_mec + gemm_reads + k_bytes + o_bytes,
+        "fused": i_bytes * halo_factor + k_bytes + o_bytes,
     }
 
 
@@ -51,8 +48,8 @@ def rows(batch: int = 32):
         t = traffic(s)
         flops = conv_flops(s)
         out.append({"name": name, "flops": flops,
-                    "ai_flop_per_byte": flops / t["fused2"],
-                    "bound": "compute" if flops / t["fused2"] > RIDGE
+                    "ai_flop_per_byte": flops / t["fused"],
+                    "bound": "compute" if flops / t["fused"] > RIDGE
                              else "memory", **t})
     return out
 
@@ -67,15 +64,14 @@ def main(emit=print, fmt: str = "csv"):
         s = spec(name, batch=32)     # server batch
         t = traffic(s)
         flops = conv_flops(s)
-        ai = flops / t["fused2"]
-        t_mem_us = t["fused2"] / HBM_BW * 1e6
+        ai = flops / t["fused"]
+        t_mem_us = t["fused"] / HBM_BW * 1e6
         t_cmp_us = flops / PEAK_FLOPS * 1e6
         emit(f"tpu_traffic,{name},{max(t_mem_us, t_cmp_us):.1f},"
              f"im2col={t['im2col']/2**20:.1f}MB;"
-             f"lowered={t['lowered']/2**20:.1f}MB;"
+             f"mec={t['mec']/2**20:.1f}MB;"
              f"fused={t['fused']/2**20:.1f}MB;"
-             f"fused2={t['fused2']/2**20:.1f}MB;"
-             f"fused2_vs_im2col={t['im2col']/t['fused2']:.2f}x;"
+             f"fused_vs_im2col={t['im2col']/t['fused']:.2f}x;"
              f"AI={ai:.0f}FLOP/B;"
              f"bound={'compute' if ai > RIDGE else 'memory'}")
     return None

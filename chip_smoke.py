@@ -50,7 +50,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax import lax  # noqa: E402
 
-from repro.analysis.pallas_check import PALLAS_ALGORITHMS  # noqa: E402
 from repro.bench.scenarios import (CV_LAYERS, RESNET101_WEIGHTS,  # noqa: E402
                                    layer_spec)
 from repro.core.conv_api import conv2d  # noqa: E402
@@ -124,7 +123,7 @@ def on_tpu() -> bool:
 def assert_kernel_compiled(plan, compiled, what: str) -> None:
     """On the chip a Pallas plan must lower to a Mosaic custom call, never
     to the interpreter's XLA loop."""
-    if plan.algorithm in PALLAS_ALGORITHMS and on_tpu():
+    if plan.algorithm == "mec_fused" and on_tpu():
         check("tpu_custom_call" in compiled.as_text(),
               f"{what}: {plan.algorithm} compiled without a "
               "tpu_custom_call (the kernel was interpreted)")

@@ -25,7 +25,6 @@ for name, kwargs in [
     ("fft", dict(algorithm="fft")),
     ("winograd F(2x2,3x3)", dict(algorithm="winograd")),
     ("Pallas MEC kernel (fused)", dict(algorithm="mec_fused")),
-    ("Pallas MEC kernel (lowered)", dict(algorithm="mec_lowered")),
 ]:
     err = float(jnp.max(jnp.abs(conv2d(x, k, padding="SAME", **kwargs) - ref)))
     print(f"  {name:28s} max|err| vs direct = {err:.2e}")

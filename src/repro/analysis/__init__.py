@@ -3,8 +3,6 @@
 
 * :mod:`repro.analysis.memaudit` — XLA peak-temp bytes vs. the paper's
   Eq. 2-4 analytic model, for every committed baseline plan.
-* :mod:`repro.analysis.pallas_check` — symbolic grid/BlockSpec/VMEM
-  checking of the Pallas kernel geometries, no compile needed.
 * :mod:`repro.analysis.shardcheck` — the distributed-conv collective
   contract (halo permute / psum all-reduce bytes vs. the costmodel,
   zero accidental resharding) over every partitioned lowering.
@@ -17,11 +15,11 @@
   has already shipped (dropped kwargs, stray env reads, bare
   un-annotated GEMMs).
 
-Run all five: ``python -m repro.analysis --suite all``.
+Run all four: ``python -m repro.analysis --suite all``.
 
 Layering: analysis may import ``core``/``kernels``/``bench`` freely but
 never ``repro.plan`` at module level — the planner calls *into*
-``pallas_check``/``shardcheck`` (lazily), so plans are duck-typed here.
+``numcheck``/``shardcheck`` (lazily), so plans are duck-typed here.
 
 Exports resolve lazily (PEP 562): importing this package must not drag
 in the submodules' jax dependency chain, because the ``shardcheck`` CLI
@@ -37,11 +35,6 @@ _EXPORTS = {
     "TOLERANCES": "repro.analysis.memaudit",
     "audit_plan": "repro.analysis.memaudit",
     "run_audit": "repro.analysis.memaudit",
-    "PallasCheckError": "repro.analysis.pallas_check",
-    "PlanCheck": "repro.analysis.pallas_check",
-    "assert_plan": "repro.analysis.pallas_check",
-    "check_geometry": "repro.analysis.pallas_check",
-    "check_plan": "repro.analysis.pallas_check",
     "ContractViolation": "repro.analysis.numcheck",
     "NumCheck": "repro.analysis.numcheck",
     "NumCheckError": "repro.analysis.numcheck",

@@ -1,5 +1,5 @@
 """CLI: ``python -m repro.analysis --suite
-memaudit|pallas|lint|shardcheck|numcheck|all``.
+memaudit|lint|shardcheck|numcheck|all``.
 
 Exit status is non-zero on any violation — this is what the CI
 ``static-analysis`` job runs on every push.  ``--update-lint-baseline``
@@ -26,7 +26,7 @@ import os
 import pathlib
 import sys
 
-SUITES = ("memaudit", "pallas", "lint", "shardcheck", "numcheck", "all")
+SUITES = ("memaudit", "lint", "shardcheck", "numcheck", "all")
 
 # Enough forced host devices for every committed dist-baseline mesh
 # except the 256-way pod cells (those record an explicit skip — a CLI
@@ -52,61 +52,6 @@ def _run_memaudit(args) -> int:
             print(f"  {f}")
         return 1
     print("memaudit: all gated cells within tolerance")
-    return 0
-
-
-def _run_pallas(args) -> int:
-    """Check every baseline plan as-committed, then every Pallas variant
-    of each baseline geometry with the planner-derived w_blk — the
-    committed plans are mostly reference-path, so the variants are what
-    actually exercises the kernel mirror."""
-    from repro.analysis.memaudit import DEFAULT_PLANS, load_plans
-    from repro.analysis.pallas_check import (PALLAS_ALGORITHMS,
-                                             check_geometry, check_plan)
-    root = pathlib.Path(__file__).resolve().parents[3]
-    plans = load_plans(args.plans or root / DEFAULT_PLANS)
-    bad = 0
-    pallas_cells = 0
-    for name, plan in plans.items():
-        result = check_plan(plan)
-        if not result.ok:
-            bad += 1
-            print(f"pallas: {name} (as committed): {result.render()}")
-        for alg in PALLAS_ALGORITHMS:
-            variant = check_geometry(plan.spec, alg, None, plan.dtype)
-            pallas_cells += 1
-            if not variant.ok:
-                bad += 1
-                print(f"pallas: {name} as {alg}: {variant.render()}")
-    # Tuned non-default w_blk coverage: every stage-2 grid candidate the
-    # measured autotuner actually trialed (BENCH_autotune.json) must have
-    # been geometry-admissible — incl. the committed w520 cell, whose
-    # tuned w_blk=520 exceeds pick_w_blk's 512 default cap.
-    trial_cells = 0
-    autotune = root / "BENCH_autotune.json"
-    if autotune.exists():
-        from repro.core.convspec import ConvSpec
-        doc = json.loads(autotune.read_text())
-        for r in doc.get("results", []):
-            tuning = r.get("tuning")
-            if not tuning or \
-                    tuning.get("algorithm") not in PALLAS_ALGORITHMS:
-                continue
-            spec = ConvSpec(**r["run_spec"])
-            for label, t in tuning["trials"].items():
-                res = check_geometry(spec, tuning["algorithm"],
-                                     t.get("w_blk"), r["dtype"])
-                trial_cells += 1
-                if not res.ok:
-                    bad += 1
-                    print(f"pallas: {r['scenario']} trialed w_blk={label}: "
-                          f"{res.render()}")
-    if bad:
-        print(f"pallas: {bad} rejected geometry(ies)")
-        return 1
-    print(f"pallas: {len(plans)} plan(s) + {pallas_cells} Pallas "
-          f"variant geometries + {trial_cells} autotune trial "
-          f"geometries accepted")
     return 0
 
 
@@ -339,8 +284,6 @@ def main(argv=None) -> int:
     rc = 0
     if args.suite in ("lint", "all"):
         rc |= _run_lint(args)
-    if args.suite in ("pallas", "all"):
-        rc |= _run_pallas(args)
     if args.suite in ("memaudit", "all"):
         rc |= _run_memaudit(args)
     if args.suite in ("numcheck", "all"):

@@ -21,10 +21,10 @@ all 15 baseline cells plus winograd/fft probes; see DESIGN.md §8):
   measured 1.03-1.51, band [0.95, 1.9].
 * ``winograd`` / ``fft``  looser ([0.95, 2.0] / [0.95, 2.1]): XLA keeps
   transform temps alive across the element-wise product.
-* Pallas algorithms (``mec_lowered``/``mec_fused*``) are **recorded but
-  not gated** off-TPU: interpret-mode compiles materialize the lowering
-  as XLA temps, so CPU numbers say nothing about the TPU VMEM story —
-  that is ``repro.analysis.pallas_check``'s job.
+* the Pallas kernel (``mec_fused``) is **recorded but not gated**
+  off-TPU: interpret-mode compiles materialize the lowering as XLA
+  temps, so CPU numbers say nothing about the TPU VMEM story — that is
+  the kernel's geometry rule's job (``repro.kernels.mec_conv``).
 
 A band failure means either the analytic model or the implementation
 drifted — exactly the regression Table 2's memory claims rest on.  Each
@@ -46,7 +46,7 @@ from repro.core import memory
 from repro.core.convspec import ConvSpec
 
 # Per-algorithm measured-vs-predicted gates, keyed by the *base* model
-# name (repro.core.memory._DISPATCH_BASE resolves mecA/mec_lowered/...).
+# name (repro.core.memory._DISPATCH_BASE resolves mecA/mec_fused/...).
 # ratio = measured_temp_bytes / predicted_overhead_bytes.
 TOLERANCES: Dict[str, Dict[str, float]] = {
     "direct": {"abs_slack": 4096},
@@ -97,7 +97,7 @@ def audit_plan(scenario: str, plan) -> Tuple[Dict, List[str]]:
     measured = None if stats is None else int(stats.temp_size_in_bytes)
     source = None if stats is None else "memory_analysis"
 
-    is_pallas = plan.algorithm in ("mec_lowered", "mec_fused", "mec_fused2")
+    is_pallas = plan.algorithm == "mec_fused"
     policy = "recorded" if (is_pallas and not pallas_gated()) else "gated"
     tol = TOLERANCES[base]
     ratio = None

@@ -21,7 +21,7 @@ the backend's declared :class:`repro.core.numerics.NumericContract`:
   is also sub-f32 accumulated below the contract width (a dropped
   ``preferred_element_type``, the PR 4/PR 5 bug class).
 * **pallas-accum** — the in-kernel variant, checked symbolically on the
-  kernel jaxpr beside ``pallas_check.check_geometry``: Pallas dots must
+  kernel jaxpr: Pallas dots must
   *carry* ``preferred_element_type=f32`` for sub-f32 inputs (MXU
   accumulation width is set per dot, not recovered by a later cast).
 * **narrow-widen** — a value narrowed then widened again (silent
@@ -65,13 +65,13 @@ DIRECTIONS = ("fwd", "grad")
 
 #: executor backends the CLI suite sweeps (ALGORITHMS minus "auto").
 NUMCHECK_ALGORITHMS = ("direct", "im2col", "fft", "winograd", "mec",
-                       "mec_lowered", "mec_fused", "mec_fused2")
+                       "mec_fused")
 NUMCHECK_DTYPES = CONTRACT_DTYPES
 
 
 def probe_spec():
     """The fixed geometry every contract budget is measured on: 3x3
-    stride-1 (so winograd participates), small enough that the 24-cell
+    stride-1 (so winograd participates), small enough that the 18-cell
     f64 sweep stays in CI budget.  Matches shardcheck's probe spec."""
     from repro.core.convspec import ConvSpec
     return ConvSpec(2, 16, 16, 3, 3, 3, 4, 1, 1)
@@ -643,11 +643,13 @@ def check_numerics(spec, algorithm: str, dtype: str = "float32", *,
             (spec.k_h, spec.k_w, spec.s_h, spec.s_w) != (3, 3, 1, 1):
         return skipped("winograd F(2x2,3x3) requires a 3x3 kernel and "
                        "stride 1")
-    if algorithm in ("mec_lowered", "mec_fused", "mec_fused2"):
-        from repro.analysis.pallas_check import check_geometry
-        geo = check_geometry(spec, algorithm, None, dtype)
-        if not geo.ok:
-            return skipped(f"pallas geometry rejected: {geo.render()}")
+    if algorithm == "mec_fused":
+        from repro.kernels.mec_conv import fused_refusal
+        from repro.kernels.ops import pick_w_blk
+        why = fused_refusal(spec, dtype,
+                            pick_w_blk(spec.o_w, spec.k_c, _warn_env=False))
+        if why:
+            return skipped(f"mec_fused refuses the geometry: {why}")
 
     import jax
     import jax.numpy as jnp

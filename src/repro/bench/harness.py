@@ -38,8 +38,7 @@ from repro.launch.hlo_analysis import hlo_flops_bytes
 
 # Variant name -> key into conv2d_algorithm_costs for the flops model
 # (all MEC executions compute the same mult-adds as the reference).
-_FLOPS_BASE = {"mecA": "mec", "mecB": "mec", "mec_lowered": "mec",
-               "mec_fused": "mec", "mec_fused2": "mec"}
+_FLOPS_BASE = {"mecA": "mec", "mecB": "mec", "mec_fused": "mec"}
 
 
 def make_arrays(s: ConvSpec, dtype: str = "float32", seed: int = 0):

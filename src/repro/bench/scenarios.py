@@ -60,8 +60,8 @@ CV_LAYERS = {
 RESNET101_WEIGHTS = {"cv4": 1, "cv9": 3, "cv10": 4, "cv11": 23, "cv12": 3}
 
 # conv2d dispatch variants: bench name -> conv2d(**kwargs).  mecA/mecB are
-# the paper's Solution A/B of the reference Algorithm 2; the mec_* names
-# are the Pallas kernels (DESIGN.md §2).
+# the paper's Solution A/B of the reference Algorithm 2; mec_fused is the
+# Pallas kernel (DESIGN.md §2).
 ALGORITHM_VARIANTS: Dict[str, Dict[str, str]] = {
     "direct": {"algorithm": "direct"},
     "im2col": {"algorithm": "im2col"},
@@ -69,9 +69,7 @@ ALGORITHM_VARIANTS: Dict[str, Dict[str, str]] = {
     "winograd": {"algorithm": "winograd"},
     "mecA": {"algorithm": "mec", "solution": "A"},
     "mecB": {"algorithm": "mec", "solution": "B"},
-    "mec_lowered": {"algorithm": "mec_lowered"},
     "mec_fused": {"algorithm": "mec_fused"},
-    "mec_fused2": {"algorithm": "mec_fused2"},
 }
 
 ALL_VARIANTS = tuple(ALGORITHM_VARIANTS)
@@ -111,7 +109,7 @@ class Scenario:
     # Measured-mode candidate restriction (DESIGN.md §10): when set, the
     # autotune suite races exactly these ``conv2d`` algorithm names
     # instead of every eligible candidate.  Kernel-tuning cells use it
-    # to keep the stage-1 race inside the Pallas variants so the stage-2
+    # to keep the stage-1 race on the Pallas kernel so the stage-2
     # knob grid (``w_blk``) is what the cell exercises.  Names here are
     # executor algorithm names ("mec", "mec_fused", ...), not the
     # mecA/mecB bench-variant names.
@@ -201,13 +199,12 @@ def _smoke() -> Tuple[Scenario, ...]:
     # splits the row into two grid steps while the stage-2 grid's
     # min(o_w, 2*default)=520 trial covers it in one — a structural
     # (grid-step count) gap the measured tuner must find, independent of
-    # timer jitter.  The race is restricted to the Pallas variants so
+    # timer jitter.  The race is restricted to the Pallas kernel so
     # stage 2 tunes w_blk rather than re-litigating the algorithm pick.
     w520 = ConvSpec(1, 3, 522, 3, 3, 3, 8, 1, 1)
     cells.append(Scenario(
-        name="w520", spec=w520, run_spec=w520,
-        algorithms=("mec_lowered", "mec_fused", "mec_fused2"),
-        tune_candidates=("mec_lowered", "mec_fused", "mec_fused2")))
+        name="w520", spec=w520, run_spec=w520, algorithms=("mec_fused",),
+        tune_candidates=("mec_fused",)))
     return tuple(cells)
 
 

@@ -11,9 +11,7 @@ to one of the algorithm back-ends the paper compares in §4:
 ``fft``        frequency-domain (paper §2.2 FFT baseline)
 ``winograd``   F(2x2, 3x3); requires a 3x3 kernel and stride 1
 ``mec``        paper Algorithm 2, pure JAX (Solutions A/B)
-``mec_lowered``  Pallas: L materialized in HBM (paper-faithful kernels)
-``mec_fused``    Pallas: lowering fused into the GEMM, no L in HBM
-``mec_fused2``   Pallas: h-blocked fused variant with halo fetch
+``mec_fused``  Pallas: lowering fused into the GEMM, no L in HBM
 ``auto``       cached :class:`repro.plan.ConvPlan` (analytic on miss)
 =============  ============================================================
 
@@ -34,7 +32,7 @@ lowering is trainable end-to-end:
   channel-transposed kernel.  A stride-1 conv that ran forward on
   ``mec_fused`` runs it on the same ``mec_fused`` kernel, in the
   cotangent's dtype, where the transposed geometry passes the forward's
-  own TPU rule (:func:`fused_input_grad_refusal`); every other conv, and
+  own rule (:func:`fused_input_grad_refusal`); every other conv, and
   a transposed geometry the rule refuses, runs the pure-JAX reference
   in f32 (``_mec_input_grad``);
 * weight gradient reuses ``mec_lower``'s compact L — one small einsum per
@@ -60,7 +58,7 @@ from repro.core.winograd import winograd_conv2d
 if TYPE_CHECKING:  # repro.plan imports core; the cycle is runtime-lazy
     from repro.plan import ConvPlan
 
-MEC_ALGORITHMS = ("mec", "mec_lowered", "mec_fused", "mec_fused2")
+MEC_ALGORITHMS = ("mec", "mec_fused")
 ALGORITHMS = ("auto", "direct", "im2col", "fft", "winograd") + MEC_ALGORITHMS
 
 Padding = Union[str, int, Tuple]
@@ -75,8 +73,8 @@ Padding = Union[str, int, Tuple]
 # the head (pool, classifier and loss).
 TRACE_SCOPES = ("conv2d", "conv2d_pad", "mec_fold", "conv2d_out",
                 "mec_input_grad", "mec_weight_grad", "adamw_update",
-                "mec_fused", "mec_fused2", "mec_lower", "mec_gemm",
-                "mec_conv1d", "batch_norm", "pointwise", "head")
+                "mec_fused", "mec_conv1d", "batch_norm", "pointwise",
+                "head")
 
 
 def apply_padding(inp: jnp.ndarray, k_h: int, k_w: int, s_h: int, s_w: int,
@@ -110,7 +108,7 @@ def apply_padding(inp: jnp.ndarray, k_h: int, k_w: int, s_h: int, s_w: int,
 
 
 # ---------------------------------------------------------------------------
-# MEC custom VJP (shared by the reference and all Pallas variants)
+# MEC custom VJP (shared by the reference and the Pallas kernel)
 # ---------------------------------------------------------------------------
 
 def _mec_forward(inp, kernel, s_h, s_w, variant, solution, interpret,
@@ -119,10 +117,8 @@ def _mec_forward(inp, kernel, s_h, s_w, variant, solution, interpret,
         return _mec_reference(inp, kernel, (s_h, s_w), solution=solution,
                               precision=precision)
     from repro.kernels.ops import mec_conv2d_tpu
-    mode = variant[len("mec_"):]          # lowered | fused | fused2
-    return mec_conv2d_tpu(inp, kernel, (s_h, s_w), mode=mode,
-                          interpret=interpret, precision=precision,
-                          w_blk=w_blk)
+    return mec_conv2d_tpu(inp, kernel, (s_h, s_w), interpret=interpret,
+                          precision=precision, w_blk=w_blk)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7, 8))
@@ -195,13 +191,12 @@ def fused_input_grad_refusal(spec: ConvSpec, dtype) -> Optional[str]:
     the XLA path (``_mec_input_grad``) and not on the ``mec_fused``
     kernel, or None where the kernel takes it: a stride-1 conv whose
     transposed geometry (:func:`input_grad_spec`) passes the forward's
-    own rule, ``costmodel.tpu_fused_ineligibility``."""
+    own rule, :func:`repro.kernels.mec_conv.fused_refusal`."""
     if (spec.s_h, spec.s_w) != (1, 1):
         return (f"stride {(spec.s_h, spec.s_w)}: the kernel takes the "
                 "input gradients of stride-1 convs only")
-    # Lazy import: launch sits above core.
-    from repro.launch.costmodel import tpu_fused_ineligibility
-    why = tpu_fused_ineligibility(input_grad_spec(spec), str(dtype))
+    from repro.kernels.mec_conv import fused_refusal
+    why = fused_refusal(input_grad_spec(spec), dtype)
     return None if why is None else f"transposed geometry refused: {why}"
 
 
@@ -218,9 +213,8 @@ def _mec_fused_input_grad(g: jnp.ndarray, kernel: jnp.ndarray, interpret,
                      (0, 0)))
     k_t = jnp.transpose(kernel[::-1, ::-1], (0, 1, 3, 2)).astype(g.dtype)
     w_blk = pick_w_blk(gp.shape[2] - k_w + 1, i_c, _warn_env=False)
-    return mec_conv2d_tpu(gp, k_t, (1, 1), mode="fused",
-                          interpret=interpret, precision=precision,
-                          w_blk=w_blk)
+    return mec_conv2d_tpu(gp, k_t, (1, 1), interpret=interpret,
+                          precision=precision, w_blk=w_blk)
 
 
 def _mec_bwd(s_h, s_w, variant, _solution, interpret, precision, _w_blk,

@@ -69,14 +69,10 @@ ALL_OVERHEADS = {
     "winograd": winograd_overhead,
 }
 
-# conv2d dispatch names -> the base overhead model above.  The Pallas
-# 'lowered' mode materializes the same compact L as the reference; the
-# fused kernels keep the lowering in VMEM, so their HBM overhead is the
+# conv2d dispatch names -> the base overhead model above.  The fused
+# Pallas kernel keeps the lowering in VMEM, so its HBM overhead is the
 # direct conv's (zero).
-_DISPATCH_BASE = {
-    "mecA": "mec", "mecB": "mec", "mec_lowered": "mec",
-    "mec_fused": "direct", "mec_fused2": "direct",
-}
+_DISPATCH_BASE = {"mecA": "mec", "mecB": "mec", "mec_fused": "direct"}
 
 
 def algorithm_overhead(s: ConvSpec, algorithm: str,

@@ -130,9 +130,7 @@ CONTRACTS: Dict[str, NumericContract] = {
         error_budget={"float32": _F32_FFT, "bfloat16": _BF16,
                       "float16": _F16}),
     "mec": NumericContract("mec", error_budget=_MEC_BUDGET),
-    "mec_lowered": NumericContract("mec_lowered", error_budget=_MEC_BUDGET),
     "mec_fused": NumericContract("mec_fused", error_budget=_MEC_BUDGET),
-    "mec_fused2": NumericContract("mec_fused2", error_budget=_MEC_BUDGET),
 }
 
 

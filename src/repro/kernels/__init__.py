@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the paper's compute hot-spot: MEC convolution.
 
-mec_conv.py   — compact lowering + shifted-window GEMM (+ fused variant)
+mec_conv.py   — the fused MEC kernel, its blocking and its geometry rule
 mec_conv1d.py — fused causal depthwise conv1d (Mamba2/xLSTM blocks)
 ops.py        — jit'd public wrappers (block-size selection, interpret auto)
 ref.py        — pure-jnp oracles

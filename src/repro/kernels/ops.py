@@ -3,23 +3,18 @@
 ``interpret`` defaults to True when the backend has no TPU, so the CPU
 tests run the kernels in the Pallas interpreter.  On a TPU backend the
 kernels always compile through Mosaic: asking for interpret mode there is
-an error, never a silent slowdown.  Only ``mode="fused"`` has a Mosaic
-lowering (:data:`MOSAIC_MODES`); the other modes raise
-``NotImplementedError`` before lowering.  Block sizes are chosen for v5e
-VMEM (~16 MiB/core).
+an error, never a silent slowdown.  Block sizes are chosen for v5e VMEM
+(~16 MiB/core).
 """
 from __future__ import annotations
 
-import functools
 import os
 import warnings
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.mec_conv import (mec_conv_fused2_pallas,
-                                    mec_conv_fused_pallas, mec_gemm_pallas,
-                                    mec_lower_pallas)
+from repro.kernels.mec_conv import mec_conv_fused_pallas
 from repro.kernels.mec_conv1d import mec_conv1d_pallas
 
 # Accumulator budget override for non-v5e targets (bytes; decimal or hex).
@@ -43,20 +38,11 @@ INTERPRET_VMEM = 16 << 20
 _ACC_FRACTION = 8
 
 
-# Kernel modes with a Mosaic (TPU) lowering.  ``fused2`` and ``lowered``
-# index loaded values with ``lax.dynamic_slice`` and use strided or
-# (8,128)-misaligned blocks, none of which Mosaic lowers; they run only
-# in the Pallas interpreter.
-MOSAIC_MODES = ("fused",)
-
-
-def resolve_interpret(interpret, mode: str = "fused") -> bool:
+def resolve_interpret(interpret) -> bool:
     """The interpret flag a kernel call runs with.
 
     None follows the backend: interpret everywhere but TPU.  On a TPU
-    backend ``interpret=True`` raises.  A mode without a Mosaic lowering
-    raises ``NotImplementedError`` whenever the call would lower for the
-    chip, before any tracing."""
+    backend ``interpret=True`` raises."""
     on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
         interpret = not on_tpu
@@ -64,20 +50,14 @@ def resolve_interpret(interpret, mode: str = "fused") -> bool:
         raise ValueError(
             "interpret=True on a TPU backend would run the Pallas kernel "
             "in the interpreter; drop the flag to compile it for the chip")
-    if not interpret and mode not in MOSAIC_MODES:
-        raise NotImplementedError(
-            f"mec_{mode} has no Mosaic lowering (it slices loaded values "
-            "with lax.dynamic_slice and uses strided or misaligned "
-            f"blocks); on TPU use one of {['mec_' + m for m in MOSAIC_MODES]}"
-            " or an XLA algorithm")
     return interpret
 
 
 def vmem_bytes() -> int:
     """Per-core VMEM of the local device kind.  Off TPU (interpreter
     runs) this is :data:`INTERPRET_VMEM`; an unknown TPU kind raises.
-    The static checker (``repro.analysis.pallas_check``) sizes
-    whole-kernel working sets against this; :func:`accumulator_budget`
+    The kernel's geometry rule (``repro.kernels.mec_conv.fused_refusal``)
+    sizes a step's working set against this; :func:`accumulator_budget`
     carves the accumulator's fraction out of it."""
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -146,43 +126,26 @@ def pick_w_blk(o_w: int, k_c: int, target_bytes: int | None = None, *,
 
 
 def mec_conv2d_tpu(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
-                   mode: str = "fused", interpret=None,
-                   precision=None, w_blk: int | None = None) -> jnp.ndarray:
-    """MEC convolution with Pallas kernels.
+                   interpret=None, precision=None,
+                   w_blk: int | None = None) -> jnp.ndarray:
+    """MEC convolution on the fused Pallas kernel (no L in HBM).
 
-    mode='lowered' is the paper-faithful path (L materialized in HBM,
-    Eq. 3 memory observable); mode='fused' is the beyond-paper fused path.
     precision reaches the in-kernel GEMMs (matters for bf16 operands on
     the MXU; accumulation is f32 regardless).  w_blk is normally supplied
     by the resolved :class:`repro.plan.ConvPlan`; when None (bare kwargs
     path) it falls back to :func:`pick_w_blk` — device-queried VMEM with
     the deprecated REPRO_MEC_ACC_BYTES env override.
     """
-    if mode not in ("fused", "fused2", "lowered"):
-        raise ValueError(f"unknown mode {mode!r}")
-    interpret = resolve_interpret(interpret, mode)
+    interpret = resolve_interpret(interpret)
     s_h, s_w = (stride, stride) if isinstance(stride, int) else stride
-    i_n, i_h, i_w, i_c = inp.shape
-    k_h, k_w, _, k_c = kernel.shape
-    o_w = (i_w - k_w) // s_w + 1
+    k_w, k_c = kernel.shape[1], kernel.shape[3]
+    o_w = (inp.shape[2] - k_w) // s_w + 1
     if w_blk is None:
         w_blk = pick_w_blk(o_w, k_c)
     elif not 1 <= w_blk <= max(o_w, 1):
         raise ValueError(f"w_blk must be in [1, o_w={o_w}], got {w_blk}")
-    if mode == "fused":
-        return mec_conv_fused_pallas(inp, kernel, (s_h, s_w), w_blk=w_blk,
-                                     interpret=interpret,
-                                     precision=precision)
-    if mode == "fused2":   # h-blocked + halo: ~1x input fetch (EXPERIMENTS)
-        return mec_conv_fused2_pallas(inp, kernel, (s_h, s_w), w_blk=w_blk,
-                                      interpret=interpret,
-                                      precision=precision)
-    low = mec_lower_pallas(inp, k_w, s_w, interpret=interpret)   # lowered
-    kernel_mat = kernel.reshape(k_h, k_w * i_c, k_c)
-    out = mec_gemm_pallas(low, kernel_mat, k_h, s_h, w_blk=w_blk,
-                          interpret=interpret, precision=precision)
-    with jax.named_scope("conv2d_out"):
-        return out.astype(inp.dtype)
+    return mec_conv_fused_pallas(inp, kernel, (s_h, s_w), w_blk=w_blk,
+                                 interpret=interpret, precision=precision)
 
 
 def mec_conv1d_tpu(x: jnp.ndarray, kernel: jnp.ndarray,

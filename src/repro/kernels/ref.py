@@ -4,19 +4,12 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.direct import direct_conv2d
-from repro.core.mec import mec_conv1d_depthwise, mec_lower
+from repro.core.mec import mec_conv1d_depthwise
 
 
 def conv2d_ref(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1) -> jnp.ndarray:
-    """Oracle for mec_gemm_pallas / mec_conv_fused_pallas."""
+    """Oracle for mec_conv_fused_pallas."""
     return direct_conv2d(inp, kernel, stride)
-
-
-def lower_ref(inp: jnp.ndarray, k_w: int, s_w: int) -> jnp.ndarray:
-    """Oracle for mec_lower_pallas: L (n, o_w, i_h, k_w*i_c)."""
-    low = mec_lower(inp, k_w, s_w)  # (n, o_w, i_h, k_w, i_c)
-    n, o_w, i_h, kw, i_c = low.shape
-    return low.reshape(n, o_w, i_h, kw * i_c)
 
 
 def conv1d_ref(x: jnp.ndarray, kernel: jnp.ndarray) -> jnp.ndarray:

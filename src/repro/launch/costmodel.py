@@ -515,49 +515,14 @@ def pick_conv_partition(spec, axis_sizes: Dict,
     return best
 
 
-# Fewer input channels than this leave the fused kernel's per-tap GEMMs
-# nearly empty (a contraction over i_c x s_w of the MXU's 128 rows).  On a
-# TPU v5e at batch 32 in bfloat16, XLA's conv ran every 3-channel conv
-# timed (the ResNet stem, 7x7/2 at 224 px; MEC Table 2's cv1, cv3 and
-# cv7) 3.1-5.2x faster forward and 4.5-6.3x faster with its gradients.
-# The rule stops at the width timed; wider inputs keep the kernel.
-FUSED_MIN_CHANNELS = 4
-
-
-def tpu_fused_ineligibility(spec, dtype="float32") -> str | None:
-    """Why the fused Pallas kernel cannot take ``spec`` on a TPU, or None.
-
-    The kernel's Mosaic lowering needs its geometry to pass the static
-    Pallas checker at the planner's own ``w_blk``: blocks in bounds,
-    sublane-aligned block starts, and a working set inside the device's
-    VMEM.  A geometry it refuses, a 1x1 kernel, or an input of fewer than
-    ``FUSED_MIN_CHANNELS`` channels runs on XLA's direct conv instead
-    (:func:`pick_conv2d_algorithm`); ``ConvPlan.explain`` prints the
-    reason."""
-    if spec.k_h == 1 and spec.k_w == 1:
-        return "1x1 kernel: the lowering is a no-op, XLA's conv wins"
-    if spec.i_c < FUSED_MIN_CHANNELS:
-        return (f"{spec.i_c} input channel(s), under {FUSED_MIN_CHANNELS}: "
-                "each tap's GEMM contracts over i_c x s_w lanes, and XLA's "
-                "conv runs such convs several times faster")
-    from repro.analysis.pallas_check import check_geometry
-    from repro.kernels.ops import pick_w_blk
-    verdict = check_geometry(spec, "mec_fused",
-                             pick_w_blk(spec.o_w, spec.k_c, _warn_env=False),
-                             str(dtype))
-    if verdict.ok:
-        return None
-    return "; ".join(v.render() for v in verdict.violations)
-
-
 def pick_conv2d_algorithm(spec, backend: str | None = None,
                           calibration="ambient", dtype="float32") -> str:
     """Dispatch rule for conv2d(algorithm='auto') — DESIGN.md §1, §10.
 
     * 1x1 kernels: lowering is a no-op, direct wins outright.
     * TPU backend: the fused Pallas kernel (no L in HBM at all) wherever
-      :func:`tpu_fused_ineligibility` admits the geometry, else XLA's
-      direct conv.
+      its rule, :func:`repro.kernels.mec_conv.fused_refusal`, admits the
+      geometry, else XLA's direct conv.
     * elsewhere (CPU/GPU via XLA): MEC whenever its compact L actually
       saves memory over im2col (k_h > s_h row overlap, Eq. 4), else
       direct — never im2col/fft/winograd, which only trade memory away
@@ -582,8 +547,8 @@ def pick_conv2d_algorithm(spec, backend: str | None = None,
     if spec.k_h == 1 and spec.k_w == 1:
         return "direct"
     if backend == "tpu":
-        return "direct" if tpu_fused_ineligibility(spec, dtype) else \
-            "mec_fused"
+        from repro.kernels.mec_conv import fused_refusal
+        return "direct" if fused_refusal(spec, dtype) else "mec_fused"
     from repro.plan.calibrate import resolve_calibration
     calib = resolve_calibration(calibration, backend)
     costs = conv2d_algorithm_costs(spec, calibration=calib)

@@ -15,7 +15,7 @@ axis — or, composite, over TWO — in one of three base modes:
 ``spatial``  input sharded on ``i_h`` rows.  Because MEC's compact L
              (Eq. 3) lowers whole input rows, a device only needs the
              first ``k_h - s_h`` rows of its lower neighbour — the same
-             overlap the ``fused2`` kernel fetches as its halo — which
+             overlap ``mec_fused``'s row windows share as their halo — which
              are exchanged with one ``lax.ppermute`` before the local
              conv.  The backward pass routes the halo cotangent back
              through the transposed permute automatically.

@@ -44,9 +44,7 @@ PLAN_VERSION = 1
 PRECISION_NAMES = ("DEFAULT", "HIGH", "HIGHEST")
 
 _SINGLE_DEVICE_ALGOS = ("direct", "im2col", "fft", "winograd", "mec",
-                        "mec_lowered", "mec_fused", "mec_fused2")
-# Pallas variants: the only algorithms whose plan carries a w_blk.
-_PALLAS_ALGOS = ("mec_lowered", "mec_fused", "mec_fused2")
+                        "mec_fused")
 
 PLAN_MODES = ("analytic", "measured", "cached")
 
@@ -231,37 +229,34 @@ class ConvPlan:
             "  candidate costs (Eq. 2-4 overhead elems / flops):",
         ]
         costs = conv2d_algorithm_costs(s)
-        base = {"mec_lowered": "mec", "mec_fused": "direct",
-                "mec_fused2": "direct"}.get(self.algorithm, self.algorithm)
+        base = "direct" if self.algorithm == "mec_fused" else self.algorithm
         for alg in sorted(costs):
             mark = " <- plan" if alg == base else ""
             c = costs[alg]
             lines.append(f"    {alg:8s} overhead={c['overhead_elems']:.3e} "
                          f"flops={c['flops']:.3e}{mark}")
-        if self.algorithm in _PALLAS_ALGOS:
+        if self.algorithm == "mec_fused":
+            import numpy as np
+            from repro.core.conv_api import (fused_input_grad_refusal,
+                                             input_grad_spec)
+            from repro.kernels.mec_conv import fused_blocks
+            from repro.kernels.ops import pick_w_blk
             lines.append("  (Pallas kernel: lowering stays in VMEM; "
                          "HBM overhead is the direct conv's)")
-            if self.algorithm == "mec_fused":
-                import numpy as np
-                from repro.kernels.mec_conv import fused_blocks
-                from repro.kernels.ops import pick_w_blk
-                w_blk = self.w_blk or pick_w_blk(s.o_w, s.k_c,
-                                                 _warn_env=False)
-                fb = fused_blocks(s.i_n, s.i_h, s.i_w, s.i_c, s.k_h,
-                                  s.k_w, s.k_c, s.s_h, s.s_w, w_blk,
-                                  np.dtype(self.dtype).itemsize)
-                lines.append(f"  mec_fused blocking: {fb.describe()}")
-                from repro.core.conv_api import (fused_input_grad_refusal,
-                                                 input_grad_spec)
-                why = fused_input_grad_refusal(s, self.dtype)
-                lines.append(
-                    "  input gradient: the mec_fused kernel on the "
-                    f"transposed conv {spec_key(input_grad_spec(s))}"
-                    if why is None else
-                    f"  input gradient: XLA (_mec_input_grad), {why}")
+            w_blk = self.w_blk or pick_w_blk(s.o_w, s.k_c, _warn_env=False)
+            fb = fused_blocks(s.i_n, s.i_h, s.i_w, s.i_c, s.k_h, s.k_w,
+                              s.k_c, s.s_h, s.s_w, w_blk,
+                              np.dtype(self.dtype).itemsize)
+            lines.append(f"  mec_fused blocking: {fb.describe()}")
+            why = fused_input_grad_refusal(s, self.dtype)
+            lines.append(
+                "  input gradient: the mec_fused kernel on the "
+                f"transposed conv {spec_key(input_grad_spec(s))}"
+                if why is None else
+                f"  input gradient: XLA (_mec_input_grad), {why}")
         elif self.backend == "tpu":
-            from repro.launch.costmodel import tpu_fused_ineligibility
-            why = tpu_fused_ineligibility(s, self.dtype)
+            from repro.kernels.mec_conv import fused_refusal
+            why = fused_refusal(s, self.dtype)
             if why:
                 lines.append(f"  mec_fused not taken on tpu: {why}")
         if self.partition is None:
@@ -381,12 +376,30 @@ def _hit_satisfies(hit: ConvPlan, precision_name: Optional[str],
 
 
 def _pallas_w_blk(spec: ConvSpec, algorithm: str) -> Optional[int]:
-    if algorithm not in _PALLAS_ALGOS:
+    if algorithm != "mec_fused":
         return None
     from repro.kernels.ops import pick_w_blk
     # The planner is the supported home for the accumulator budget; the
     # env override applies here without the deprecation warning.
     return pick_w_blk(spec.o_w, spec.k_c, _warn_env=False)
+
+
+def _kernel_refusal(plan: ConvPlan) -> Optional[str]:
+    """Why the Pallas kernel cannot run ``plan``'s own block, or None
+    (also None for a plan that runs no kernel)."""
+    if plan.algorithm != "mec_fused":
+        return None
+    from repro.kernels.mec_conv import fused_refusal
+    return fused_refusal(plan.spec, plan.dtype, plan.w_blk)
+
+
+def _assert_kernel_runs(plan: ConvPlan) -> None:
+    """Never return (or let the cached policy store) a plan whose block
+    the kernel's rule refuses — raising here beats faulting at execute."""
+    why = _kernel_refusal(plan)
+    if why:
+        raise ValueError(f"mec_fused cannot run the plan for "
+                         f"{spec_key(plan.spec)}: {why}")
 
 
 # A measured flip needs to clear this margin over the analytic pick —
@@ -422,24 +435,12 @@ def pick_measured(times: Dict[str, float], analytic: str,
     return best
 
 
-def eligible_candidates(spec: ConvSpec,
-                        backend: Optional[str] = None) -> Tuple[str, ...]:
-    """conv2d algorithm names the measured policy may time on a spec.
-    On a TPU backend only the Pallas kernels with a Mosaic lowering
-    (``repro.kernels.ops.MOSAIC_MODES``) are candidates."""
-    import jax
-    from repro.kernels.ops import MOSAIC_MODES
-    on_tpu = (backend or jax.default_backend()) == "tpu"
-    algs = []
-    for alg in _SINGLE_DEVICE_ALGOS:
-        if alg == "winograd" and \
-                (spec.k_h, spec.k_w, spec.s_h, spec.s_w) != (3, 3, 1, 1):
-            continue
-        if on_tpu and alg in _PALLAS_ALGOS and \
-                alg[len("mec_"):] not in MOSAIC_MODES:
-            continue
-        algs.append(alg)
-    return tuple(algs)
+def eligible_candidates(spec: ConvSpec) -> Tuple[str, ...]:
+    """conv2d algorithm names the measured policy may time on a spec:
+    every single-device algorithm, winograd only on 3x3 stride 1."""
+    return tuple(alg for alg in _SINGLE_DEVICE_ALGOS
+                 if alg != "winograd"
+                 or (spec.k_h, spec.k_w, spec.s_h, spec.s_w) == (3, 3, 1, 1))
 
 
 @dataclasses.dataclass
@@ -501,8 +502,8 @@ def measure_candidates_detailed(
     (resolved solution, planner-derived w_blk, named precision), and
     the planner's w_blk derivation stays on the warning-free path.
 
-    Candidates that cannot be timed — the Pallas geometry checker
-    rejects the trial plan, or compilation/execution raises — are never
+    Candidates that cannot be timed — the kernel's geometry rule
+    refuses the trial plan, or compilation/execution raises — are never
     dropped silently: each lands in ``.skipped`` with its reason (and a
     warning), so the autotune report can show exactly what the race was
     missing.  With ``record=True`` every successful trial is added to
@@ -525,18 +526,13 @@ def measure_candidates_detailed(
             solution=pick_solution(spec) if alg == "mec" else "auto",
             w_blk=_pallas_w_blk(spec, alg), precision=precision_name,
             backend=jax.default_backend())
-        if alg in _PALLAS_ALGOS:
-            # Static geometry gate (repro.analysis.pallas_check): a
-            # candidate the checker rejects would fault or overrun VMEM
-            # on a real TPU — never time it, never let it win.
-            from repro.analysis.pallas_check import check_plan
-            verdict = check_plan(trial)
-            if not verdict.ok:
-                reason = "pallas_check: " + \
-                    verdict.render().replace("\n", "; ")
-                out.skipped[alg] = reason
-                warnings.warn(f"measured planning skips {alg}: {reason}")
-                continue
+        why = _kernel_refusal(trial)
+        if why:
+            # A block the kernel's rule refuses would fault or overrun
+            # VMEM on a real TPU — never time it, never let it win.
+            out.skipped[alg] = why
+            warnings.warn(f"measured planning skips {alg}: {why}")
+            continue
         try:
             timing = _time_trial(trial, inp, ker, iters, warmup, interpret)
         except Exception as e:
@@ -575,9 +571,9 @@ def _stage2_trials(spec: ConvSpec, dtype: str, algorithm: str,
 
     mec: both §3.2 solutions (A = h-direction Solution 1, B =
     w-direction Solution 2) — ``pick_solution``'s T=100 rule is exactly
-    the kind of paper constant the measurements should audit.  Pallas
-    variants: the planner's ``pick_w_blk`` default plus half and double
-    (clamped to [8, o_w]), each re-checked by the geometry gate.  Other
+    the kind of paper constant the measurements should audit.
+    ``mec_fused``: the planner's ``pick_w_blk`` default plus half and
+    double (clamped to [8, o_w]), each one the kernel's rule admits.  Other
     algorithms have no plan-level knob.  Returns (knob_name, {label:
     trial plan}) or (None, {}).
     """
@@ -587,8 +583,7 @@ def _stage2_trials(spec: ConvSpec, dtype: str, algorithm: str,
                                backend=backend)
                  for sol in ("A", "B")}
         return "solution", plans
-    if algorithm in _PALLAS_ALGOS:
-        from repro.analysis.pallas_check import check_plan
+    if algorithm == "mec_fused":
         default = _pallas_w_blk(spec, algorithm)
         grid = {default, max(8, default // 2), min(spec.o_w, default * 2)}
         plans = {}
@@ -596,7 +591,7 @@ def _stage2_trials(spec: ConvSpec, dtype: str, algorithm: str,
             trial = ConvPlan(spec=spec, dtype=dtype, algorithm=algorithm,
                              w_blk=blk, precision=precision_name,
                              backend=backend)
-            if check_plan(trial).ok:
+            if not _kernel_refusal(trial):
                 plans[str(blk)] = trial
         return "w_blk", plans
     return None, {}
@@ -688,11 +683,7 @@ def tune_measured(spec: ConvSpec, dtype: str = "float32",
                     solution=solution, w_blk=w_blk,
                     precision=precision_name, backend=backend,
                     mode="measured")
-    if plan.algorithm in _PALLAS_ALGOS:
-        # Never return a Pallas plan the static checker rejects —
-        # raising here beats faulting at execute.
-        from repro.analysis.pallas_check import assert_plan
-        assert_plan(plan)
+    _assert_kernel_runs(plan)
     detail = {"analytic_algorithm": analytic,
               "candidate_us": dict(mc.times),
               "candidate_stats": mc.stats,
@@ -770,12 +761,12 @@ def plan_conv2d(spec: ConvSpec, *, dtype="float32", mode: str = "analytic",
             spec, dtype, backend=backend, precision=precision_name,
             candidates=candidates, iters=iters, warmup=warmup,
             interpret=interpret, calibration=calibration)
-        # tune_measured already ran the Pallas assert; replaying it
+        # tune_measured already ran the kernel's rule; replaying it
         # through replace() only re-runs __post_init__ validation.
         plan = dataclasses.replace(base, partition=parts,
                                    partition_axes=axes)
         if plan.partition:
-            # Same rule as the Pallas hook: never return a partitioned
+            # Same stance as the kernel's rule: never return a partitioned
             # plan whose compiled collectives break the costmodel
             # contract (skips silently when no mesh is installed).
             from repro.analysis.shardcheck import assert_plan_contract
@@ -799,11 +790,7 @@ def plan_conv2d(spec: ConvSpec, *, dtype="float32", mode: str = "analytic",
                     precision=precision_name,
                     partition=parts, partition_axes=axes,
                     backend=backend, mode=mode)
-    if plan.algorithm in _PALLAS_ALGOS:
-        # Never return (or let the cached policy store) a Pallas plan the
-        # static checker rejects — raising here beats faulting at execute.
-        from repro.analysis.pallas_check import assert_plan
-        assert_plan(plan)
+    _assert_kernel_runs(plan)
     if plan.partition:
         # Partitioned plans additionally pass the collective contract
         # (halo/psum bytes vs. the costmodel, no accidental resharding;

@@ -1,7 +1,7 @@
 """Tests for the repro.analysis subsystem (DESIGN.md §8): the HLO
-memory auditor, the static Pallas geometry checker, and the
-repo-invariant lint pass."""
-import dataclasses
+memory auditor and the repo-invariant lint pass; and for mec_fused's
+geometry rule (``repro.kernels.mec_conv.fused_refusal``), which the
+planner asks of every kernel plan."""
 import json
 import pathlib
 import textwrap
@@ -9,87 +9,98 @@ import textwrap
 import pytest
 
 from repro.analysis import lint
-from repro.analysis.pallas_check import (PALLAS_ALGORITHMS,
-                                         PallasCheckError, assert_plan,
-                                         check_geometry, check_plan)
 from repro.core.convspec import ConvSpec
+from repro.kernels import ops
+from repro.kernels.mec_conv import fused_refusal
 from repro.plan.convplan import ConvPlan
 
 SMALL = ConvSpec(1, 14, 14, 4, 3, 3, 8)
 STRIDED = ConvSpec(1, 23, 23, 3, 11, 11, 8, 4, 4)
 
 
+def _planner_w_blk(spec):
+    return ops.pick_w_blk(spec.o_w, spec.k_c, _warn_env=False)
+
+
 # ---------------------------------------------------------------------------
-# pallas_check
+# mec_fused's geometry rule (repro.kernels.mec_conv.fused_refusal)
 # ---------------------------------------------------------------------------
 
-def test_pallas_check_accepts_all_committed_plans():
-    """Acceptance criterion: every plan in the committed baseline passes."""
+def test_fused_refusal_admits_every_committed_plan_geometry():
+    """Every plan in the committed baseline runs, and every one of its
+    geometries would run on mec_fused at the planner's block."""
     from repro.analysis.memaudit import DEFAULT_PLANS, load_plans
     root = pathlib.Path(__file__).resolve().parents[1]
     plans = load_plans(root / DEFAULT_PLANS)
     assert len(plans) >= 15
     for name, plan in plans.items():
-        result = check_plan(plan)
-        assert result.ok, f"{name}: {result.render()}"
+        if plan.algorithm == "mec_fused":
+            assert fused_refusal(plan.spec, plan.dtype, plan.w_blk) is None
+        why = fused_refusal(plan.spec, plan.dtype,
+                            _planner_w_blk(plan.spec))
+        assert why is None, f"{name}: {why}"
 
 
-@pytest.mark.parametrize("alg", PALLAS_ALGORITHMS)
 @pytest.mark.parametrize("spec", [SMALL, STRIDED],
                          ids=["3x3", "11x11s4"])
-def test_pallas_check_accepts_planner_geometries(alg, spec):
-    """Planner-derived w_blk on every Pallas variant must check clean,
-    and the mirror must actually model kernels (non-empty geometry)."""
-    result = check_geometry(spec, alg, None, "float32")
-    assert result.ok, result.render()
-    assert result.pallas and result.kernels
-    assert result.vmem_bytes > 0
-    expected = 2 if alg == "mec_lowered" else 1
-    assert len(result.kernels) == expected
+def test_fused_refusal_admits_planner_geometries(spec):
+    """The planner's block runs, and its blocking holds a working set."""
+    from repro.kernels.mec_conv import fused_blocks
+    w_blk = _planner_w_blk(spec)
+    assert fused_refusal(spec, "float32", w_blk) is None
+    fb = fused_blocks(spec.i_n, spec.i_h, spec.i_w, spec.i_c, spec.k_h,
+                      spec.k_w, spec.k_c, spec.s_h, spec.s_w, w_blk, 4)
+    assert 0 < fb.vmem_bytes <= ops.vmem_bytes()
 
 
-def test_pallas_check_rejects_oversized_w_blk():
-    """Acceptance criterion: a deliberately-oversized w_blk is rejected
-    statically — ConvPlan itself doesn't validate w_blk against o_w, so
-    the checker is the gate."""
+def test_fused_refusal_rejects_oversized_w_blk():
+    """A deliberately oversized w_blk is refused — ConvPlan itself does
+    not validate w_blk against o_w, so the rule is the gate, and the
+    planner raises on such a plan."""
+    from repro.plan.convplan import _assert_kernel_runs
     plan = ConvPlan(spec=SMALL, dtype="float32", algorithm="mec_fused",
                     w_blk=SMALL.o_w * 4)
-    result = check_plan(plan)
-    assert not result.ok
-    assert {v.rule for v in result.violations} == {"w-blk-out-of-range"}
-    with pytest.raises(PallasCheckError, match="w-blk-out-of-range"):
-        assert_plan(plan)
+    why = fused_refusal(plan.spec, plan.dtype, plan.w_blk)
+    assert why == f"w_blk={SMALL.o_w * 4} outside [1, o_w={SMALL.o_w}]"
+    with pytest.raises(ValueError, match="outside"):
+        _assert_kernel_runs(plan)
 
 
-def test_pallas_check_rejects_vmem_overrun():
+def test_fused_refusal_rejects_vmem_overrun(monkeypatch):
     big = ConvSpec(1, 64, 4096, 64, 3, 3, 256)
-    result = check_geometry(big, "mec_fused", 512, "float32",
-                            vmem_budget=1 << 16, acc_budget=1 << 20)
-    assert not result.ok
-    assert any(v.rule == "vmem-budget-overrun" for v in result.violations)
+    monkeypatch.setattr(ops, "vmem_bytes", lambda: 1 << 16)
+    monkeypatch.setattr(ops, "accumulator_budget",
+                        lambda _warn_env=True: 1 << 20)
+    assert "exceeds VMEM" in fused_refusal(big, "float32", 512)
 
 
-def test_pallas_check_rejects_accumulator_overrun():
-    result = check_geometry(SMALL, "mec_fused", SMALL.o_w, "float32",
-                            acc_budget=4)   # 12*8*4 f32 >> 4 bytes
-    assert any(v.rule == "accumulator-overrun"
-               for v in result.violations)
+def test_fused_refusal_rejects_accumulator_overrun(monkeypatch):
+    monkeypatch.setattr(ops, "accumulator_budget",
+                        lambda _warn_env=True: 4)   # < one f32 row
+    why = fused_refusal(SMALL, "float32", SMALL.o_w)
+    assert "accumulator budget 4 B" in why
 
 
-def test_pallas_check_non_pallas_trivially_ok():
+def test_fused_refusal_rejects_unaligned_column_blocks():
+    """Several column blocks must start on whole sublane tiles: 8 rows
+    in float32, 16 in bfloat16."""
+    wide = ConvSpec(1, 3, 42, 4, 3, 3, 8)           # o_w = 40
+    assert fused_refusal(wide, "float32", 20) is not None
+    assert fused_refusal(wide, "float32", 24) is None
+    assert fused_refusal(wide, "bfloat16", 24) is not None
+    assert fused_refusal(wide, "bfloat16", 32) is None
+
+
+def test_kernel_rule_skips_plans_without_a_kernel():
+    """The planner asks the kernel's rule of mec_fused plans only."""
+    from repro.plan.convplan import _kernel_refusal
     plan = ConvPlan(spec=SMALL, dtype="float32", algorithm="mec",
-                    solution="A")
-    result = check_plan(plan)
-    assert result.ok and not result.pallas and not result.kernels
-
-
-def test_pallas_check_fused2_fallback_geometry():
-    """k_h < s_h (halo < 0): fused2 falls back to the v1 kernel — the
-    mirror must model what actually runs."""
-    spec = ConvSpec(1, 16, 16, 2, 1, 1, 4, 2, 2)
-    result = check_geometry(spec, "mec_fused2", None, "float32")
-    assert result.ok, result.render()
-    assert result.kernels[0].name == "mec_fused"
+                    solution="A", w_blk=None)
+    assert _kernel_refusal(plan) is None
+    bad = ConvPlan(spec=SMALL, dtype="float32", algorithm="mec_fused",
+                   w_blk=SMALL.o_w * 4)
+    assert _kernel_refusal(bad) == fused_refusal(SMALL, "float32",
+                                                 SMALL.o_w * 4)
 
 
 # The resnet101_t3 stages as the benchmark runs them (3x3 inputs
@@ -101,53 +112,6 @@ RESNET_STAGES = {
     "cv11": (ConvSpec(32, 16, 16, 256, 3, 3, 256), 23),
     "cv12": (ConvSpec(32, 9, 9, 512, 3, 3, 512), 3),
 }
-
-
-def _pallas_call_geometry(spec, dtype, w_blk):
-    """Grid and block shapes of the pallas_call the fused wrapper traces."""
-    import jax
-    import jax.numpy as jnp
-    from repro.kernels.mec_conv import mec_conv_fused_pallas
-    x = jax.ShapeDtypeStruct((spec.i_n, spec.i_h, spec.i_w, spec.i_c),
-                             jnp.dtype(dtype))
-    k = jax.ShapeDtypeStruct((spec.k_h, spec.k_w, spec.i_c, spec.k_c),
-                             jnp.dtype(dtype))
-    jaxpr = jax.make_jaxpr(lambda a, b: mec_conv_fused_pallas(
-        a, b, (spec.s_h, spec.s_w), w_blk=w_blk, interpret=True))(x, k)
-
-    def find(jx):
-        for eqn in jx.eqns:
-            if eqn.primitive.name == "pallas_call":
-                return eqn
-            for v in eqn.params.values():
-                inner = getattr(v, "jaxpr", None)
-                inner = getattr(inner, "jaxpr", inner)
-                if inner is not None and hasattr(inner, "eqns"):
-                    found = find(inner)
-                    if found is not None:
-                        return found
-        return None
-
-    gm = find(jaxpr.jaxpr).params["grid_mapping"]
-    blocks = [tuple(getattr(d, "block_size", d) for d in bm.block_shape)
-              for bm in gm.block_mappings]
-    return tuple(gm.grid), dict(zip(("input", "kernel", "output"), blocks))
-
-
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-@pytest.mark.parametrize("stage", sorted(RESNET_STAGES))
-def test_fused_mirror_matches_pallas_call(stage, dtype):
-    """The checker's mec_fused mirror derives its grid and blocks from
-    the kernel's own blocking, and they are the pallas_call's."""
-    from repro.kernels.ops import pick_w_blk
-    spec, _ = RESNET_STAGES[stage]
-    w_blk = pick_w_blk(spec.o_w, spec.k_c, _warn_env=False)
-    result = check_geometry(spec, "mec_fused", w_blk, dtype)
-    assert result.ok, result.render()
-    (kernel,) = result.kernels
-    grid, blocks = _pallas_call_geometry(spec, dtype, w_blk)
-    assert kernel.grid == grid
-    assert kernel.blocks == blocks
 
 
 def test_fused_blocking_engages_on_every_stage():
@@ -178,13 +142,12 @@ def test_plan_conv2d_never_returns_rejected_pallas_plan(monkeypatch):
     from repro.plan import convplan
 
     def bad_w_blk(spec, algorithm):
-        return None if algorithm not in convplan._PALLAS_ALGOS \
-            else spec.o_w * 10
+        return None if algorithm != "mec_fused" else spec.o_w * 10
     monkeypatch.setattr(convplan, "_pallas_w_blk", bad_w_blk)
     monkeypatch.setattr(
         "repro.launch.costmodel.pick_conv2d_algorithm",
         lambda spec, backend, **kw: "mec_fused")
-    with pytest.raises(PallasCheckError):
+    with pytest.raises(ValueError, match="mec_fused cannot run the plan"):
         convplan.plan_conv2d(SMALL, mode="analytic")
 
 
@@ -192,8 +155,7 @@ def test_measure_candidates_skips_rejected_pallas(monkeypatch):
     from repro.plan import convplan
 
     def bad_w_blk(spec, algorithm):
-        return None if algorithm not in convplan._PALLAS_ALGOS \
-            else spec.o_w * 10
+        return None if algorithm != "mec_fused" else spec.o_w * 10
     monkeypatch.setattr(convplan, "_pallas_w_blk", bad_w_blk)
     with pytest.warns(UserWarning, match="measured planning skips"):
         times = convplan.measure_candidates(
@@ -416,7 +378,7 @@ def test_lint_tree_is_clean_against_committed_baseline():
 
 
 # ---------------------------------------------------------------------------
-# autotune trial replay (the --suite pallas coverage extension)
+# autotune trial replay
 # ---------------------------------------------------------------------------
 
 def test_autotune_stage2_w520_grid_passes_geometry():
@@ -430,26 +392,24 @@ def test_autotune_stage2_w520_grid_passes_geometry():
     assert knob == "w_blk"
     assert set(plans) == {"256", "512", "520"}
     for label, trial in plans.items():
-        res = check_geometry(trial.spec, "mec_fused", trial.w_blk,
-                             "float32")
-        assert res.ok, f"w_blk={label}: {res.render()}"
+        why = fused_refusal(trial.spec, "float32", trial.w_blk)
+        assert why is None, f"w_blk={label}: {why}"
 
 
 def test_committed_autotune_trials_replay_clean():
-    """Every (Pallas) w_blk the committed BENCH_autotune.json actually
-    trialed replays through the static geometry gate."""
+    """Every mec_fused w_blk the committed BENCH_autotune.json actually
+    trialed is one the kernel's rule admits."""
     root = pathlib.Path(__file__).resolve().parents[1]
     doc = json.loads((root / "BENCH_autotune.json").read_text())
     replayed = 0
     for r in doc["results"]:
         tuning = r.get("tuning")
-        if not tuning or tuning.get("algorithm") not in PALLAS_ALGORITHMS:
+        if not tuning or tuning.get("algorithm") != "mec_fused":
             continue
         spec = ConvSpec(**r["run_spec"])
         for label, t in tuning["trials"].items():
-            res = check_geometry(spec, tuning["algorithm"], t.get("w_blk"),
-                                 r["dtype"])
-            assert res.ok, f"{r['scenario']} w_blk={label}: {res.render()}"
+            why = fused_refusal(spec, r["dtype"], t["w_blk"])
+            assert why is None, f"{r['scenario']} w_blk={label}: {why}"
             replayed += 1
     assert replayed >= 3      # the w520 grid alone contributes three
 
@@ -459,6 +419,9 @@ def test_committed_autotune_trials_replay_clean():
 # ---------------------------------------------------------------------------
 
 def test_cli_lint_and_pallas_suites_green():
+    """The lint suite is green; the kernel's geometry rule has no suite
+    of its own (its sweeps are the tests above)."""
     from repro.analysis.__main__ import main
     assert main(["--suite", "lint"]) == 0
-    assert main(["--suite", "pallas"]) == 0
+    with pytest.raises(SystemExit):
+        main(["--suite", "pallas"])

@@ -362,7 +362,7 @@ def test_smoke_w520_is_a_kernel_tuning_cell():
     # strictly larger (single-grid-step) block to find.
     from repro.kernels.ops import pick_w_blk
     sc = {s.name: s for s in resolve_suite("smoke")}["w520"]
-    assert sc.tune_candidates == ("mec_lowered", "mec_fused", "mec_fused2")
+    assert sc.tune_candidates == ("mec_fused",)
     assert set(sc.algorithms) == set(sc.tune_candidates)
     default = pick_w_blk(sc.run_spec.o_w, sc.run_spec.k_c, _warn_env=False)
     assert default < sc.run_spec.o_w

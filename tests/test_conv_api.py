@@ -11,8 +11,8 @@ from jax import lax
 
 from repro.core import ALGORITHMS, MEC_ALGORITHMS, conv2d, conv2d_spec
 
-GRID_ALGS = ["direct", "im2col", "fft", "winograd", "mec", "mec_lowered",
-             "mec_fused", "mec_fused2", "auto"]
+GRID_ALGS = ["direct", "im2col", "fft", "winograd", "mec", "mec_fused",
+             "auto"]
 
 
 def _rand(shape, seed, dtype=jnp.float32):
@@ -104,7 +104,7 @@ def test_auto_dispatch_consults_costmodel():
 GRAD_CASES = [pytest.param(alg, stride, 3, 4, "float32",
                            id=f"{alg}-{stride}")
               for stride in (1, 2)
-              for alg in ("mec", "mec_fused", "mec_lowered")] + [
+              for alg in ("mec", "mec_fused")] + [
     pytest.param(alg, 1, i_c, k_c, dtype,
                  id=f"{alg}-1-{i_c}to{k_c}-{dtype}")
     for alg in ("mec", "mec_fused")

@@ -8,7 +8,6 @@ import pytest
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.mec_conv import mec_gemm_pallas, mec_lower_pallas
 from repro.kernels.ops import mec_conv1d_tpu, mec_conv2d_tpu
 
 SWEEP = [
@@ -32,28 +31,17 @@ def _rand(shape, seed, dtype):
 
 @pytest.mark.parametrize("geom", SWEEP)
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("mode", ["fused", "fused2", "lowered"])
-def test_mec_conv2d_kernel(geom, dtype, mode):
+def test_mec_conv2d_kernel(geom, dtype):
     ih, iw, ic, kh, kw, kc, s = geom
     inp = _rand((2, ih, iw, ic), 0, dtype)
     ker = _rand((kh, kw, ic, kc), 1, dtype)
     oracle = ref.conv2d_ref(inp.astype(jnp.float32),
                             ker.astype(jnp.float32), s)
-    out = mec_conv2d_tpu(inp, ker, s, mode=mode, interpret=True)
+    out = mec_conv2d_tpu(inp, ker, s, interpret=True)
     assert out.shape == oracle.shape
     tol = 2e-4 if dtype == jnp.float32 else 4e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(oracle), rtol=tol, atol=tol)
-
-
-@pytest.mark.parametrize("geom", SWEEP[:5])
-def test_mec_lower_kernel(geom):
-    ih, iw, ic, kh, kw, kc, s = geom
-    s_w = s[1] if isinstance(s, tuple) else s
-    inp = _rand((2, ih, iw, ic), 2, jnp.float32)
-    out = mec_lower_pallas(inp, kw, s_w, interpret=True)
-    oracle = ref.lower_ref(inp, kw, s_w)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle))
 
 
 @pytest.mark.parametrize("t,c,kw", [(10, 5, 4), (1024, 256, 4), (33, 7, 3),
@@ -79,7 +67,7 @@ def test_mec_fused_kernel_property(ih, iw, ic, kh, kw, kc, sh, sw):
     inp = _rand((1, ih, iw, ic), 5, jnp.float32)
     ker = _rand((kh, kw, ic, kc), 6, jnp.float32)
     oracle = ref.conv2d_ref(inp, ker, (sh, sw))
-    out = mec_conv2d_tpu(inp, ker, (sh, sw), mode="fused", interpret=True)
+    out = mec_conv2d_tpu(inp, ker, (sh, sw), interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                rtol=2e-4, atol=2e-4)
 
@@ -136,22 +124,12 @@ def test_mec_fused_blocking_matches_direct(case, dtype):
     ker = _rand((kh, kw, ic, kc), 12, dtype) / np.sqrt(kh * kw * ic)
     oracle = ref.conv2d_ref(inp.astype(jnp.float32),
                             ker.astype(jnp.float32), (s_h, s_w))
-    out = mec_conv2d_tpu(inp, ker, (s_h, s_w), mode="fused",
-                         interpret=True, w_blk=w_blk)
+    out = mec_conv2d_tpu(inp, ker, (s_h, s_w), interpret=True,
+                         w_blk=w_blk)
     assert out.shape == oracle.shape and out.dtype == dtype
     tol = 2e-4 if dtype == jnp.float32 else 4e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(oracle), rtol=tol, atol=tol)
-
-
-def test_lowered_gemm_matches_fused():
-    """The two kernel modes are numerically identical paths."""
-    inp = _rand((2, 14, 14, 4), 7, jnp.float32)
-    ker = _rand((3, 3, 4, 8), 8, jnp.float32)
-    a = mec_conv2d_tpu(inp, ker, 1, mode="fused", interpret=True)
-    b = mec_conv2d_tpu(inp, ker, 1, mode="lowered", interpret=True)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=1e-5, atol=1e-5)
 
 
 def test_accumulator_budget_env_and_default(monkeypatch):
@@ -239,22 +217,23 @@ class _FakeTPU:
 
 def test_tpu_backend_refuses_interpret_and_unlowerable_modes(monkeypatch):
     """On a TPU backend the kernels compile through Mosaic or fail loudly:
-    interpret=True is an error, and a mode without a Mosaic lowering
-    raises before any tracing."""
+    interpret=True is an error, raised before any tracing."""
     import jax
     from repro.kernels import ops
     assert ops.resolve_interpret(None) is True          # CPU: interpreter
+    assert ops.resolve_interpret(True) is True
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert ops.resolve_interpret(None, "fused") is False
+    assert ops.resolve_interpret(None) is False
+    assert ops.resolve_interpret(False) is False
     with pytest.raises(ValueError, match="interpret=True"):
-        ops.resolve_interpret(True, "fused")
-    for mode in ("fused2", "lowered"):
-        with pytest.raises(NotImplementedError, match="Mosaic"):
-            ops.resolve_interpret(None, mode)
+        ops.resolve_interpret(True)
     inp, ker = _rand((1, 6, 6, 2), 0, jnp.float32), \
         _rand((3, 3, 2, 4), 1, jnp.float32)
-    with pytest.raises(NotImplementedError):
-        mec_conv2d_tpu(inp, ker, mode="fused2")
+    with pytest.raises(ValueError, match="interpret=True"):
+        mec_conv2d_tpu(inp, ker, interpret=True)
+    with pytest.raises(ValueError, match="interpret=True"):
+        mec_conv1d_tpu(jnp.ones((1, 8, 4)), jnp.ones((3, 4)),
+                       interpret=True)
 
 
 def test_vmem_bytes_unknown_tpu_kind_raises(monkeypatch):

@@ -26,9 +26,7 @@ from repro.plan.cache import reset_global_plan_cache
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_ALGOS = ("direct", "im2col", "fft", "winograd", "mec", "mec_lowered",
-          "mec_fused", "mec_fused2")
-_PALLAS = ("mec_lowered", "mec_fused", "mec_fused2")
+_ALGOS = ("direct", "im2col", "fft", "winograd", "mec", "mec_fused")
 # (partition, axes) combos a plan may carry — None through composite.
 _PARTITIONS = (
     (None, None),
@@ -72,7 +70,8 @@ def test_plan_json_roundtrip_property(n, c, kc, s, algorithm, solution,
     disk cache AND the committed baseline — it must be lossless)."""
     k = 3
     spec = ConvSpec(n, 8 * s, 8 * s, c, k, k, kc, s, s)
-    w_blk = pick_w_blk(spec.o_w, spec.k_c) if algorithm in _PALLAS else None
+    w_blk = pick_w_blk(spec.o_w, spec.k_c) if algorithm == "mec_fused" \
+        else None
     plan = ConvPlan(spec=spec, dtype=dtype, algorithm=algorithm,
                     solution=solution, w_blk=w_blk, precision=precision,
                     partition=part[0], partition_axes=part[1],
@@ -142,7 +141,7 @@ def test_conv2d_plan_bit_identical_to_kwargs(algorithm, stride):
     plan = ConvPlan(
         spec=spec, dtype="float32", algorithm=algorithm,
         w_blk=(pick_w_blk(spec.o_w, spec.k_c)
-               if algorithm in _PALLAS else None))
+               if algorithm == "mec_fused" else None))
     out_plan = conv2d(inp, ker, stride=stride, padding="SAME", plan=plan)
     out_kw = conv2d(inp, ker, stride=stride, padding="SAME",
                     algorithm=algorithm, partition="none")
@@ -225,6 +224,14 @@ def test_cache_survives_process_via_disk(fresh_cache):
     doc = json.loads(files[0].read_text())
     assert doc["plan_cache_version"] == 1
     assert plan.cache_key() in doc["plans"]
+    # an entry naming an algorithm this version does not have is skipped as
+    # stale, and the rest of the file still loads
+    doc["plans"]["gone"] = dict(doc["plans"][plan.cache_key()],
+                                algorithm="toeplitz")
+    files[0].write_text(json.dumps(doc))
+    again = PlanCache(path=files[0])
+    assert again.get("gone") is None
+    assert again.get(plan.cache_key()) == plan
 
 
 def test_cache_lru_and_corruption_tolerance(tmp_path):
@@ -381,65 +388,187 @@ def test_measured_mode_picks_a_timed_winner(fresh_cache):
 
 
 def test_tpu_candidates_and_pick_follow_the_mosaic_rule():
-    """On TPU only kernels with a Mosaic lowering are candidates, and a
-    geometry the fused kernel cannot take is routed to direct by an
-    explicit predicate that explain() reports."""
-    from repro.launch.costmodel import (pick_conv2d_algorithm,
-                                        tpu_fused_ineligibility)
+    """The one Pallas kernel is a candidate, and a geometry it cannot
+    take is routed to direct by the kernel's own rule, which explain()
+    reports."""
+    from repro.kernels.mec_conv import fused_refusal
+    from repro.launch.costmodel import pick_conv2d_algorithm
     spec = ConvSpec(1, 10, 10, 4, 3, 3, 4, 1, 1)
-    tpu = eligible_candidates(spec, backend="tpu")
-    assert "mec_fused" in tpu
-    assert not {"mec_fused2", "mec_lowered"} & set(tpu)
-    assert {"mec_fused2", "mec_lowered"} <= set(
-        eligible_candidates(spec, backend="cpu"))
-    assert tpu_fused_ineligibility(spec) is None
+    assert eligible_candidates(spec) == ("direct", "im2col", "fft",
+                                         "winograd", "mec", "mec_fused")
+    assert fused_refusal(spec, "float32") is None
     assert pick_conv2d_algorithm(spec, "tpu") == "mec_fused"
     # 4096 input channels: even one 128-channel block of the 3x3
-    # kernel, double-buffered, overruns VMEM, so the checker refuses it
+    # kernel, double-buffered, overruns VMEM, so the rule refuses it
     wide = ConvSpec(1, 3, 1002, 4096, 3, 3, 2048, 1, 1)
-    why = tpu_fused_ineligibility(wide)
-    assert why
+    why = fused_refusal(wide, "float32")
+    assert "exceeds VMEM" in why
     assert pick_conv2d_algorithm(wide, "tpu") == "direct"
     plan = plan_conv2d(wide, backend="tpu")
     assert plan.algorithm == "direct"
     assert "mec_fused not taken on tpu" in plan.explain()
 
 
+# Every distinct conv of ResNet-101 at batch 32 (as repro.models.resnet
+# pads them), resnet101_t3's cv4, MEC Table 2's cv1-cv12, then the
+# transposed conv (the input gradient) of each stride-1 kxk conv among
+# them: geometry, then for bfloat16 and float32 the TPU pick, the
+# planner's w_blk, mec_fused's grid and whether the input gradient runs
+# on the kernel.
+TPU_PICKS = [
+    ((32, 230, 230, 3, 7, 7, 64, 2, 2),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 56, 56, 64, 1, 1, 64, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 58, 58, 64, 3, 3, 64, 1, 1),
+     ("mec_fused", 56, (1, 32, 1, 1), True),
+     ("mec_fused", 56, (1, 32, 1, 1), True)),
+    ((32, 56, 56, 64, 1, 1, 256, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 56, 56, 256, 1, 1, 64, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 56, 56, 256, 1, 1, 128, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 58, 58, 128, 3, 3, 128, 2, 2),
+     ("mec_fused", 28, (1, 8, 1, 1), False),
+     ("mec_fused", 28, (1, 16, 1, 1), False)),
+    ((32, 28, 28, 128, 1, 1, 512, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 56, 56, 256, 1, 1, 512, 2, 2),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 28, 28, 512, 1, 1, 128, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 30, 30, 128, 3, 3, 128, 1, 1),
+     ("mec_fused", 28, (1, 8, 1, 1), True),
+     ("mec_fused", 28, (1, 8, 1, 1), True)),
+    ((32, 28, 28, 512, 1, 1, 256, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 30, 30, 256, 3, 3, 256, 2, 2),
+     ("mec_fused", 14, (1, 7, 1, 1), False),
+     ("mec_fused", 14, (2, 11, 1, 1), False)),
+    ((32, 14, 14, 256, 1, 1, 1024, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 28, 28, 512, 1, 1, 1024, 2, 2),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 14, 14, 1024, 1, 1, 256, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 16, 16, 256, 3, 3, 256, 1, 1),
+     ("mec_fused", 14, (1, 4, 1, 1), True),
+     ("mec_fused", 14, (2, 4, 1, 1), True)),
+    ((32, 14, 14, 1024, 1, 1, 512, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 16, 16, 512, 3, 3, 512, 2, 2),
+     ("mec_fused", 7, (4, 8, 1, 1), False),
+     ("mec_fused", 7, (4, 8, 1, 1), False)),
+    ((32, 7, 7, 512, 1, 1, 2048, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 14, 14, 1024, 1, 1, 2048, 2, 2),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 7, 7, 2048, 1, 1, 512, 1, 1),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 9, 9, 512, 3, 3, 512, 1, 1),
+     ("mec_fused", 7, (4, 2, 1, 1), True),
+     ("mec_fused", 7, (4, 4, 1, 1), True)),
+    ((32, 224, 224, 64, 7, 7, 64, 2, 2),
+     ("mec_fused", 109, (1, 32, 4, 1), False),
+     ("mec_fused", 109, (1, 32, 7, 1), False)),
+    ((32, 227, 227, 3, 11, 11, 96, 4, 4),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 231, 231, 3, 11, 11, 96, 4, 4),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 227, 227, 3, 7, 7, 64, 2, 2),
+     ("direct", None, None, False), ("direct", None, None, False)),
+    ((32, 24, 24, 96, 5, 5, 256, 1, 1),
+     ("mec_fused", 20, (1, 11, 1, 1), True),
+     ("mec_fused", 20, (2, 4, 1, 1), True)),
+    ((32, 12, 12, 256, 3, 3, 512, 1, 1),
+     ("mec_fused", 10, (2, 3, 1, 1), True),
+     ("mec_fused", 10, (4, 3, 1, 1), True)),
+    ((32, 224, 224, 3, 3, 3, 64, 1, 1),
+     ("direct", None, None, True), ("direct", None, None, True)),
+    ((32, 112, 112, 64, 3, 3, 128, 1, 1),
+     ("mec_fused", 110, (1, 32, 4, 1), True),
+     ("mec_fused", 110, (1, 32, 4, 1), True)),
+    ((32, 56, 56, 64, 3, 3, 64, 1, 1),
+     ("mec_fused", 54, (1, 32, 1, 1), True),
+     ("mec_fused", 54, (1, 32, 1, 1), True)),
+    ((32, 28, 28, 128, 3, 3, 128, 1, 1),
+     ("mec_fused", 26, (1, 8, 1, 1), True),
+     ("mec_fused", 26, (1, 8, 1, 1), True)),
+    ((32, 14, 14, 256, 3, 3, 256, 1, 1),
+     ("mec_fused", 12, (1, 4, 1, 1), True),
+     ("mec_fused", 12, (2, 3, 1, 1), True)),
+    ((32, 7, 7, 512, 3, 3, 512, 1, 1),
+     ("mec_fused", 5, (4, 2, 1, 1), True),
+     ("mec_fused", 5, (4, 2, 1, 1), True)),
+    ((32, 60, 60, 64, 3, 3, 64, 1, 1),
+     ("mec_fused", 58, (1, 32, 1, 1), True),
+     ("mec_fused", 58, (1, 32, 1, 1), True)),
+    ((32, 32, 32, 128, 3, 3, 128, 1, 1),
+     ("mec_fused", 30, (1, 8, 1, 1), True),
+     ("mec_fused", 30, (1, 8, 1, 1), True)),
+    ((32, 18, 18, 256, 3, 3, 256, 1, 1),
+     ("mec_fused", 16, (1, 4, 1, 1), True),
+     ("mec_fused", 16, (2, 4, 1, 1), True)),
+    ((32, 11, 11, 512, 3, 3, 512, 1, 1),
+     ("mec_fused", 9, (4, 3, 1, 1), True),
+     ("mec_fused", 9, (4, 5, 1, 1), True)),
+    ((32, 28, 28, 256, 5, 5, 96, 1, 1),
+     ("mec_fused", 24, (1, 7, 1, 1), True),
+     ("mec_fused", 24, (1, 16, 1, 1), True)),
+    ((32, 14, 14, 512, 3, 3, 256, 1, 1),
+     ("mec_fused", 12, (2, 3, 1, 1), True),
+     ("mec_fused", 12, (2, 6, 1, 1), True)),
+    ((32, 226, 226, 64, 3, 3, 3, 1, 1),
+     ("mec_fused", 224, (1, 32, 13, 1), False),
+     ("mec_fused", 224, (1, 32, 13, 1), False)),
+    ((32, 114, 114, 128, 3, 3, 64, 1, 1),
+     ("mec_fused", 112, (1, 32, 4, 1), True),
+     ("mec_fused", 112, (1, 32, 4, 1), True)),
+]
+
+
+@pytest.mark.parametrize("spec,dtype,pick", [
+    pytest.param(ConvSpec(*geometry), dtype, pick,
+                 id=f"{spec_key(ConvSpec(*geometry))}-{dtype}")
+    for geometry, *picks in TPU_PICKS
+    for dtype, pick in zip(("bfloat16", "float32"), picks)])
+def test_tpu_plans_mec_fused_for_table2_and_resnet_stages(spec, dtype, pick):
+    """The TPU pick, the planner's block, the kernel's grid and where the
+    input gradient runs, as the kernel's rule (``fused_refusal``) decides
+    them; traced nothing, so each case costs milliseconds."""
+    from repro.core.conv_api import fused_input_grad_refusal
+    from repro.kernels.mec_conv import fused_blocks, fused_refusal
+    from repro.launch.costmodel import pick_conv2d_algorithm
+    from repro.plan.convplan import _pallas_w_blk
+    algorithm, w_blk, grid, grad_on_kernel = pick
+    assert pick_conv2d_algorithm(spec, "tpu", dtype=dtype) == algorithm
+    assert (fused_refusal(spec, dtype) is None) == \
+        (algorithm == "mec_fused")
+    assert _pallas_w_blk(spec, algorithm) == w_blk
+    if algorithm == "mec_fused":
+        s = spec
+        fb = fused_blocks(s.i_n, s.i_h, s.i_w, s.i_c, s.k_h, s.k_w, s.k_c,
+                          s.s_h, s.s_w, w_blk, jnp.dtype(dtype).itemsize)
+        assert fb.grid == grid
+    assert (fused_input_grad_refusal(spec, dtype) is None) == \
+        grad_on_kernel
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_tpu_plans_mec_fused_for_table2_and_resnet_stages(dtype):
-    """Every paper Table-2 layer of more than 3 input channels and every
-    resnet101_t3 stage, at batch 32, stays eligible for the fused kernel
-    on TPU, and explain() prints its blocking; the 3-channel layers go
-    to XLA's conv."""
-    from repro.bench.scenarios import CV_LAYERS, layer_spec
-    from repro.launch.costmodel import (FUSED_MIN_CHANNELS,
-                                        tpu_fused_ineligibility)
-    table2 = [layer_spec(name, batch=32) for name in CV_LAYERS]
-    for spec in table2:
-        if spec.i_c < FUSED_MIN_CHANNELS:
-            assert "input channel" in tpu_fused_ineligibility(spec, dtype)
-            assert plan_conv2d(spec, backend="tpu", dtype=dtype).algorithm \
-                == "direct"
-    specs = [s for s in table2 if s.i_c >= FUSED_MIN_CHANNELS] + [
-        ConvSpec(32, 224, 224, 64, 7, 7, 64, 2, 2),
-        ConvSpec(32, 58, 58, 64, 3, 3, 64),
-        ConvSpec(32, 30, 30, 128, 3, 3, 128),
-        ConvSpec(32, 16, 16, 256, 3, 3, 256),
-        ConvSpec(32, 9, 9, 512, 3, 3, 512)]
-    for spec in specs:
-        assert tpu_fused_ineligibility(spec, dtype) is None, spec
-    plan = plan_conv2d(specs[-1], backend="tpu", dtype=dtype)
+def test_tpu_explain_prints_the_blocking_and_the_input_gradient(dtype):
+    """explain() of a TPU plan prints mec_fused's blocking and where the
+    input gradient runs: on the kernel for cv12, stride 1; on XLA for
+    cv4, stride 2."""
+    plan = plan_conv2d(ConvSpec(32, 9, 9, 512, 3, 3, 512), backend="tpu",
+                       dtype=dtype)
     assert plan.algorithm == "mec_fused"
     assert "mec_fused blocking:" in plan.explain()
     assert "image(s) x 7 row(s)" in plan.explain()
-    # Every stride-1 stage runs its input gradient on the kernel too;
-    # cv4, stride 2, keeps the XLA path, and explain() says which.
-    from repro.core.conv_api import fused_input_grad_refusal
-    for spec in specs[-4:]:
-        assert fused_input_grad_refusal(spec, dtype) is None, spec
     assert ("input gradient: the mec_fused kernel on the transposed conv "
             "32x11x11x512-k3x3x512-s1x1") in plan.explain()
-    cv4 = plan_conv2d(specs[-5], backend="tpu", dtype=dtype).explain()
+    cv4 = plan_conv2d(ConvSpec(32, 224, 224, 64, 7, 7, 64, 2, 2),
+                      backend="tpu", dtype=dtype).explain()
     assert "input gradient: XLA (_mec_input_grad), stride (2, 2)" in cv4
 
 
@@ -447,13 +576,12 @@ def test_tpu_sends_3_channel_inputs_to_direct():
     """The ResNet stem (7x7/2 on 224 px of 3 channels) goes to XLA's conv
     on TPU, with the channel rule as its reason; cv4, the same kernel on
     64 channels, and a 4-channel input keep the fused kernel."""
-    from repro.launch.costmodel import (FUSED_MIN_CHANNELS,
-                                        pick_conv2d_algorithm,
-                                        tpu_fused_ineligibility)
+    from repro.kernels.mec_conv import FUSED_MIN_CHANNELS, fused_refusal
+    from repro.launch.costmodel import pick_conv2d_algorithm
     assert FUSED_MIN_CHANNELS == 4
     stem = ConvSpec(32, 230, 230, 3, 7, 7, 64, 2, 2)
     assert pick_conv2d_algorithm(stem, "tpu", dtype="bfloat16") == "direct"
-    why = tpu_fused_ineligibility(stem, "bfloat16")
+    why = fused_refusal(stem, "bfloat16")
     assert why.startswith("3 input channel(s), under 4")
     assert why in plan_conv2d(stem, backend="tpu",
                               dtype="bfloat16").explain()
@@ -461,7 +589,7 @@ def test_tpu_sends_3_channel_inputs_to_direct():
     assert pick_conv2d_algorithm(cv4, "tpu", dtype="bfloat16") == \
         "mec_fused"
     four = ConvSpec(32, 230, 230, 4, 7, 7, 64, 2, 2)
-    assert tpu_fused_ineligibility(four, "bfloat16") is None
+    assert fused_refusal(four, "bfloat16") is None
 
 
 # -------------------------------------------------------------- partitions
@@ -615,12 +743,13 @@ def test_tune_measured_grids_the_mec_solution(fresh_cache):
 
 def test_tune_measured_grids_pallas_w_blk(fresh_cache):
     from repro.plan import tune_measured
-    # o_w = 30 > default w_blk: the half/default/double grid is real
-    spec = ConvSpec(1, 8, 32, 2, 3, 3, 4, 1, 1)
-    plan, detail = tune_measured(spec, candidates=("mec_lowered",),
+    # o_w = 32: the default block takes the whole row, and its half is
+    # a second, sublane-aligned trial
+    spec = ConvSpec(1, 8, 34, 2, 3, 3, 4, 1, 1)
+    plan, detail = tune_measured(spec, candidates=("mec_fused",),
                                  iters=1, warmup=1, interpret=True,
                                  record=False, calibration=None)
-    assert plan.algorithm == "mec_lowered"
+    assert plan.algorithm == "mec_fused"
     tuning = detail["tuning"]
     assert tuning["knob"] == "w_blk"
     assert len(tuning["trials"]) >= 2
@@ -645,23 +774,17 @@ def test_measured_skips_are_counted_not_dropped(fresh_cache, monkeypatch):
             spec, candidates=("direct", "mec"), record=False)
     assert mc.times == {"direct": 10.0}
     assert mc.skipped["mec"].startswith("RuntimeError")
-    # a Pallas candidate the geometry checker rejects is skipped the
-    # same loud way, and never timed at all
-    from repro.analysis import pallas_check
-
-    class _Reject:
-        ok = False
-
-        def render(self):
-            return "rejected: w_blk tile overruns VMEM"
-
-    monkeypatch.setattr(pallas_check, "check_plan",
-                        lambda plan: _Reject())
-    with pytest.warns(UserWarning, match="pallas_check"):
+    # a kernel candidate the kernel's geometry rule refuses is skipped
+    # the same loud way, and never timed at all
+    from repro.kernels import mec_conv
+    monkeypatch.setattr(mec_conv, "fused_refusal",
+                        lambda spec, dtype, w_blk=None: "overruns VMEM")
+    with pytest.warns(UserWarning,
+                      match="measured planning skips mec_fused"):
         mc = measure_candidates_detailed(
-            spec, candidates=("mec_lowered",), record=False)
+            spec, candidates=("mec_fused",), record=False)
     assert mc.times == {}
-    assert mc.skipped["mec_lowered"].startswith("pallas_check")
+    assert mc.skipped["mec_fused"] == "overruns VMEM"
 
 
 def test_tune_measured_raises_when_nothing_timeable(fresh_cache,

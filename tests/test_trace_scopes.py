@@ -4,7 +4,7 @@ where a profiler trace reads each device op's owner.
 
 Programs are compiled on the CPU, the Pallas kernels in interpret mode:
 a ``conv2d(plan=)`` forward on ``mec_fused``, a training step (``jax.grad``
-through the MEC custom VJP, then AdamW), the other Pallas paths, and a
+through the MEC custom VJP, then AdamW), the conv1d kernel, and a
 tiny ResNet's training step (``repro.models.resnet``).
 """
 import re
@@ -63,15 +63,9 @@ def _programs():
     net_step = resnet.train_step(resnet.plan(net, 2, BF16),
                                  adamw.AdamWConfig())
 
-    xs = jnp.ones((2, 9, 9, 4), BF16)
-    k = jnp.ones((3, 3, 4, 4), BF16)
     return {
         "forward": (forward, (x, ws)),
         "train": (step, (ws, adamw.init(ws), x)),
-        "lowered": (lambda a, b: conv2d(a, b, stride=2, padding=1,
-                                        algorithm="mec_lowered"), (xs, k)),
-        "fused2": (lambda a, b: conv2d(a, b, padding=1,
-                                       algorithm="mec_fused2"), (xs, k)),
         "conv1d": (mec_conv1d_tpu, (jnp.ones((2, 16, 8)),
                                     jnp.ones((3, 8)))),
         "resnet": (net_step, (net_params, net_stats,
@@ -92,9 +86,6 @@ COVERS = {
     "mec_weight_grad": ("train", {"dot_general"}),
     "adamw_update": ("train", {"sqrt"}),
     "mec_fused": ("forward", {"dot_general"}),
-    "mec_fused2": ("fused2", {"dot_general"}),
-    "mec_lower": ("lowered", {"concatenate"}),
-    "mec_gemm": ("lowered", {"dot_general"}),
     "mec_conv1d": ("conv1d", {"mul"}),
     "batch_norm": ("resnet", {"rsqrt"}),
     "pointwise": ("resnet", {"conv_general_dilated"}),
@@ -102,8 +93,7 @@ COVERS = {
 }
 # Scopes that lie inside ``conv2d`` wherever they appear.
 IN_CONV2D = ("conv2d_pad", "mec_fold", "conv2d_out", "mec_input_grad",
-             "mec_weight_grad", "mec_fused", "mec_fused2", "mec_lower",
-             "mec_gemm")
+             "mec_weight_grad", "mec_fused")
 
 
 def _unwrap(component):
