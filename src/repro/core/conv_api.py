@@ -35,8 +35,15 @@ lowering is trainable end-to-end:
   own rule (:func:`fused_input_grad_refusal`); every other conv, and
   a transposed geometry the rule refuses, runs the pure-JAX reference
   in f32 (``_mec_input_grad``);
-* weight gradient reuses ``mec_lower``'s compact L — one small einsum per
-  kernel row over shifted views of L, never an im2col-sized buffer.
+* weight gradient = the forward's windows against the cotangent, summed
+  over the pixels.  A conv that ran forward on ``mec_fused`` runs it on
+  ``mec_fused_wgrad``, which walks the forward's own blocking and builds
+  each tap's window in VMEM, so L never exists in HBM, where its working
+  set fits and a strided conv folds to enough column taps
+  (``kernels.mec_conv.fused_weight_grad_refusal``); every other
+  conv, and a geometry the rule refuses, materialises ``mec_lower``'s
+  compact L and runs one f32 einsum per kernel row over shifted views of
+  it (``_mec_weight_grad``), never an im2col-sized buffer.
 """
 from __future__ import annotations
 
@@ -73,8 +80,8 @@ Padding = Union[str, int, Tuple]
 # the head (pool, classifier and loss).
 TRACE_SCOPES = ("conv2d", "conv2d_pad", "mec_fold", "conv2d_out",
                 "mec_input_grad", "mec_weight_grad", "adamw_update",
-                "mec_fused", "mec_conv1d", "batch_norm", "pointwise",
-                "head")
+                "mec_fused", "mec_fused_wgrad", "mec_conv1d", "batch_norm",
+                "pointwise", "head")
 
 
 def apply_padding(inp: jnp.ndarray, k_h: int, k_w: int, s_h: int, s_w: int,
@@ -217,22 +224,30 @@ def _mec_fused_input_grad(g: jnp.ndarray, kernel: jnp.ndarray, interpret,
                           precision=precision, w_blk=w_blk)
 
 
-def _mec_bwd(s_h, s_w, variant, _solution, interpret, precision, _w_blk,
+def _mec_bwd(s_h, s_w, variant, _solution, interpret, precision, w_blk,
              res, g):
-    # The nondiff args arrive positionally.  solution and w_blk shape the
-    # forward lowering only; the variant picks where the input gradient
-    # runs, the same mathematics either way.
+    # The nondiff args arrive positionally.  solution shapes the forward
+    # lowering only; the variant and the forward's w_blk pick where each
+    # gradient runs, the same mathematics either way.
     inp, kernel = res
+    spec = spec_of(inp, kernel, (s_h, s_w))
+    fused = variant == "mec_fused"
     with jax.named_scope("mec_input_grad"):
-        if variant == "mec_fused" and fused_input_grad_refusal(
-                spec_of(inp, kernel, (s_h, s_w)), g.dtype) is None:
+        if fused and fused_input_grad_refusal(spec, g.dtype) is None:
             d_inp = _mec_fused_input_grad(g, kernel, interpret, precision)
         else:
             d_inp = _mec_input_grad(g, kernel, s_h, s_w, inp.shape[1],
                                     inp.shape[2], precision)
     with jax.named_scope("mec_weight_grad"):
-        d_ker = _mec_weight_grad(inp, g, s_h, s_w, kernel.shape[0],
-                                 kernel.shape[1], precision)
+        from repro.kernels.mec_conv import fused_weight_grad_refusal
+        if fused and fused_weight_grad_refusal(spec, g.dtype, w_blk) is None:
+            from repro.kernels.ops import mec_weight_grad_tpu
+            d_ker = mec_weight_grad_tpu(inp, g, kernel.shape[0],
+                                        kernel.shape[1], (s_h, s_w),
+                                        interpret, precision, w_blk)
+        else:
+            d_ker = _mec_weight_grad(inp, g, s_h, s_w, kernel.shape[0],
+                                     kernel.shape[1], precision)
     return d_inp.astype(inp.dtype), d_ker.astype(kernel.dtype)
 
 

@@ -14,8 +14,12 @@ traffic is I (1 + halo/rows a step) + K + O.  The paper's lowering with
 L in HBM is the pure-JAX reference, ``repro.core.mec``.
 
 The blocking is :func:`fused_blocks`, and :func:`fused_refusal` is the
-one rule for the geometries the kernel does not take.  Every tap
-accumulates in f32 regardless of input dtype.
+one rule for the geometries the kernel does not take.  The weight
+gradient of such a conv runs on ``mec_fused_wgrad``, which walks the
+same blocks and folds and builds the same windows in VMEM, summing each
+tap's window against the cotangent; :func:`fused_weight_grad_refusal`
+says where its working set does not fit.  Every tap accumulates in f32
+regardless of input dtype.
 """
 from __future__ import annotations
 
@@ -93,6 +97,21 @@ class FusedBlocks:
                 -(-self.o_h // self.hb), -(-self.o_w // self.w_blk))
 
     @property
+    def out_extent(self) -> Tuple[int, int, int]:
+        """(images, rows, columns) the grid's output blocks cover."""
+        _, n_b, n_h, n_w = self.grid
+        return n_b * self.nb, n_h * self.hb, n_w * self.w_blk
+
+    @property
+    def window_extent(self) -> Tuple[int, int, int]:
+        """(images, folded rows, folded columns) the grid's input windows
+        reach: past the folded input wherever the last blocks run past
+        the output."""
+        n, rows, _ = self.out_extent
+        return (n, rows + (self.k_h - 1) // self.s_h,
+                (self.grid[3] - 1) * self.w_blk + self.cols_in)
+
+    @property
     def steps(self) -> int:
         return math.prod(self.grid)
 
@@ -141,6 +160,27 @@ class FusedBlocks:
         b = self.block_bytes()
         return (2 * (b["input"] + b["kernel"] + b["output"]) + b["acc"]
                 + b["window"] + b["dot"])
+
+    @property
+    def weight_grad_vmem_bytes(self) -> int:
+        """Per-step working set of the weight-gradient kernel on these
+        blocks: the input and cotangent (the output's block) streams
+        double-buffered, the f32 dW block (resident across the reduction
+        axes; Mosaic gives a block whose index never changes one buffer,
+        else two), and a chunk's cotangent, its transpose, a tap's window
+        and their f32 product."""
+        b = self.block_bytes()
+        tile = _sublane_tile(self.itemsize)
+        c2 = _round_up(self.c2, _LANES)
+        kc = _round_up(self.kc, _LANES)
+        dw = self.k_h * self.k_q * _round_up(self.kc, 8) * c2 * 4
+        cot = self.ib * self.hc * _round_up(self.w_blk, tile) * kc
+        lhs = _round_up(self.kc, tile) * _round_up(self.dot_rows, _LANES)
+        window = _round_up(self.dot_rows, tile) * c2
+        return (2 * (b["input"] + b["output"])
+                + (2 if self.grid[0] > 1 else 1) * dw
+                + (cot + lhs + window) * self.itemsize
+                + _round_up(self.kc, 8) * c2 * 4)
 
     def describe(self) -> str:
         return (f"{self.nb} image(s) x {self.hb} row(s) x {self.w_blk} "
@@ -264,6 +304,17 @@ def fused_blocks(i_n: int, i_h: int, i_w: int, i_c: int, k_h: int,
 FUSED_MIN_CHANNELS = 4
 
 
+# A strided conv whose width fold leaves fewer column taps than this
+# (ResNet's 3x3/2 convs: 2 taps over 2 x i_c channels, a quarter of them
+# zero) ran its weight gradient slower on ``mec_fused_wgrad`` than on
+# XLA's ``_mec_weight_grad``.  On a TPU v5e at batch 32 in bfloat16 (host
+# clock, dispatch included), 3x3/2 at 56, 28 and 14 px read 0.255, 0.235
+# and 0.280 ms on the kernel against 0.207, 0.197 and 0.211 on XLA, while
+# cv4 (7x7/2, 4 taps) read 4.73 ms against 11.35.  The rule stops at the
+# geometries timed; stride-1 convs keep the kernel.
+WGRAD_MIN_STRIDED_TAPS = 3
+
+
 def fused_refusal(spec, dtype, w_blk: int | None = None) -> str | None:
     """Why ``mec_fused`` does not take the conv ``spec`` in ``dtype``, or
     None where it does.
@@ -311,6 +362,38 @@ def fused_refusal(spec, dtype, w_blk: int | None = None) -> str | None:
     return None
 
 
+def fused_weight_grad_refusal(spec, dtype,
+                              w_blk: int | None = None) -> str | None:
+    """Why the weight gradient of a conv that ran forward on ``mec_fused``
+    at column block ``w_blk`` (None: the planner's
+    :func:`~repro.kernels.ops.pick_w_blk`) does not run on
+    ``mec_fused_wgrad``, or None where it does.  The kernel walks the
+    forward's own :class:`FusedBlocks`, so the forward must run them; its
+    step then also holds the f32 dW block of the step's output channels,
+    and the working set (:attr:`FusedBlocks.weight_grad_vmem_bytes`) must
+    fit :func:`~repro.kernels.ops.vmem_bytes`.  A strided conv folded to
+    fewer than ``WGRAD_MIN_STRIDED_TAPS`` column taps keeps XLA."""
+    from repro.kernels import ops
+    if w_blk is None:
+        w_blk = ops.pick_w_blk(spec.o_w, spec.k_c, _warn_env=False)
+    why = fused_refusal(spec, dtype, w_blk)
+    if why is not None:
+        return f"the forward's blocking is refused: {why}"
+    vmem = ops.vmem_bytes()
+    fb = fused_blocks(spec.i_n, spec.i_h, spec.i_w, spec.i_c, spec.k_h,
+                      spec.k_w, spec.k_c, spec.s_h, spec.s_w, w_blk,
+                      np.dtype(dtype).itemsize, vmem_budget=vmem)
+    if max(spec.s_h, spec.s_w) > 1 and fb.k_q < WGRAD_MIN_STRIDED_TAPS:
+        return (f"stride {spec.s_h}x{spec.s_w} folds {spec.k_w} kernel "
+                f"columns into {fb.k_q} taps: XLA's weight gradient wins "
+                f"under {WGRAD_MIN_STRIDED_TAPS}")
+    if fb.weight_grad_vmem_bytes > vmem:
+        return (f"a step's working set with the f32 dW block "
+                f"{fb.k_h}x{fb.k_q}x{fb.c2}x{fb.kc}, "
+                f"{fb.weight_grad_vmem_bytes} B, exceeds VMEM {vmem} B")
+    return None
+
+
 def _fused_kernel(x_ref, k_ref, o_ref, acc_ref, *, fb: FusedBlocks,
                   precision):
     # x_ref: (nb, rows_in, s_h, cols_in, c2) — the step's images, folded
@@ -345,6 +428,87 @@ def _fused_kernel(x_ref, k_ref, o_ref, acc_ref, *, fb: FusedBlocks,
         return carry
 
     lax.fori_loop(0, fb.chunks, chunk, 0)
+
+
+def _weight_grad_kernel(x_ref, g_ref, o_ref, *, fb: FusedBlocks,
+                        precision):
+    # x_ref: the forward's input block; g_ref: (nb, hb, w_blk, kc), the
+    #        cotangent of the forward's output block
+    # o_ref: (k_h, k_q, kc, c2) f32 — dW of the step's output channels,
+    #        transposed, resident across the image, row and column axes
+    ib, hc, wb, kc = fb.ib, fb.hc, fb.w_blk, fb.kc
+    per_image = fb.hb // hc
+
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0)
+             & (pl.program_id(3) == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def chunk(j, carry):
+        i0, h0 = (j // per_image) * ib, (j % per_image) * hc
+        # The chunk's cotangent, transposed once for all its taps.
+        g_t = g_ref[pl.ds(i0, ib), pl.ds(h0, hc)].reshape(fb.dot_rows, kc).T
+
+        def kernel_row(r, c):
+            t, p = r // fb.s_h, r % fb.s_h
+            for q in range(fb.k_q):
+                win = x_ref[pl.ds(i0, ib), pl.ds(h0 + t, hc), p,
+                            q:q + wb, :]
+                o_ref[r, q] += jnp.dot(
+                    g_t, win.reshape(fb.dot_rows, fb.c2),
+                    precision=precision, preferred_element_type=jnp.float32)
+            return c
+
+        lax.fori_loop(0, fb.k_h, kernel_row, 0)
+        return carry
+
+    lax.fori_loop(0, fb.chunks, chunk, 0)
+
+
+def _fold_input(inp: jnp.ndarray, fb: FusedBlocks,
+                extent: Tuple[int, int, int]) -> jnp.ndarray:
+    """The input as the kernels read it, ``(images, rows, s_h, cols,
+    c2)`` for ``extent`` = (images, folded rows, folded columns): a unit
+    width squeezed, the width stride folded into channels and the height
+    stride into a phase axis.  Rows and columns past the extent feed no
+    window and are cut; missing ones, and missing images, are zeros."""
+    i_n, i_h, _, i_c = inp.shape
+    if fb.squeeze:
+        inp = inp.reshape(i_n, 1, i_h, i_c)
+    n, rows, cols = extent
+    need_h, need_w = rows * fb.s_h, cols * (fb.c2 // i_c)
+    inp = inp[:, :need_h, :need_w, :]
+    if n > i_n or need_h > inp.shape[1] or need_w > inp.shape[2]:
+        inp = jnp.pad(inp, ((0, n - i_n), (0, need_h - inp.shape[1]),
+                            (0, need_w - inp.shape[2]), (0, 0)))
+    # The width fold first, materialized on its own: XLA then lays it out
+    # as the plain (rows, cols, channels) fold does, and the split into
+    # phases is free.  Folded in one reshape, it went through a
+    # lane-padded copy of the whole input.
+    x4 = lax.optimization_barrier(inp.reshape(n, need_h, cols, fb.c2))
+    return x4.reshape(n, rows, fb.s_h, cols, fb.c2)
+
+
+def _input_spec(fb: FusedBlocks, shape) -> pl.BlockSpec:
+    """The step's input window: windows overlap by the halo, so they are
+    placed by element; where the folded input ``shape`` ends before the
+    grid's windows do, the last ones run past it."""
+    n, rows, cols = fb.window_extent
+
+    def el(size, over=0):
+        return pl.Element(size, (0, over))
+
+    return pl.BlockSpec(
+        (el(fb.nb, n - shape[0]), el(fb.rows_in, rows - shape[1]),
+         el(fb.s_h), el(fb.cols_in, cols - shape[3]), el(fb.c2)),
+        lambda c, b, h, w: (b * fb.nb, h * fb.hb, 0, w * fb.w_blk, 0))
+
+
+def _out_spec(fb: FusedBlocks) -> pl.BlockSpec:
+    """The step's output block, and in the weight gradient the block of
+    the cotangent it reads."""
+    return pl.BlockSpec((fb.nb, fb.hb, fb.w_blk, fb.kc),
+                        lambda c, b, h, w: (b, h, w, c))
 
 
 @functools.partial(jax.jit,
@@ -383,55 +547,30 @@ def mec_conv_fused_pallas(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
     fb = fused_blocks(i_n, i_h, i_w, i_c, k_h, k_w, k_c, s_h, s_w,
                       min(w_blk, o_w), inp.dtype.itemsize)
     with jax.named_scope("mec_fold"):
-        if fb.squeeze:
-            inp = inp.reshape(i_n, 1, i_h, i_c)
-            kernel = kernel.reshape(1, k_h, i_c, k_c)
-            i_h, i_w, k_h, k_w, s_h, s_w = 1, i_h, 1, k_h, 1, s_h
         # Rows and columns past the last window feed no output; missing
         # ones (fewer than a stride) only meet zero taps.
-        need_h, need_w = fb.i_h2 * s_h, fb.i_w2 * s_w
-        inp = inp[:, :need_h, :need_w, :]
-        if need_h > inp.shape[1] or need_w > inp.shape[2]:
-            inp = jnp.pad(inp, ((0, 0), (0, need_h - inp.shape[1]),
-                                (0, need_w - inp.shape[2]), (0, 0)))
-        # The width fold first, materialized on its own: XLA then lays
-        # it out as the plain (rows, cols, channels) fold does, and the
-        # split into phases is free.  Folded in one reshape, it went
-        # through a lane-padded copy of the whole input.
-        x4 = lax.optimization_barrier(
-            inp.reshape(i_n, need_h, fb.i_w2, s_w * i_c))
-        x5 = x4.reshape(i_n, fb.i_h2, s_h, fb.i_w2, s_w * i_c)
+        x5 = _fold_input(inp, fb, (i_n, fb.i_h2, fb.i_w2))
+        if fb.squeeze:
+            kernel = kernel.reshape(1, k_h, i_c, k_c)
+            k_h, k_w, s_w = 1, k_h, s_h
         k2 = jnp.pad(kernel, ((0, 0), (0, fb.k_q * s_w - k_w), (0, 0),
                               (0, 0)))
-        k2 = k2.reshape(k_h, fb.k_q, s_w * i_c, k_c)
-    nb, hb, wb, kc = fb.nb, fb.hb, fb.w_blk, fb.kc
-    _, n_b, n_h, n_w = fb.grid
-
-    def el(size, over=0):       # a window that may run past the array
-        return pl.Element(size, (0, over))
-
-    # Input windows overlap by the halo, so they are placed by element;
-    # the last ones run past the array, into rows, images and columns
-    # whose outputs are cropped or never written.
-    x_spec = pl.BlockSpec(
-        (el(nb, n_b * nb - i_n), el(fb.rows_in, n_h * hb - fb.o_h),
-         el(s_h), el(fb.cols_in, (n_w - 1) * wb + fb.cols_in - fb.i_w2),
-         el(fb.c2)),
-        lambda c, b, h, w: (b * nb, h * hb, 0, w * wb, 0))
+        k2 = k2.reshape(k_h, fb.k_q, fb.c2, k_c)
+    # The last input windows run past the array, into rows, images and
+    # columns whose outputs are cropped or never written.
     out = pl.pallas_call(
         functools.partial(_fused_kernel, fb=fb, precision=precision),
         name="mec_fused",
         grid=fb.grid,
         in_specs=[
-            x_spec,
-            pl.BlockSpec((k_h, fb.k_q, fb.c2, kc),
+            _input_spec(fb, x5.shape),
+            pl.BlockSpec((k_h, fb.k_q, fb.c2, fb.kc),
                          lambda c, b, h, w: (0, 0, 0, c)),
         ],
-        out_specs=pl.BlockSpec((nb, hb, wb, kc),
-                               lambda c, b, h, w: (b, h, w, c)),
-        out_shape=jax.ShapeDtypeStruct((i_n, fb.o_h, n_w * wb, k_c),
+        out_specs=_out_spec(fb),
+        out_shape=jax.ShapeDtypeStruct((i_n, fb.o_h, fb.out_extent[2], k_c),
                                        inp.dtype),
-        scratch_shapes=[pltpu.VMEM((fb.dot_rows, kc), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((fb.dot_rows, fb.kc), jnp.float32)],
         interpret=interpret,
     )(x5, k2)
     with jax.named_scope("conv2d_out"):
@@ -439,3 +578,57 @@ def mec_conv_fused_pallas(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
         if fb.squeeze:
             out = out.reshape(i_n, fb.o_w, 1, k_c)
         return out
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("k_h", "k_w", "stride", "w_blk",
+                                    "interpret", "precision"))
+def mec_weight_grad_pallas(inp: jnp.ndarray, g: jnp.ndarray, k_h: int,
+                           k_w: int, stride=1, w_blk: int = 128,
+                           interpret: bool = True,
+                           precision=None) -> jnp.ndarray:
+    """dL/dK of the conv :func:`mec_conv_fused_pallas` runs at ``w_blk``,
+    from its pre-padded input ``inp`` (n, i_h, i_w, i_c) and the
+    cotangent ``g`` (n, o_h, o_w, k_c) of its output, in the operands'
+    dtype.  Returns (k_h, k_w, i_c, k_c) in f32.
+
+    The kernel, ``mec_fused_wgrad``, walks the forward's blocks and
+    folds (:func:`fused_blocks`): each step reads the forward's input
+    window and the cotangent of the forward's output block, and each
+    tap ``(r, q)`` of a chunk is one GEMM of the chunk's cotangent,
+    transposed, against the tap's window, summed in an f32 block of the
+    step's output channels that stays in VMEM across the image, row and
+    column axes.  L never exists in HBM.  The input is
+    zero-padded to the windows' extent and the cotangent to the output
+    blocks', so pixels past the conv's output add nothing.
+    """
+    s_h, s_w = (stride, stride) if isinstance(stride, int) else stride
+    i_n, i_h, i_w, i_c = inp.shape
+    k_c = g.shape[3]
+    o_w = (i_w - k_w) // s_w + 1
+    fb = fused_blocks(i_n, i_h, i_w, i_c, k_h, k_w, k_c, s_h, s_w,
+                      min(w_blk, o_w), inp.dtype.itemsize)
+    n, rows, cols = fb.out_extent
+    with jax.named_scope("mec_fold"):
+        x5 = _fold_input(inp, fb, fb.window_extent)
+        g = g.reshape(i_n, fb.o_h, fb.o_w, k_c)     # a squeezed unit width
+        g = jnp.pad(g, ((0, n - i_n), (0, rows - fb.o_h),
+                        (0, cols - fb.o_w), (0, 0)))
+    dw = pl.pallas_call(
+        functools.partial(_weight_grad_kernel, fb=fb, precision=precision),
+        name="mec_fused_wgrad",
+        grid=fb.grid,
+        in_specs=[_input_spec(fb, x5.shape), _out_spec(fb)],
+        out_specs=pl.BlockSpec((fb.k_h, fb.k_q, fb.kc, fb.c2),
+                               lambda c, b, h, w: (0, 0, c, 0)),
+        out_shape=jax.ShapeDtypeStruct((fb.k_h, fb.k_q, k_c, fb.c2),
+                                       jnp.float32),
+        interpret=interpret,
+    )(x5, g)
+    with jax.named_scope("mec_fold"):
+        # (k_h, k_q, k_c, s_w' i_c) back to HWIO; the width fold's taps
+        # past k_w belong to no weight and are cut.
+        s_w = fb.c2 // i_c
+        dw = dw.transpose(0, 1, 3, 2).reshape(fb.k_h, fb.k_q * s_w, i_c, k_c)
+        return dw[:, :(k_h if fb.squeeze else k_w)].reshape(k_h, k_w, i_c,
+                                                            k_c)

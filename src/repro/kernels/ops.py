@@ -14,7 +14,8 @@ import warnings
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.mec_conv import mec_conv_fused_pallas
+from repro.kernels.mec_conv import (mec_conv_fused_pallas,
+                                    mec_weight_grad_pallas)
 from repro.kernels.mec_conv1d import mec_conv1d_pallas
 
 # Accumulator budget override for non-v5e targets (bytes; decimal or hex).
@@ -146,6 +147,20 @@ def mec_conv2d_tpu(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
         raise ValueError(f"w_blk must be in [1, o_w={o_w}], got {w_blk}")
     return mec_conv_fused_pallas(inp, kernel, (s_h, s_w), w_blk=w_blk,
                                  interpret=interpret, precision=precision)
+
+
+def mec_weight_grad_tpu(inp: jnp.ndarray, g: jnp.ndarray, k_h: int,
+                        k_w: int, stride=1, interpret=None, precision=None,
+                        w_blk: int | None = None) -> jnp.ndarray:
+    """dL/dK (f32, HWIO) of the :func:`mec_conv2d_tpu` conv of ``inp``
+    that ran at ``w_blk`` (None: :func:`pick_w_blk`, as the forward
+    picks it), from the cotangent ``g`` of its output, on the forward's
+    blocking (``repro.kernels.mec_conv.mec_weight_grad_pallas``)."""
+    interpret = resolve_interpret(interpret)
+    if w_blk is None:
+        w_blk = pick_w_blk(g.shape[2], g.shape[3], _warn_env=False)
+    return mec_weight_grad_pallas(inp, g, k_h, k_w, stride, w_blk=w_blk,
+                                  interpret=interpret, precision=precision)
 
 
 def mec_conv1d_tpu(x: jnp.ndarray, kernel: jnp.ndarray,

@@ -239,7 +239,8 @@ class ConvPlan:
             import numpy as np
             from repro.core.conv_api import (fused_input_grad_refusal,
                                              input_grad_spec)
-            from repro.kernels.mec_conv import fused_blocks
+            from repro.kernels.mec_conv import (fused_blocks,
+                                                fused_weight_grad_refusal)
             from repro.kernels.ops import pick_w_blk
             lines.append("  (Pallas kernel: lowering stays in VMEM; "
                          "HBM overhead is the direct conv's)")
@@ -254,6 +255,11 @@ class ConvPlan:
                 f"transposed conv {spec_key(input_grad_spec(s))}"
                 if why is None else
                 f"  input gradient: XLA (_mec_input_grad), {why}")
+            why = fused_weight_grad_refusal(s, self.dtype, w_blk)
+            lines.append(
+                "  weight gradient: the mec_fused_wgrad kernel on the "
+                "forward's blocking" if why is None else
+                f"  weight gradient: XLA (_mec_weight_grad), {why}")
         elif self.backend == "tpu":
             from repro.kernels.mec_conv import fused_refusal
             why = fused_refusal(s, self.dtype)

@@ -97,18 +97,42 @@ def test_auto_dispatch_consults_costmodel():
     assert pick_conv2d_algorithm(s3) in ALGORITHMS
 
 
-# (algorithm, stride, input channels, output channels, dtype): 3 -> 4
-# channels in f32 on every MEC path and stride, then stride-1 convs whose
-# channel counts differ, the cases whose input gradient mec_fused runs on
-# its own kernel with the kernel's channel axes swapped.
-GRAD_CASES = [pytest.param(alg, stride, 3, 4, "float32",
+# (algorithm, stride, input channels, output channels, dtype, blocking):
+# 3 -> 4 channels in f32 on every MEC path and stride, then stride-1 convs
+# whose channel counts differ, the cases whose input gradient mec_fused
+# runs on its own kernel with the kernel's channel axes swapped, then the
+# weight gradient's kernel at each edge of the forward's blocking.
+GRAD_CASES = [pytest.param(alg, stride, 3, 4, "float32", None,
                            id=f"{alg}-{stride}")
               for stride in (1, 2)
               for alg in ("mec", "mec_fused")] + [
-    pytest.param(alg, 1, i_c, k_c, dtype,
+    pytest.param(alg, 1, i_c, k_c, dtype, None,
                  id=f"{alg}-1-{i_c}to{k_c}-{dtype}")
     for alg in ("mec", "mec_fused")
     for i_c, k_c in ((8, 16), (16, 8))
+    for dtype in ("float32", "bfloat16")]
+# (name, stride, i_c, k_c, (images, rows, columns), kernel size, w_blk,
+#  accumulator budget in bytes, what mec_fused's blocking must show) —
+#  a budget None is the device's, w_blk None the planner's.
+WGRAD_BLOCKING = [
+    # stride 2 with 3 column taps (a 3x3/2 folds to 2: XLA keeps it)
+    ("s2", 2, 8, 16, (2, 9, 10), 5, None, None, "plane"),
+    # cv4's form: 7x7, stride 2, both strides folded
+    ("7x7_s2", 2, 8, 16, (2, 29, 31), 7, None, None, "plane"),
+    # o_w = 40 in 16-column blocks, the last one past the row
+    ("columns", 1, 4, 8, (2, 9, 40), 3, 16, None, "columns"),
+    # 5 images in blocks of 2 or 3, the last one past the batch
+    ("ragged_images", 1, 4, 8, (5, 6, 8), 3, None, 102400,
+     "ragged_images"),
+    # o_h = 21 in 4-row blocks, the last one past the rows
+    ("rows", 1, 4, 8, (2, 21, 16), 3, None, 32768, "ragged_rows"),
+    # cv12's channels: the kernel's 512 output channels in blocks
+    ("channels", 1, 256, 512, (2, 6, 8), 3, None, None, "channels"),
+]
+GRAD_CASES += [
+    pytest.param("mec_fused", stride, i_c, k_c, dtype, blocking,
+                 id=f"mec_fused-wgrad-{name}-{dtype}")
+    for name, stride, i_c, k_c, *blocking in WGRAD_BLOCKING
     for dtype in ("float32", "bfloat16")]
 # bf16: each gradient is rounded once to bf16 (2**-8 relative) from a
 # cotangent that is itself bf16; 5 ulps of relative L2 against the f32
@@ -116,15 +140,46 @@ GRAD_CASES = [pytest.param(alg, stride, 3, 4, "float32",
 BF16_GRAD_REL_L2 = 5 * 2.0 ** -8
 
 
-@pytest.mark.parametrize("algorithm,stride,i_c,k_c,dtype", GRAD_CASES)
-def test_mec_grad_matches_direct(algorithm, stride, i_c, k_c, dtype):
-    inp = _rand((2, 9, 10, i_c), 13, dtype)
-    ker = _rand((3, 3, i_c, k_c), 14, dtype)
+@pytest.mark.parametrize("algorithm,stride,i_c,k_c,dtype,blocking",
+                         GRAD_CASES)
+def test_mec_grad_matches_direct(algorithm, stride, i_c, k_c, dtype,
+                                 blocking, monkeypatch):
+    shape, k, plan = (2, 9, 10), 3, None
+    if blocking:
+        from repro.kernels.mec_conv import (fused_blocks,
+                                            fused_weight_grad_refusal)
+        from repro.plan import ConvPlan
+        from repro.kernels import ops
+        shape, k, w_blk, acc, shows = blocking
+        if acc is not None:     # rows, then images, fill the accumulator
+            monkeypatch.setattr(ops, "accumulator_budget", lambda **_: acc)
+        spec = conv2d_spec(jax.ShapeDtypeStruct(shape + (i_c,), dtype),
+                           jax.ShapeDtypeStruct((k, k, i_c, k_c), dtype),
+                           stride=stride, padding="SAME")
+        if w_blk is not None:
+            plan = ConvPlan(spec, dtype, "mec_fused", w_blk=w_blk)
+        fb = fused_blocks(spec.i_n, spec.i_h, spec.i_w, i_c, k, k, k_c,
+                          stride, stride, w_blk or spec.o_w,
+                          jnp.dtype(dtype).itemsize)
+        _, n_b, n_h, n_w = fb.grid
+        assert {
+            "plane": fb.hb == spec.o_h,
+            "columns": n_w > 1 and spec.o_w % fb.w_blk != 0,
+            "ragged_images": n_b > 1 and spec.i_n % fb.nb != 0,
+            "ragged_rows": n_h > 1 and spec.o_h % fb.hb != 0,
+            "channels": fb.kc < k_c,
+        }[shows], fb.describe()
+        assert fused_weight_grad_refusal(spec, dtype, w_blk) is None
+    inp = _rand(shape + (i_c,), 13, dtype)
+    ker = _rand((k, k, i_c, k_c), 14, dtype)
+    if blocking:     # outputs of unit scale whatever the kernel's size
+        ker = (ker.astype(jnp.float32) / np.sqrt(k * k * i_c)).astype(dtype)
 
     def loss(alg):
+        kw = dict(plan=plan) if alg != "direct" and plan else \
+            dict(algorithm=alg)
         return lambda i, k: jnp.sum(jnp.sin(conv2d(
-            i, k, stride=stride, padding="SAME",
-            algorithm=alg).astype(jnp.float32)))
+            i, k, stride=stride, padding="SAME", **kw).astype(jnp.float32)))
 
     gi, gk = jax.grad(loss(algorithm), argnums=(0, 1))(inp, ker)
     ri, rk = jax.grad(loss("direct"), argnums=(0, 1))(
@@ -139,16 +194,15 @@ def test_mec_grad_matches_direct(algorithm, stride, i_c, k_c, dtype):
             assert rel < BF16_GRAD_REL_L2, rel
 
 
-def _kernels_in_input_grad(fn, *args):
-    """The ``pallas_call``s named ``mec_fused`` that ``fn``'s jaxpr runs
-    inside the ``mec_input_grad`` scope."""
+def _kernels_in(scope, kernel, fn, *args):
+    """The ``pallas_call``s named ``kernel`` that ``fn``'s jaxpr runs
+    inside the named scope ``scope``."""
     def walk(jaxpr, stack):
         n = 0
         for eqn in jaxpr.eqns:
             path = f"{stack}/{eqn.source_info.name_stack}"
             if eqn.primitive.name == "pallas_call":
-                n += eqn.params["name"] == "mec_fused" and \
-                    "mec_input_grad" in path
+                n += eqn.params["name"] == kernel and scope in path
             for v in eqn.params.values():
                 inner = getattr(v, "jaxpr", None)
                 inner = getattr(inner, "jaxpr", inner)
@@ -156,6 +210,13 @@ def _kernels_in_input_grad(fn, *args):
                     n += walk(inner, path)
         return n
     return walk(jax.make_jaxpr(fn)(*args).jaxpr, "")
+
+
+def _grad_of(algorithm, stride):
+    def loss(i, k):
+        return jnp.sum(conv2d(i, k, stride=stride, padding="SAME",
+                              algorithm=algorithm).astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1))
 
 
 @pytest.mark.parametrize("algorithm,stride,kernels", [
@@ -167,13 +228,28 @@ def test_input_grad_runs_on_mec_fused_for_stride_1(algorithm, stride,
     the pure-JAX ``mec`` keep the XLA path."""
     inp = _rand((2, 9, 10, 8), 15, jnp.bfloat16)
     ker = _rand((3, 3, 8, 16), 16, jnp.bfloat16)
+    assert _kernels_in("mec_input_grad", "mec_fused",
+                       _grad_of(algorithm, stride), inp, ker) == kernels
 
-    def loss(i, k):
-        return jnp.sum(conv2d(i, k, stride=stride, padding="SAME",
-                              algorithm=algorithm).astype(jnp.float32))
 
-    grad = jax.grad(loss, argnums=(0, 1))
-    assert _kernels_in_input_grad(grad, inp, ker) == kernels
+@pytest.mark.parametrize("algorithm,stride,shape,kernels", [
+    ("mec_fused", 1, (2, 9, 10, 8, 16, 3), 1),
+    ("mec_fused", 2, (2, 9, 10, 8, 16, 5), 1),
+    ("mec", 1, (2, 9, 10, 8, 16, 3), 0),
+    # the f32 dW block of 768 input channels overruns VMEM
+    ("mec_fused", 1, (32, 7, 7, 768, 768, 3), 0),
+    # a 3x3/2 folds to 2 column taps, under WGRAD_MIN_STRIDED_TAPS
+    ("mec_fused", 2, (2, 9, 10, 8, 16, 3), 0)])
+def test_weight_grad_runs_on_mec_fused(algorithm, stride, shape, kernels):
+    """The weight gradient of a ``mec_fused`` conv, either stride, is one
+    ``mec_fused_wgrad`` kernel inside ``mec_weight_grad``; the pure-JAX
+    ``mec`` and a geometry ``fused_weight_grad_refusal`` refuses keep
+    the XLA path (``_mec_weight_grad``).  Traced, not run."""
+    n, h, w, i_c, k_c, k = shape
+    inp = jax.ShapeDtypeStruct((n, h, w, i_c), jnp.bfloat16)
+    ker = jax.ShapeDtypeStruct((k, k, i_c, k_c), jnp.bfloat16)
+    assert _kernels_in("mec_weight_grad", "mec_fused_wgrad",
+                       _grad_of(algorithm, stride), inp, ker) == kernels
 
 
 @pytest.mark.parametrize("algorithm", list(MEC_ALGORITHMS))

@@ -132,6 +132,67 @@ def test_mec_fused_blocking_matches_direct(case, dtype):
                                np.asarray(oracle), rtol=tol, atol=tol)
 
 
+def _pallas_call(jaxpr, name):
+    """The ``pallas_call`` eqn named ``name`` in ``jaxpr``, nested or not."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and \
+                eqn.params["name"] == name:
+            return eqn
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            inner = getattr(inner, "jaxpr", inner)
+            if inner is not None and hasattr(inner, "eqns"):
+                found = _pallas_call(inner, name)
+                if found is not None:
+                    return found
+    return None
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("case", FUSED_BLOCKING, ids=[c[0] for c in
+                                                      FUSED_BLOCKING])
+def test_weight_grad_reads_only_padded_data(case, dtype):
+    """The weight gradient's kernel runs the forward's grid
+    (``fused_blocks``), and every input window and cotangent block it
+    reads, at every grid step, lies inside the arrays the wrapper padded
+    to the windows' extent: no read runs past an array into memory that
+    may hold NaN, so pixels past the conv's output add exactly zero."""
+    import jax
+    from jax.experimental import pallas as pl
+    from repro.kernels.mec_conv import fused_blocks, mec_weight_grad_pallas
+    from repro.kernels.ops import pick_w_blk
+    _, (n, ih, iw, ic), (kh, kw, kc), s, w_blk, _ = case
+    s_h, s_w = (s, s) if isinstance(s, int) else s
+    o_h, o_w = (ih - kh) // s_h + 1, (iw - kw) // s_w + 1
+    w_blk = w_blk or pick_w_blk(o_w, kc)
+    fb = fused_blocks(n, ih, iw, ic, kh, kw, kc, s_h, s_w, w_blk,
+                      jnp.dtype(dtype).itemsize)
+    eqn = _pallas_call(jax.make_jaxpr(
+        lambda x, g: mec_weight_grad_pallas(x, g, kh, kw, (s_h, s_w),
+                                            w_blk=w_blk))(
+        jax.ShapeDtypeStruct((n, ih, iw, ic), dtype),
+        jax.ShapeDtypeStruct((n, o_h, o_w, kc), dtype)).jaxpr,
+        "mec_fused_wgrad")
+    gm = eqn.params["grid_mapping"]
+    assert tuple(gm.grid) == fb.grid
+    x_map, g_map = gm.block_mappings[:gm.num_inputs]
+    assert x_map.array_aval.shape[:2] + x_map.array_aval.shape[3:4] == \
+        fb.window_extent
+    assert g_map.array_aval.shape[:3] == fb.out_extent
+    for bm in (x_map, g_map):
+        shape = bm.array_aval.shape
+        for d in bm.block_shape:
+            assert getattr(d, "padding", (0, 0)) == (0, 0), bm.block_shape
+        for step in np.ndindex(*fb.grid):
+            starts = jax.core.eval_jaxpr(bm.index_map_jaxpr.jaxpr,
+                                         bm.index_map_jaxpr.consts, *step)
+            for d, start, size in zip(bm.block_shape, starts, shape):
+                lo = int(start) * (1 if isinstance(d, pl.Element)
+                                   else d.block_size)
+                assert 0 <= lo and lo + d.block_size <= size, \
+                    (step, bm.block_shape, shape)
+
+
 def test_accumulator_budget_env_and_default(monkeypatch):
     """pick_w_blk's VMEM accumulator budget is env-configurable
     (REPRO_MEC_ACC_BYTES) instead of a hard-coded ~2 MiB."""

@@ -413,118 +413,137 @@ def test_tpu_candidates_and_pick_follow_the_mosaic_rule():
 # pads them), resnet101_t3's cv4, MEC Table 2's cv1-cv12, then the
 # transposed conv (the input gradient) of each stride-1 kxk conv among
 # them: geometry, then for bfloat16 and float32 the TPU pick, the
-# planner's w_blk, mec_fused's grid and whether the input gradient runs
-# on the kernel.
+# planner's w_blk, mec_fused's grid and whether the input gradient and
+# the weight gradient run on a kernel.
 TPU_PICKS = [
     ((32, 230, 230, 3, 7, 7, 64, 2, 2),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 56, 56, 64, 1, 1, 64, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 58, 58, 64, 3, 3, 64, 1, 1),
-     ("mec_fused", 56, (1, 32, 1, 1), True),
-     ("mec_fused", 56, (1, 32, 1, 1), True)),
+     ("mec_fused", 56, (1, 32, 1, 1), True, True),
+     ("mec_fused", 56, (1, 32, 1, 1), True, True)),
     ((32, 56, 56, 64, 1, 1, 256, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 56, 56, 256, 1, 1, 64, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 56, 56, 256, 1, 1, 128, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 58, 58, 128, 3, 3, 128, 2, 2),
-     ("mec_fused", 28, (1, 8, 1, 1), False),
-     ("mec_fused", 28, (1, 16, 1, 1), False)),
+     ("mec_fused", 28, (1, 8, 1, 1), False, False),
+     ("mec_fused", 28, (1, 16, 1, 1), False, False)),
     ((32, 28, 28, 128, 1, 1, 512, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 56, 56, 256, 1, 1, 512, 2, 2),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 28, 28, 512, 1, 1, 128, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 30, 30, 128, 3, 3, 128, 1, 1),
-     ("mec_fused", 28, (1, 8, 1, 1), True),
-     ("mec_fused", 28, (1, 8, 1, 1), True)),
+     ("mec_fused", 28, (1, 8, 1, 1), True, True),
+     ("mec_fused", 28, (1, 8, 1, 1), True, True)),
     ((32, 28, 28, 512, 1, 1, 256, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 30, 30, 256, 3, 3, 256, 2, 2),
-     ("mec_fused", 14, (1, 7, 1, 1), False),
-     ("mec_fused", 14, (2, 11, 1, 1), False)),
+     ("mec_fused", 14, (1, 7, 1, 1), False, False),
+     ("mec_fused", 14, (2, 11, 1, 1), False, False)),
     ((32, 14, 14, 256, 1, 1, 1024, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 28, 28, 512, 1, 1, 1024, 2, 2),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 14, 14, 1024, 1, 1, 256, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 16, 16, 256, 3, 3, 256, 1, 1),
-     ("mec_fused", 14, (1, 4, 1, 1), True),
-     ("mec_fused", 14, (2, 4, 1, 1), True)),
+     ("mec_fused", 14, (1, 4, 1, 1), True, True),
+     ("mec_fused", 14, (2, 4, 1, 1), True, True)),
     ((32, 14, 14, 1024, 1, 1, 512, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 16, 16, 512, 3, 3, 512, 2, 2),
-     ("mec_fused", 7, (4, 8, 1, 1), False),
-     ("mec_fused", 7, (4, 8, 1, 1), False)),
+     ("mec_fused", 7, (4, 8, 1, 1), False, False),
+     ("mec_fused", 7, (4, 8, 1, 1), False, False)),
     ((32, 7, 7, 512, 1, 1, 2048, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 14, 14, 1024, 1, 1, 2048, 2, 2),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 7, 7, 2048, 1, 1, 512, 1, 1),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 9, 9, 512, 3, 3, 512, 1, 1),
-     ("mec_fused", 7, (4, 2, 1, 1), True),
-     ("mec_fused", 7, (4, 4, 1, 1), True)),
+     ("mec_fused", 7, (4, 2, 1, 1), True, True),
+     ("mec_fused", 7, (4, 4, 1, 1), True, True)),
     ((32, 224, 224, 64, 7, 7, 64, 2, 2),
-     ("mec_fused", 109, (1, 32, 4, 1), False),
-     ("mec_fused", 109, (1, 32, 7, 1), False)),
+     ("mec_fused", 109, (1, 32, 4, 1), False, True),
+     ("mec_fused", 109, (1, 32, 7, 1), False, True)),
     ((32, 227, 227, 3, 11, 11, 96, 4, 4),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 231, 231, 3, 11, 11, 96, 4, 4),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 227, 227, 3, 7, 7, 64, 2, 2),
-     ("direct", None, None, False), ("direct", None, None, False)),
+     ("direct", None, None, False, False),
+     ("direct", None, None, False, False)),
     ((32, 24, 24, 96, 5, 5, 256, 1, 1),
-     ("mec_fused", 20, (1, 11, 1, 1), True),
-     ("mec_fused", 20, (2, 4, 1, 1), True)),
+     ("mec_fused", 20, (1, 11, 1, 1), True, True),
+     ("mec_fused", 20, (2, 4, 1, 1), True, True)),
     ((32, 12, 12, 256, 3, 3, 512, 1, 1),
-     ("mec_fused", 10, (2, 3, 1, 1), True),
-     ("mec_fused", 10, (4, 3, 1, 1), True)),
+     ("mec_fused", 10, (2, 3, 1, 1), True, True),
+     ("mec_fused", 10, (4, 3, 1, 1), True, True)),
     ((32, 224, 224, 3, 3, 3, 64, 1, 1),
-     ("direct", None, None, True), ("direct", None, None, True)),
+     ("direct", None, None, True, False), ("direct", None, None, True, False)),
     ((32, 112, 112, 64, 3, 3, 128, 1, 1),
-     ("mec_fused", 110, (1, 32, 4, 1), True),
-     ("mec_fused", 110, (1, 32, 4, 1), True)),
+     ("mec_fused", 110, (1, 32, 4, 1), True, True),
+     ("mec_fused", 110, (1, 32, 4, 1), True, True)),
     ((32, 56, 56, 64, 3, 3, 64, 1, 1),
-     ("mec_fused", 54, (1, 32, 1, 1), True),
-     ("mec_fused", 54, (1, 32, 1, 1), True)),
+     ("mec_fused", 54, (1, 32, 1, 1), True, True),
+     ("mec_fused", 54, (1, 32, 1, 1), True, True)),
     ((32, 28, 28, 128, 3, 3, 128, 1, 1),
-     ("mec_fused", 26, (1, 8, 1, 1), True),
-     ("mec_fused", 26, (1, 8, 1, 1), True)),
+     ("mec_fused", 26, (1, 8, 1, 1), True, True),
+     ("mec_fused", 26, (1, 8, 1, 1), True, True)),
     ((32, 14, 14, 256, 3, 3, 256, 1, 1),
-     ("mec_fused", 12, (1, 4, 1, 1), True),
-     ("mec_fused", 12, (2, 3, 1, 1), True)),
+     ("mec_fused", 12, (1, 4, 1, 1), True, True),
+     ("mec_fused", 12, (2, 3, 1, 1), True, True)),
     ((32, 7, 7, 512, 3, 3, 512, 1, 1),
-     ("mec_fused", 5, (4, 2, 1, 1), True),
-     ("mec_fused", 5, (4, 2, 1, 1), True)),
+     ("mec_fused", 5, (4, 2, 1, 1), True, True),
+     ("mec_fused", 5, (4, 2, 1, 1), True, True)),
     ((32, 60, 60, 64, 3, 3, 64, 1, 1),
-     ("mec_fused", 58, (1, 32, 1, 1), True),
-     ("mec_fused", 58, (1, 32, 1, 1), True)),
+     ("mec_fused", 58, (1, 32, 1, 1), True, True),
+     ("mec_fused", 58, (1, 32, 1, 1), True, True)),
     ((32, 32, 32, 128, 3, 3, 128, 1, 1),
-     ("mec_fused", 30, (1, 8, 1, 1), True),
-     ("mec_fused", 30, (1, 8, 1, 1), True)),
+     ("mec_fused", 30, (1, 8, 1, 1), True, True),
+     ("mec_fused", 30, (1, 8, 1, 1), True, True)),
     ((32, 18, 18, 256, 3, 3, 256, 1, 1),
-     ("mec_fused", 16, (1, 4, 1, 1), True),
-     ("mec_fused", 16, (2, 4, 1, 1), True)),
+     ("mec_fused", 16, (1, 4, 1, 1), True, True),
+     ("mec_fused", 16, (2, 4, 1, 1), True, True)),
     ((32, 11, 11, 512, 3, 3, 512, 1, 1),
-     ("mec_fused", 9, (4, 3, 1, 1), True),
-     ("mec_fused", 9, (4, 5, 1, 1), True)),
+     ("mec_fused", 9, (4, 3, 1, 1), True, True),
+     ("mec_fused", 9, (4, 5, 1, 1), True, True)),
     ((32, 28, 28, 256, 5, 5, 96, 1, 1),
-     ("mec_fused", 24, (1, 7, 1, 1), True),
-     ("mec_fused", 24, (1, 16, 1, 1), True)),
+     ("mec_fused", 24, (1, 7, 1, 1), True, True),
+     ("mec_fused", 24, (1, 16, 1, 1), True, True)),
     ((32, 14, 14, 512, 3, 3, 256, 1, 1),
-     ("mec_fused", 12, (2, 3, 1, 1), True),
-     ("mec_fused", 12, (2, 6, 1, 1), True)),
+     ("mec_fused", 12, (2, 3, 1, 1), True, True),
+     ("mec_fused", 12, (2, 6, 1, 1), True, True)),
     ((32, 226, 226, 64, 3, 3, 3, 1, 1),
-     ("mec_fused", 224, (1, 32, 13, 1), False),
-     ("mec_fused", 224, (1, 32, 13, 1), False)),
+     ("mec_fused", 224, (1, 32, 13, 1), False, True),
+     ("mec_fused", 224, (1, 32, 13, 1), False, True)),
     ((32, 114, 114, 128, 3, 3, 64, 1, 1),
-     ("mec_fused", 112, (1, 32, 4, 1), True),
-     ("mec_fused", 112, (1, 32, 4, 1), True)),
+     ("mec_fused", 112, (1, 32, 4, 1), True, True),
+     ("mec_fused", 112, (1, 32, 4, 1), True, True)),
 ]
 
 
@@ -535,13 +554,15 @@ TPU_PICKS = [
     for dtype, pick in zip(("bfloat16", "float32"), picks)])
 def test_tpu_plans_mec_fused_for_table2_and_resnet_stages(spec, dtype, pick):
     """The TPU pick, the planner's block, the kernel's grid and where the
-    input gradient runs, as the kernel's rule (``fused_refusal``) decides
-    them; traced nothing, so each case costs milliseconds."""
+    input and weight gradients run, as the kernel's rules
+    (``fused_refusal``, ``fused_weight_grad_refusal``) decide them;
+    traced nothing, so each case costs milliseconds."""
     from repro.core.conv_api import fused_input_grad_refusal
-    from repro.kernels.mec_conv import fused_blocks, fused_refusal
+    from repro.kernels.mec_conv import (fused_blocks, fused_refusal,
+                                        fused_weight_grad_refusal)
     from repro.launch.costmodel import pick_conv2d_algorithm
     from repro.plan.convplan import _pallas_w_blk
-    algorithm, w_blk, grid, grad_on_kernel = pick
+    algorithm, w_blk, grid, grad_on_kernel, wgrad_on_kernel = pick
     assert pick_conv2d_algorithm(spec, "tpu", dtype=dtype) == algorithm
     assert (fused_refusal(spec, dtype) is None) == \
         (algorithm == "mec_fused")
@@ -553,13 +574,18 @@ def test_tpu_plans_mec_fused_for_table2_and_resnet_stages(spec, dtype, pick):
         assert fb.grid == grid
     assert (fused_input_grad_refusal(spec, dtype) is None) == \
         grad_on_kernel
+    # a conv that runs forward on mec_fused, at the planner's block
+    assert (algorithm == "mec_fused" and fused_weight_grad_refusal(
+        spec, dtype, w_blk) is None) == wgrad_on_kernel
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_tpu_explain_prints_the_blocking_and_the_input_gradient(dtype):
     """explain() of a TPU plan prints mec_fused's blocking and where the
-    input gradient runs: on the kernel for cv12, stride 1; on XLA for
-    cv4, stride 2."""
+    gradients run: the input gradient on the kernel for cv12, stride 1,
+    and on XLA for cv4, stride 2; the weight gradient on its kernel for
+    both, and on XLA for a 3x3/2 and where 768 channels' f32 dW block
+    overruns VMEM."""
     plan = plan_conv2d(ConvSpec(32, 9, 9, 512, 3, 3, 512), backend="tpu",
                        dtype=dtype)
     assert plan.algorithm == "mec_fused"
@@ -567,9 +593,21 @@ def test_tpu_explain_prints_the_blocking_and_the_input_gradient(dtype):
     assert "image(s) x 7 row(s)" in plan.explain()
     assert ("input gradient: the mec_fused kernel on the transposed conv "
             "32x11x11x512-k3x3x512-s1x1") in plan.explain()
+    assert ("weight gradient: the mec_fused_wgrad kernel on the forward's "
+            "blocking") in plan.explain()
     cv4 = plan_conv2d(ConvSpec(32, 224, 224, 64, 7, 7, 64, 2, 2),
                       backend="tpu", dtype=dtype).explain()
     assert "input gradient: XLA (_mec_input_grad), stride (2, 2)" in cv4
+    assert "weight gradient: the mec_fused_wgrad kernel" in cv4
+    s2 = plan_conv2d(ConvSpec(32, 58, 58, 128, 3, 3, 128, 2, 2),
+                     backend="tpu", dtype=dtype).explain()
+    assert ("weight gradient: XLA (_mec_weight_grad), stride 2x2 folds 3 "
+            "kernel columns into 2 taps") in s2
+    wide = plan_conv2d(ConvSpec(32, 9, 9, 768, 3, 3, 768), backend="tpu",
+                       dtype=dtype)
+    assert wide.algorithm == "mec_fused"
+    assert ("weight gradient: XLA (_mec_weight_grad), a step's working set "
+            "with the f32 dW block 3x3x768x128") in wide.explain()
 
 
 def test_tpu_sends_3_channel_inputs_to_direct():

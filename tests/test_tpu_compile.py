@@ -95,9 +95,11 @@ def test_mosaic_kernel_carries_program_names(one_chip):
                              "pallas_call"), name
 
 
-# The geometries the whole ResNet-101 adds to ``LAYERS``, at batch 32:
-# (input h = w, input channels, kernel, output channels, stride, pad).
+# The geometries the whole ResNet-101 adds to ``LAYERS``, at batch 32,
+# and resnet101_t3's cv4: (input h = w, input channels, kernel, output
+# channels, stride, pad).
 RESNET = {
+    "cv4": (224, 64, 7, 64, 2, 0),
     "stem": (224, 3, 7, 64, 2, 3),
     "s2_3x3_56": (56, 128, 3, 128, 2, 1),
     "s2_3x3_28": (28, 256, 3, 256, 2, 1),
@@ -115,17 +117,19 @@ RESNET = {
 def test_resnet_geometry_trains_on_v5e_pick(one_chip, name):
     """Each geometry's forward and both gradients compile for the v5e on
     the algorithm the planner picks there, in bfloat16; a stride-1
-    ``mec_fused`` conv's input gradient is a Mosaic kernel of its own."""
+    ``mec_fused`` conv's input gradient is a Mosaic kernel of its own,
+    and so is the weight gradient of a ``mec_fused`` conv wherever
+    ``fused_weight_grad_refusal`` takes it."""
     from repro.core.conv_api import conv2d_spec
+    from repro.kernels.mec_conv import fused_weight_grad_refusal
     from repro.launch.costmodel import pick_conv2d_algorithm
     size, i_c, k, o_c, stride, pad = RESNET[name]
     dt = jnp.bfloat16
     x = jax.ShapeDtypeStruct((BATCH, size, size, i_c), dt, sharding=one_chip)
     w = jax.ShapeDtypeStruct((k, k, i_c, o_c), dt, sharding=one_chip)
     padding = pad or "VALID"
-    pick = pick_conv2d_algorithm(
-        conv2d_spec(x, w, stride=stride, padding=padding), backend="tpu",
-        dtype="bfloat16")
+    spec = conv2d_spec(x, w, stride=stride, padding=padding)
+    pick = pick_conv2d_algorithm(spec, backend="tpu", dtype="bfloat16")
 
     def loss(a, b):
         y = conv2d(a, b, stride=stride, padding=padding, algorithm=pick,
@@ -138,4 +142,9 @@ def test_resnet_geometry_trains_on_v5e_pick(one_chip, name):
     input_grad = [line for line in text.splitlines()
                   if "tpu_custom_call" in line and "mec_input_grad" in line]
     assert bool(input_grad) == (pick == "mec_fused" and stride == 1), name
+    weight_grad = [line for line in text.splitlines()
+                   if "tpu_custom_call" in line and "mec_weight_grad" in line]
+    assert all("/mec_fused_wgrad/" in line for line in weight_grad)
+    takes = fused_weight_grad_refusal(spec, "bfloat16") is None
+    assert bool(weight_grad) == (pick == "mec_fused" and takes), name
     assert pick == ("direct" if k == 1 or i_c < 4 else "mec_fused"), pick

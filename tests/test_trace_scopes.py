@@ -86,6 +86,7 @@ COVERS = {
     "mec_weight_grad": ("train", {"dot_general"}),
     "adamw_update": ("train", {"sqrt"}),
     "mec_fused": ("forward", {"dot_general"}),
+    "mec_fused_wgrad": ("train", {"dot_general"}),
     "mec_conv1d": ("conv1d", {"mul"}),
     "batch_norm": ("resnet", {"rsqrt"}),
     "pointwise": ("resnet", {"conv_general_dilated"}),
@@ -93,7 +94,7 @@ COVERS = {
 }
 # Scopes that lie inside ``conv2d`` wherever they appear.
 IN_CONV2D = ("conv2d_pad", "mec_fold", "conv2d_out", "mec_input_grad",
-             "mec_weight_grad", "mec_fused")
+             "mec_weight_grad", "mec_fused", "mec_fused_wgrad")
 
 
 def _unwrap(component):
@@ -133,13 +134,16 @@ def test_scope_names_its_ops(op_names, scope):
 
 def test_training_scopes_are_apart(op_names):
     """The backward's halves and the optimizer do not nest in each
-    other, and the optimizer lies outside every conv."""
+    other, the optimizer lies outside every conv, and the weight
+    gradient's kernel inside ``mec_weight_grad``."""
     for stack in op_names["train"]:
         owned = {"mec_input_grad", "mec_weight_grad", "adamw_update"} & \
             set(stack)
         assert len(owned) <= 1, stack
         if "adamw_update" in stack:
             assert "conv2d" not in stack
+        if "mec_fused_wgrad" in stack:
+            assert "mec_weight_grad" in stack[:stack.index("mec_fused_wgrad")]
 
 
 def test_resnet_scopes_are_apart(op_names):
