@@ -135,7 +135,7 @@ def audit_plan(scenario: str, plan) -> Tuple[Dict, List[str]]:
         "scenario": scenario,
         "algorithm": plan.algorithm,
         "dtype": plan.dtype,
-        "spec": dataclasses.asdict(s),
+        "spec": s.to_dict(),
         "predicted_overhead_elems": predicted_elems,
         "predicted_overhead_bytes": predicted_bytes,
         "measured_temp_bytes": measured,

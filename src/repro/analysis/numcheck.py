@@ -479,10 +479,24 @@ def hlo_convert_counts(hlo_text: str) -> Dict[Tuple[str, str], int]:
 # f64 reference + error probe
 # ---------------------------------------------------------------------------
 
+def _group_slices(x64, k64):
+    """``(input channels, output channels)`` slices of each group of an
+    HWIO kernel whose ``I`` is the input's channels over the groups."""
+    groups = x64.shape[3] // k64.shape[2]
+    c_g, o_g = k64.shape[2], k64.shape[3] // groups
+    return [(slice(g * c_g, (g + 1) * c_g), slice(g * o_g, (g + 1) * o_g))
+            for g in range(groups)]
+
+
 def f64_conv2d(x64, k64, s_h: int, s_w: int):
     """The f64 numpy oracle (jax's x64 flag stays untouched): direct
-    valid convolution, NHWC x HWIO -> NHWC."""
+    valid convolution, NHWC x HWIO -> NHWC, grouped where the kernel's
+    ``I`` is a share of the input's channels."""
     import numpy as np
+    if k64.shape[2] != x64.shape[3]:
+        return np.concatenate([f64_conv2d(x64[..., ci], k64[..., co],
+                                          s_h, s_w)
+                               for ci, co in _group_slices(x64, k64)], -1)
     i_h, i_w = x64.shape[1], x64.shape[2]
     k_h, k_w = k64.shape[0], k64.shape[1]
     o_h = (i_h - k_h) // s_h + 1
@@ -499,6 +513,12 @@ def f64_conv2d(x64, k64, s_h: int, s_w: int):
 def f64_conv2d_grads(x64, k64, g64, s_h: int, s_w: int):
     """``(dL/dx, dL/dk)`` for cotangent ``g64``, same oracle."""
     import numpy as np
+    if k64.shape[2] != x64.shape[3]:
+        parts = [f64_conv2d_grads(x64[..., ci], k64[..., co], g64[..., co],
+                                  s_h, s_w)
+                 for ci, co in _group_slices(x64, k64)]
+        return (np.concatenate([dx for dx, _ in parts], -1),
+                np.concatenate([dk for _, dk in parts], -1))
     k_h, k_w = k64.shape[0], k64.shape[1]
     o_h, o_w = g64.shape[1], g64.shape[2]
     dx = np.zeros_like(x64)
@@ -537,7 +557,7 @@ def error_probe(spec, algorithm: str, dtype: str = "float32", *,
     rng = np.random.RandomState(seed)
     x = jnp.asarray(rng.randn(spec.i_n, spec.i_h, spec.i_w, spec.i_c)
                     .astype(np.float32), dtype)
-    k = jnp.asarray(rng.randn(spec.k_h, spec.k_w, spec.i_c, spec.k_c)
+    k = jnp.asarray(rng.randn(spec.k_h, spec.k_w, spec.group_c, spec.k_c)
                     .astype(np.float32), dtype)
     x64 = np.asarray(x).astype(np.float64)
     k64 = np.asarray(k).astype(np.float64)
@@ -639,6 +659,10 @@ def check_numerics(spec, algorithm: str, dtype: str = "float32", *,
     if dtype not in CONTRACT_DTYPES:
         return skipped(f"no contract dtype {dtype!r} (contract dtypes: "
                        f"{CONTRACT_DTYPES})")
+    from repro.core.convspec import grouped_refusal
+    why = grouped_refusal(spec, algorithm)
+    if why:
+        return skipped(why)
     if algorithm == "winograd" and \
             (spec.k_h, spec.k_w, spec.s_h, spec.s_w) != (3, 3, 1, 1):
         return skipped("winograd F(2x2,3x3) requires a 3x3 kernel and "
@@ -670,8 +694,8 @@ def check_numerics(spec, algorithm: str, dtype: str = "float32", *,
     fns = {"fwd": fwd, "grad": jax.value_and_grad(loss, argnums=(0, 1))}
     x_s = jax.ShapeDtypeStruct((spec.i_n, spec.i_h, spec.i_w, spec.i_c),
                                dtype)
-    k_s = jax.ShapeDtypeStruct((spec.k_h, spec.k_w, spec.i_c, spec.k_c),
-                               dtype)
+    k_s = jax.ShapeDtypeStruct((spec.k_h, spec.k_w, spec.group_c,
+                                spec.k_c), dtype)
     violations: List[ContractViolation] = []
     jaxprs = []
     for direction in directions:

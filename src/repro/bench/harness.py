@@ -15,7 +15,6 @@ schema-only checked.
 """
 from __future__ import annotations
 
-import dataclasses
 import math
 import time
 from typing import Dict, List, Optional, Sequence
@@ -42,10 +41,11 @@ _FLOPS_BASE = {"mecA": "mec", "mecB": "mec", "mec_fused": "mec"}
 
 
 def make_arrays(s: ConvSpec, dtype: str = "float32", seed: int = 0):
-    """Deterministic NHWC input + HWIO kernel for a spec."""
+    """Deterministic NHWC input + HWIO kernel (``i_c / groups`` input
+    channels) for a spec."""
     rng = np.random.RandomState(seed)
     inp = rng.randn(s.i_n, s.i_h, s.i_w, s.i_c).astype(np.float32)
-    ker = rng.randn(s.k_h, s.k_w, s.i_c, s.k_c).astype(np.float32)
+    ker = rng.randn(s.k_h, s.k_w, s.group_c, s.k_c).astype(np.float32)
     return jnp.asarray(inp, dtype), jnp.asarray(ker, dtype)
 
 
@@ -103,8 +103,8 @@ def measure(sc: Scenario, algorithm: str, iters: int = 3, warmup: int = 1,
         "algorithm": algorithm,
         "dtype": sc.dtype,
         "weight": sc.weight,
-        "spec": dataclasses.asdict(sc.spec),
-        "run_spec": dataclasses.asdict(sc.run_spec),
+        "spec": sc.spec.to_dict(),
+        "run_spec": sc.run_spec.to_dict(),
         # Deterministic analytics on the exact paper spec (check gates on
         # these) ...
         "overhead_elems": int(algorithm_overhead(sc.spec, algorithm)),
@@ -370,8 +370,8 @@ def run_serve(progress=None) -> Dict:
                     "algorithm": mode,
                     "dtype": cell.dtype,
                     "weight": 1,
-                    "spec": dataclasses.asdict(spec),
-                    "run_spec": dataclasses.asdict(spec),
+                    "spec": spec.to_dict(),
+                    "run_spec": spec.to_dict(),
                     # Eq. 3 on the padded class spec: the memory the
                     # serving layer's MEC lowering costs per class
                     # request — backend-independent, exact-gated.
@@ -468,7 +468,7 @@ def run_autotune(base_suite: str = "smoke", iters: int = 3, warmup: int = 1,
         results.append({
             "scenario": sc.name,
             "dtype": sc.dtype,
-            "run_spec": dataclasses.asdict(sc.run_spec),
+            "run_spec": sc.run_spec.to_dict(),
             "analytic_algorithm": analytic.algorithm,
             "analytic_us": analytic_us,
             "measured_algorithm": measured_alg,

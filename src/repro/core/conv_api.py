@@ -54,8 +54,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.convspec import (ConvSpec, normalize_stride, pad_same,
-                                 spec_of)
+from repro.core.convspec import (ConvSpec, conv_groups, grouped_refusal,
+                                 normalize_stride, pad_same, spec_of)
 from repro.core.direct import direct_conv2d
 from repro.core.fft_conv import fft_conv2d
 from repro.core.im2col import im2col_conv2d
@@ -77,11 +77,12 @@ Padding = Union[str, int, Tuple]
 # backward and the optimizer step (``repro.optim.adamw.update``), then the
 # ``pallas_call`` names of the kernels (``repro.kernels``), then the
 # ResNet's (``repro.models``): every batch norm, every 1x1 conv call and
-# the head (pool, classifier and loss).
+# the head (pool, classifier and loss); then the ConvNeXt's: every
+# depthwise conv call and every layer norm.
 TRACE_SCOPES = ("conv2d", "conv2d_pad", "mec_fold", "conv2d_out",
                 "mec_input_grad", "mec_weight_grad", "adamw_update",
                 "mec_fused", "mec_fused_wgrad", "mec_conv1d", "batch_norm",
-                "pointwise", "head")
+                "pointwise", "head", "depthwise", "layer_norm")
 
 
 def apply_padding(inp: jnp.ndarray, k_h: int, k_w: int, s_h: int, s_w: int,
@@ -264,7 +265,10 @@ def _dispatch(x: jnp.ndarray, kernel: jnp.ndarray, spec: ConvSpec,
               w_blk: Optional[int]) -> jnp.ndarray:
     """Single-device execution of a *resolved* algorithm on the
     pre-padded input — the executor core shared by the kwargs path and
-    ``conv2d(plan=)``."""
+    ``conv2d(plan=)``.  A grouped conv runs on ``direct`` only."""
+    why = grouped_refusal(spec, algorithm)
+    if why:
+        raise ValueError(why)
     if algorithm == "direct":
         return direct_conv2d(x, kernel, (s_h, s_w), precision=precision)
     if algorithm == "im2col":
@@ -290,7 +294,10 @@ def conv2d(inp: jnp.ndarray, kernel: jnp.ndarray, *, stride=1,
            plan: Optional["ConvPlan"] = None) -> jnp.ndarray:
     """2-D convolution, NHWC x HWIO -> NHWC.
 
-    inp: (i_n, i_h, i_w, i_c); kernel: (k_h, k_w, i_c, k_c).
+    inp: (i_n, i_h, i_w, i_c); kernel: (k_h, k_w, i_c / groups, k_c):
+    the group count is ``i_c`` over the kernel's input channels (a
+    depthwise kernel is ``(k_h, k_w, 1, i_c)``), and a grouped conv runs
+    on ``direct`` only.
     stride: int or (s_h, s_w).  padding: 'SAME' | 'VALID' | int |
     ((lo, hi), (lo, hi)).  algorithm: one of :data:`ALGORITHMS`.
     solution: MEC Solution 'A' | 'B' | 'auto' (reference path only).
@@ -396,4 +403,4 @@ def conv2d_spec(inp: jnp.ndarray, kernel: jnp.ndarray, *, stride=1,
                                 s_h, s_w, padding), inp)
     i_n, i_h, i_w, i_c = x.shape
     return ConvSpec(i_n, i_h, i_w, i_c, kernel.shape[0], kernel.shape[1],
-                    kernel.shape[3], s_h, s_w)
+                    kernel.shape[3], s_h, s_w, conv_groups(i_c, kernel.shape))

@@ -8,14 +8,20 @@ so every algorithm sees an identical pre-padded input.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
 
 @dataclasses.dataclass(frozen=True)
 class ConvSpec:
-    """Geometry of one 2-D convolution, pre-padding (paper Eq. 1)."""
+    """Geometry of one 2-D convolution, pre-padding (paper Eq. 1).
+
+    ``groups`` splits the channels into that many independent convs
+    (``feature_group_count``): the kernel is HWIO with ``i_c / groups``
+    input channels, and output channel ``o`` reads input group
+    ``o // (k_c / groups)``.  A depthwise conv has ``groups == i_c``.
+    Only the ``direct`` algorithm runs a grouped conv."""
 
     i_n: int
     i_h: int
@@ -26,6 +32,7 @@ class ConvSpec:
     k_c: int
     s_h: int = 1
     s_w: int = 1
+    groups: int = 1
 
     @property
     def o_h(self) -> int:
@@ -44,6 +51,22 @@ class ConvSpec:
             raise ValueError(f"kernel larger than input: {self}")
         if min(self.s_h, self.s_w) < 1:
             raise ValueError(f"strides must be >= 1: {self}")
+        if self.groups < 1 or self.i_c % self.groups or \
+                self.k_c % self.groups:
+            raise ValueError(f"groups must divide both i_c and k_c: {self}")
+
+    @property
+    def group_c(self) -> int:
+        """Input channels each output channel reads: the kernel's I."""
+        return self.i_c // self.groups
+
+    def to_dict(self) -> dict:
+        """The fields as a dict, ``groups`` only where it is not 1, so a
+        dense spec serializes as it did before groups existed."""
+        out = dataclasses.asdict(self)
+        if self.groups == 1:
+            del out["groups"]
+        return out
 
 
 def normalize_stride(stride) -> Tuple[int, int]:
@@ -95,13 +118,33 @@ def padded_spec(s: ConvSpec, padding) -> ConvSpec:
     return dataclasses.replace(s, i_h=s.i_h + pad_h, i_w=s.i_w + pad_w)
 
 
+def conv_groups(i_c: int, kernel_shape) -> int:
+    """The group count of an HWIO kernel on ``i_c`` input channels:
+    ``i_c`` over the kernel's input channels, which must divide it."""
+    kic = kernel_shape[2]
+    if kic < 1 or i_c % kic:
+        raise ValueError(f"channel mismatch: input {i_c} is not a whole "
+                         f"multiple of the kernel's {kic} input channels")
+    return i_c // kic
+
+
+def grouped_refusal(spec: ConvSpec, algorithm: str) -> Optional[str]:
+    """Why ``algorithm`` does not run the grouped conv ``spec``, or None:
+    ``direct`` (XLA's ``feature_group_count``) runs every grouped conv;
+    the lowerings (im2col, MEC, FFT, Winograd) and the Pallas kernel
+    contract over all of ``i_c`` and run dense convs only."""
+    if spec.groups == 1 or algorithm == "direct":
+        return None
+    return (f"groups={spec.groups}: {algorithm} runs dense convs only; a "
+            "grouped conv runs on direct")
+
+
 def spec_of(inp: jnp.ndarray, kernel: jnp.ndarray, stride) -> ConvSpec:
     s_h, s_w = normalize_stride(stride)
     i_n, i_h, i_w, i_c = inp.shape
-    k_h, k_w, kic, k_c = kernel.shape
-    if kic != i_c:
-        raise ValueError(f"channel mismatch: input {i_c} kernel {kic}")
-    spec = ConvSpec(i_n, i_h, i_w, i_c, k_h, k_w, k_c, s_h, s_w)
+    k_h, k_w, _, k_c = kernel.shape
+    spec = ConvSpec(i_n, i_h, i_w, i_c, k_h, k_w, k_c, s_h, s_w,
+                    conv_groups(i_c, kernel.shape))
     spec.validate()
     return spec
 

@@ -2,6 +2,9 @@
 
 XLA's direct convolution is the "no memory-overhead" reference point and
 the numerical ground truth for every other algorithm in this package.
+It is also the one algorithm that runs grouped convs: the group count is
+the input's channels over the kernel's (HWIO ``I`` = ``i_c / groups``),
+passed as ``feature_group_count``, and both gradients are XLA's.
 
 Sub-f32 inputs need a custom VJP: ``preferred_element_type=f32`` makes
 the forward emit an f32 accumulator (the numeric contract, DESIGN.md
@@ -23,12 +26,17 @@ import jax.numpy as jnp
 from jax import lax
 
 
+def _groups(inp: jnp.ndarray, kernel: jnp.ndarray) -> int:
+    return inp.shape[-1] // kernel.shape[2]
+
+
 def _conv(inp: jnp.ndarray, kernel: jnp.ndarray, s, precision):
     return lax.conv_general_dilated(
         inp, kernel,
         window_strides=s,
         padding="VALID",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=_groups(inp, kernel),
         precision=precision,
         preferred_element_type=jnp.float32,
     ).astype(inp.dtype)
@@ -52,6 +60,7 @@ def _direct_bwd(s, precision, res, g):
             window_strides=s,
             padding="VALID",
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            feature_group_count=_groups(x32, k32),
             precision=precision)
 
     _, vjp = jax.vjp(f32_conv, inp.astype(jnp.float32),
@@ -66,6 +75,7 @@ _direct.defvjp(_direct_fwd, _direct_bwd)
 @functools.partial(jax.jit, static_argnames=("stride", "precision"))
 def direct_conv2d(inp: jnp.ndarray, kernel: jnp.ndarray, stride=1,
                   precision=None) -> jnp.ndarray:
-    """inp (n, h, w, c) pre-padded; kernel (k_h, k_w, i_c, k_c); VALID."""
+    """inp (n, h, w, c) pre-padded; kernel (k_h, k_w, c / groups, k_c);
+    VALID."""
     s = (stride, stride) if isinstance(stride, int) else tuple(stride)
     return _direct(inp, kernel, s, precision)

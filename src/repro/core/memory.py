@@ -57,8 +57,9 @@ def direct_overhead(s: ConvSpec) -> int:  # lint-ignore: accepted-kwarg-not-forw
 
 
 def conv_flops(s: ConvSpec) -> int:
-    """Mult-adds x2 — identical for direct/im2col/MEC (paper §3.2)."""
-    return 2 * s.i_n * s.o_h * s.o_w * s.k_h * s.k_w * s.i_c * s.k_c
+    """Mult-adds x2 — identical for direct/im2col/MEC (paper §3.2); an
+    output channel of a grouped conv reads ``i_c / groups`` channels."""
+    return 2 * s.i_n * s.o_h * s.o_w * s.k_h * s.k_w * s.group_c * s.k_c
 
 
 ALL_OVERHEADS = {

@@ -319,6 +319,8 @@ def fused_refusal(spec, dtype, w_blk: int | None = None) -> str | None:
     """Why ``mec_fused`` does not take the conv ``spec`` in ``dtype``, or
     None where it does.
 
+    A grouped conv (``spec.groups > 1``) is refused first, with or
+    without a block: the kernel's GEMMs contract over all of ``i_c``.
     Without ``w_blk`` this is the TPU pick's rule, at the planner's block
     (:func:`repro.kernels.ops.pick_w_blk`): a 1x1 kernel, whose lowering
     is a no-op, and an input of fewer than ``FUSED_MIN_CHANNELS``
@@ -331,6 +333,9 @@ def fused_refusal(spec, dtype, w_blk: int | None = None) -> str | None:
     :func:`~repro.kernels.ops.vmem_bytes` and a chunk's f32 sums inside
     :func:`~repro.kernels.ops.accumulator_budget`."""
     from repro.kernels import ops
+    if spec.groups > 1:
+        return (f"groups={spec.groups}: each tap's GEMM contracts over all "
+                "of i_c, so the kernel runs dense convs only")
     if w_blk is None:
         if spec.k_h == 1 and spec.k_w == 1:
             return "1x1 kernel: the lowering is a no-op, XLA's conv wins"

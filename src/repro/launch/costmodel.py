@@ -263,11 +263,14 @@ def conv2d_algorithm_costs(spec, calibration=None) -> Dict[str, Dict[str, float]
     reports gate these fields exactly, so they must stay deterministic.
     """
     from repro.core import memory
+    from repro.core.convspec import grouped_refusal
     base = memory.conv_flops(spec)
     costs: Dict[str, Dict[str, float]] = {}
     for alg, overhead in memory.ALL_OVERHEADS.items():
         if alg == "winograd" and \
                 (spec.k_h, spec.k_w, spec.s_h, spec.s_w) != (3, 3, 1, 1):
+            continue
+        if grouped_refusal(spec, alg):
             continue
         flops = float(base)
         if alg == "winograd":
@@ -326,7 +329,7 @@ def replica_combine_bytes(spec, parts, sizes, dtype_bytes: int) -> float:
             (spec.i_h // max(1, n.get("spatial", 1))) * spec.i_w * spec.i_c
         return float(x_loc * dtype_bytes)
     if tuple(parts) == ("channel",):
-        k_loc = spec.k_h * spec.k_w * spec.i_c * \
+        k_loc = spec.k_h * spec.k_w * spec.group_c * \
             (-(-spec.k_c // n["channel"]))
         return float(k_loc * dtype_bytes)
     return 0.0
@@ -404,7 +407,8 @@ def conv_partition_costs(spec, n_dev, dtype_bytes: int = 4,
         if "batch" in by or "spatial" in by:
             # kernel cotangent psum'd over the input-sharding axes;
             # operand is the (possibly channel-sharded) local kernel.
-            bwd += spec.k_h * spec.k_w * spec.i_c * k_c_loc * dtype_bytes
+            bwd += spec.k_h * spec.k_w * spec.group_c * k_c_loc * \
+                dtype_bytes
         if "channel" in by:
             # input cotangent psum'd over the channel axis; operand is
             # the (possibly batch/spatially-sharded) local input.
@@ -519,6 +523,8 @@ def pick_conv2d_algorithm(spec, backend: str | None = None,
                           calibration="ambient", dtype="float32") -> str:
     """Dispatch rule for conv2d(algorithm='auto') — DESIGN.md §1, §10.
 
+    * grouped convs (``spec.groups > 1``): direct, the one algorithm that
+      runs them, on every backend.
     * 1x1 kernels: lowering is a no-op, direct wins outright.
     * TPU backend: the fused Pallas kernel (no L in HBM at all) wherever
       its rule, :func:`repro.kernels.mec_conv.fused_refusal`, admits the
@@ -544,7 +550,7 @@ def pick_conv2d_algorithm(spec, backend: str | None = None,
     import jax
 
     backend = backend or jax.default_backend()
-    if spec.k_h == 1 and spec.k_w == 1:
+    if spec.groups > 1 or (spec.k_h == 1 and spec.k_w == 1):
         return "direct"
     if backend == "tpu":
         from repro.kernels.mec_conv import fused_refusal

@@ -14,7 +14,6 @@ Results are appended as JSON files under results/dryrun/ (one per cell) —
 benchmarks/roofline.py and EXPERIMENTS.md read from there.
 """
 import argparse
-import dataclasses
 import json
 import math
 import pathlib
@@ -295,7 +294,7 @@ def run_conv_cell(name: str, multi_pod: bool, out_dir: pathlib.Path,
         "n_axis": list(n_axes),
         "mesh": "2x16x16" if multi_pod else "16x16",
         "n_chips": int(mesh.devices.size),
-        "spec": dataclasses.asdict(spec),
+        "spec": spec.to_dict(),
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),
         "per_device": {
             "flops": float(cost.get("flops", 0.0)),

@@ -1,4 +1,5 @@
-"""Model primitives: norms, linear, conv2d, RoPE, SwiGLU, GQA attention.
+"""Model primitives: norms, GELU, linear, conv2d, RoPE, SwiGLU, GQA
+attention.
 
 Attention comes in two forms:
 * ``chunked_attention`` — streaming (flash-style) online-softmax attention
@@ -61,6 +62,24 @@ def batch_norm(x: jnp.ndarray, p: dict, stats: dict):
         y = (x32 - mean) * (lax.rsqrt(var + BN_EPS) * p["scale"]) + \
             p["bias"]
         return y.astype(x.dtype), stats
+
+
+def layer_norm(x: jnp.ndarray, p: dict, eps: float) -> jnp.ndarray:
+    """Layer norm over the last axis (channels, channels-last), as
+    PyTorch's ``LayerNorm``: mean and biased variance in float32, then
+    ``scale`` and ``bias``; the output in ``x``'s dtype."""
+    with jax.named_scope("layer_norm"):
+        x32 = x.astype(jnp.float32)
+        mean = jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
+        y = (x32 - mean) * lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+        return y.astype(x.dtype)
+
+
+def gelu(x: jnp.ndarray) -> jnp.ndarray:
+    """Exact (erf) GELU, computed in float32, in ``x``'s dtype."""
+    return jax.nn.gelu(x.astype(jnp.float32), approximate=False
+                       ).astype(x.dtype)
 
 
 def linear(x: jnp.ndarray, p: dict) -> jnp.ndarray:

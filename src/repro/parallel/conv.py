@@ -120,7 +120,8 @@ def _component_viable(spec: ConvSpec, mode: str, n_dev: int) -> bool:
     if mode == "batch":
         return spec.i_n % n_dev == 0
     if mode == "channel":
-        return spec.k_c % n_dev == 0
+        # A channel split would cut a grouped conv's groups apart.
+        return spec.groups == 1 and spec.k_c % n_dev == 0
     # spatial
     if spec.i_h % n_dev:
         return False

@@ -34,7 +34,7 @@ import dataclasses
 import json
 from typing import Dict, Optional, Sequence, Tuple, Union
 
-from repro.core.convspec import ConvSpec
+from repro.core.convspec import ConvSpec, grouped_refusal
 from repro.core.mec import SOLUTIONS, pick_solution
 
 PLAN_VERSION = 1
@@ -74,10 +74,12 @@ def _dtype_name(dtype) -> str:
 
 
 def spec_key(spec: ConvSpec) -> str:
-    """Readable, order-stable spec identity used in cache keys."""
-    return (f"{spec.i_n}x{spec.i_h}x{spec.i_w}x{spec.i_c}"
-            f"-k{spec.k_h}x{spec.k_w}x{spec.k_c}"
-            f"-s{spec.s_h}x{spec.s_w}")
+    """Readable, order-stable spec identity used in cache keys; a grouped
+    spec ends in ``-g<groups>``, a dense one as before groups existed."""
+    key = (f"{spec.i_n}x{spec.i_h}x{spec.i_w}x{spec.i_c}"
+           f"-k{spec.k_h}x{spec.k_w}x{spec.k_c}"
+           f"-s{spec.s_h}x{spec.s_w}")
+    return key if spec.groups == 1 else f"{key}-g{spec.groups}"
 
 
 def plan_cache_key(spec: ConvSpec, dtype: str, backend: str) -> str:
@@ -109,6 +111,9 @@ class ConvPlan:
                              f"resolved algorithm {_SINGLE_DEVICE_ALGOS}")
         if self.solution not in SOLUTIONS:
             raise ValueError(f"unknown MEC solution {self.solution!r}")
+        why = grouped_refusal(self.spec, self.algorithm)
+        if why:
+            raise ValueError(why)
         if self.precision is not None and \
                 self.precision not in PRECISION_NAMES:
             raise ValueError(f"unknown precision {self.precision!r}")
@@ -168,7 +173,7 @@ class ConvPlan:
     def to_dict(self) -> Dict:
         return {
             "plan_version": PLAN_VERSION,
-            "spec": dataclasses.asdict(self.spec),
+            "spec": self.spec.to_dict(),
             "dtype": self.dtype,
             "algorithm": self.algorithm,
             "solution": self.solution,
@@ -235,7 +240,10 @@ class ConvPlan:
             c = costs[alg]
             lines.append(f"    {alg:8s} overhead={c['overhead_elems']:.3e} "
                          f"flops={c['flops']:.3e}{mark}")
-        if self.algorithm == "mec_fused":
+        if self.spec.groups > 1:
+            lines.append(f"  groups={self.spec.groups}: only direct runs a "
+                         "grouped conv")
+        elif self.algorithm == "mec_fused":
             import numpy as np
             from repro.core.conv_api import (fused_input_grad_refusal,
                                              input_grad_spec)
@@ -443,10 +451,13 @@ def pick_measured(times: Dict[str, float], analytic: str,
 
 def eligible_candidates(spec: ConvSpec) -> Tuple[str, ...]:
     """conv2d algorithm names the measured policy may time on a spec:
-    every single-device algorithm, winograd only on 3x3 stride 1."""
+    every single-device algorithm, winograd only on 3x3 stride 1, and
+    ``direct`` alone on a grouped spec."""
     return tuple(alg for alg in _SINGLE_DEVICE_ALGOS
-                 if alg != "winograd"
-                 or (spec.k_h, spec.k_w, spec.s_h, spec.s_w) == (3, 3, 1, 1))
+                 if grouped_refusal(spec, alg) is None and (
+                     alg != "winograd" or
+                     (spec.k_h, spec.k_w, spec.s_h, spec.s_w) ==
+                     (3, 3, 1, 1)))
 
 
 @dataclasses.dataclass
@@ -527,6 +538,11 @@ def measure_candidates_detailed(
     out = MeasuredCandidates(times={}, stats={}, skipped={})
     recorded = []
     for alg in candidates:
+        why = grouped_refusal(spec, alg)
+        if why:
+            out.skipped[alg] = why
+            warnings.warn(f"measured planning skips {alg}: {why}")
+            continue
         trial = ConvPlan(
             spec=spec, dtype=dtype, algorithm=alg,
             solution=pick_solution(spec) if alg == "mec" else "auto",

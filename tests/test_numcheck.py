@@ -282,6 +282,24 @@ def test_plan_conv2d_asserts_the_contract():
     assert plan.algorithm            # resolved and contract-clean
 
 
+def test_grouped_direct_plan_passes_the_contract():
+    """A depthwise plan (groups = i_c, kernel ``(k, k, 1, i_c)``) passes
+    the plan-time assert in bf16 and f32, with an f64 probe inside the
+    direct contract's budget; a grouped spec's other algorithms are
+    skipped with the reason, not failed."""
+    from repro.core.convspec import ConvSpec
+    from repro.plan.convplan import plan_conv2d
+    spec = ConvSpec(2, 14, 14, 8, 7, 7, 8, 1, 1, 8)
+    for dtype in ("bfloat16", "float32"):
+        plan = plan_conv2d(spec, dtype=dtype, mode="analytic")
+        assert plan.algorithm == "direct"
+        assert_plan_numerics(plan)
+    chk = check_numerics(spec, "direct", "float32", probe=True)
+    assert chk.ok and chk.record["probe"]["din_err"] < \
+        contract_for("direct").tolerance("float32", "grad")
+    assert "groups=8" in check_numerics(spec, "mec", "float32").skipped
+
+
 # ---------------------------------------------------------------------------
 # measured error budgets (tolerances from the contract, never this file)
 # ---------------------------------------------------------------------------

@@ -4,8 +4,9 @@ where a profiler trace reads each device op's owner.
 
 Programs are compiled on the CPU, the Pallas kernels in interpret mode:
 a ``conv2d(plan=)`` forward on ``mec_fused``, a training step (``jax.grad``
-through the MEC custom VJP, then AdamW), the conv1d kernel, and a
-tiny ResNet's training step (``repro.models.resnet``).
+through the MEC custom VJP, then AdamW), the conv1d kernel, a tiny
+ResNet's training step (``repro.models.resnet``) and a tiny ConvNeXt's
+(``repro.models.convnext``).
 """
 import re
 
@@ -16,7 +17,7 @@ import jax.numpy as jnp
 
 from repro.core.conv_api import TRACE_SCOPES, conv2d, conv2d_spec
 from repro.kernels.ops import mec_conv1d_tpu
-from repro.models import resnet
+from repro.models import convnext, resnet
 from repro.optim import adamw
 from repro.plan import ConvPlan
 
@@ -62,6 +63,15 @@ def _programs():
     net_params, net_stats = resnet.init(jax.random.key(0), net)
     net_step = resnet.train_step(resnet.plan(net, 2, BF16),
                                  adamw.AdamWConfig())
+    # One block per stage at a sixteenth of ConvNeXt-B's widths.
+    cnx = {"image_size": 32, "in_channels": 3, "num_classes": 10,
+           "stem": {"kernel": 4, "stride": 4},
+           "downsample": {"kernel": 2, "stride": 2}, "depths": [1, 1, 1, 1],
+           "dims": [8, 16, 32, 64], "kernel": 7, "expansion": 4,
+           "ln_eps": 1e-6, "drop_path": 0.5, "layer_scale_init": 0.5}
+    cnx_params = convnext.init(jax.random.key(0), cnx)
+    cnx_step = convnext.train_step(convnext.plan(cnx, 2, BF16),
+                                   adamw.AdamWConfig())
 
     return {
         "forward": (forward, (x, ws)),
@@ -72,6 +82,10 @@ def _programs():
                               adamw.init(net_params),
                               jnp.ones((2, 32, 32, 3), BF16),
                               jnp.zeros((2,), jnp.int32))),
+        "convnext": (cnx_step, (cnx_params, adamw.init(cnx_params),
+                                jnp.ones((2, 32, 32, 3), BF16),
+                                jnp.zeros((2,), jnp.int32),
+                                jax.random.key(1))),
     }
 
 
@@ -91,6 +105,8 @@ COVERS = {
     "batch_norm": ("resnet", {"rsqrt"}),
     "pointwise": ("resnet", {"conv_general_dilated"}),
     "head": ("resnet", {"dot_general"}),
+    "depthwise": ("convnext", {"conv_general_dilated"}),
+    "layer_norm": ("convnext", {"rsqrt"}),
 }
 # Scopes that lie inside ``conv2d`` wherever they appear.
 IN_CONV2D = ("conv2d_pad", "mec_fold", "conv2d_out", "mec_input_grad",
@@ -155,3 +171,19 @@ def test_resnet_scopes_are_apart(op_names):
             assert "conv2d" in stack[stack.index("pointwise"):], stack
         if {"batch_norm", "head"} & set(stack):
             assert "conv2d" not in stack and "pointwise" not in stack, stack
+
+
+def test_convnext_scopes_are_apart(op_names):
+    """A depthwise conv's convolutions, forward and backward, lie in
+    ``depthwise`` and then ``conv2d``, never in ``pointwise``; no layer
+    norm op lies in a conv."""
+    convs = [stack for stack in op_names["convnext"]
+             if stack[-1] == "conv_general_dilated"]
+    assert any("depthwise" in stack for stack in convs)
+    for stack in convs:
+        if "depthwise" in stack:
+            assert "conv2d" in stack[stack.index("depthwise"):], stack
+            assert "pointwise" not in stack, stack
+    for stack in op_names["convnext"]:
+        if "layer_norm" in stack:
+            assert "conv2d" not in stack and "depthwise" not in stack, stack
